@@ -5,6 +5,9 @@ set -euo pipefail
 cd "$(dirname "$0")/.."
 
 export PYTHONPATH="src${PYTHONPATH:+:$PYTHONPATH}"
+# forced host devices (repro.api.MeshSpec) need the CPU pinned as the
+# platform; the Pallas kernels then run interpreted
+export JAX_PLATFORMS=cpu
 
 echo "=== tier-1 pytest ==="
 python -m pytest -x -q

@@ -5,6 +5,9 @@ manual ``XLA_FLAGS`` device forcing. A spec is plain data: it can be built
 before jax touches any device, so the host-device forcing (needed for CPU
 testing of multi-client meshes) happens at exactly the right moment —
 before the first backend init — no matter which entrypoint runs first.
+Forcing applies only when the CPU is the pinned platform
+(``JAX_PLATFORMS=cpu``); on an accelerator the mesh is built from the
+chips present and ``XLA_FLAGS`` is left alone.
 
 FLAD axis mapping (see :mod:`repro.launch.mesh`): ``pod`` = cloud regions,
 ``data`` = vehicles / edge FL clients, ``model`` = intra-cluster
@@ -27,17 +30,25 @@ _FORCE_RE = re.compile(r"--xla_force_host_platform_device_count=(\d+)")
 _devices_locked = False
 
 
-def ensure_host_devices(n: int) -> None:
-    """Force at least ``n`` host (CPU) devices before the first backend init.
+def _cpu_pinned() -> bool:
+    """True iff ``jax_platforms`` (``JAX_PLATFORMS``) makes the CPU the
+    platform jax will use — read without initializing a backend."""
+    import jax
+    return (jax.config.jax_platforms or "").split(",")[0] == "cpu"
 
-    Safe to call repeatedly and on real accelerators: the flag only affects
-    the host platform, and once jax has initialized this degrades to an
-    assertion that enough devices exist.
+
+def ensure_host_devices(n: int) -> None:
+    """Require ``n`` devices; on the pinned CPU platform, first force at
+    least ``n`` host devices (before the first backend init).
+
+    Safe to call repeatedly: once jax has initialized, or on an
+    accelerator, this is only a check that enough devices exist, and the
+    error names the platform and the count found.
     """
     global _devices_locked
     if n <= 0:
         return
-    if not _devices_locked:
+    if not _devices_locked and _cpu_pinned():
         flags = os.environ.get("XLA_FLAGS", "")
         m = _FORCE_RE.search(flags)
         current = int(m.group(1)) if m else 0
@@ -48,13 +59,18 @@ def ensure_host_devices(n: int) -> None:
             ).strip()
     import jax
 
-    have = len(jax.devices())
+    devices = jax.devices()
     _devices_locked = True
-    if have < n:
+    if len(devices) < n:
+        platform = devices[0].platform
+        hint = ("jax locks the host device count at first backend use: "
+                "set JAX_PLATFORMS=cpu and build the Session/MeshSpec (or "
+                "call ensure_host_devices) before any other jax device "
+                "access" if platform == "cpu"
+                else "use a mesh no larger than the chips present")
         raise RuntimeError(
-            f"need {n} devices, have {have}; jax locks the device count at "
-            f"first backend use — build the Session/MeshSpec (or call "
-            f"ensure_host_devices) before any other jax device access")
+            f"need {n} devices, have {len(devices)} {platform} "
+            f"device(s); {hint}")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -63,9 +79,10 @@ class MeshSpec:
 
     ``dims``     trailing-aligned against ``(pod, data, model)`` unless
                  ``axes`` is given: ``(2, 4)`` -> data=2, model=4.
-    ``devices``  None (default) forces ``prod(dims)`` host devices on CPU;
+    ``devices``  None (default) requires ``prod(dims)`` devices, forcing
+                 that many host devices on the pinned CPU platform;
                  0 disables forcing (use whatever jax already has);
-                 N forces at least N.
+                 N requires (on the CPU: forces) at least N.
     ``production``/``multi_pod`` select the deployment meshes from
                  :func:`repro.launch.mesh.make_production_mesh`.
     """

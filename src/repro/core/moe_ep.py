@@ -28,7 +28,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core import compat
 from repro.config import ModelConfig
 
 
@@ -126,7 +125,7 @@ def moe_block_ep(p: dict, x: jnp.ndarray, cfg: ModelConfig, *,
         aux = lax.pmean(aux + zl, mesh.axis_names)
         return yl, aux
 
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), wspec_i, wspec_i, wspec_o, xspec),
         out_specs=(xspec, P()),

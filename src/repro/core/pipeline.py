@@ -38,7 +38,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.core import compat
 from repro.config import ModelConfig, ShapeConfig
 from repro.models import blocks as B
 
@@ -675,7 +674,7 @@ def make_fhdp_train_step(cfg: ModelConfig, shape: ShapeConfig, mesh: Mesh, *,
     bspec = jax.tree.map(
         lambda x: P(batch_axes, *([None] * (len(x.shape) - 1))), batch_abs)
 
-    step = compat.shard_map(device_fn, mesh=mesh,
+    step = jax.shard_map(device_fn, mesh=mesh,
                          in_specs=(pspec, ospec, bspec),
                          out_specs=(pspec, ospec, P()),
                          check_vma=False)
@@ -698,5 +697,5 @@ def fedavg_stage_params(pp, mesh: Mesh):
             if jnp.issubdtype(x.dtype, jnp.inexact) else x, pp)
 
     spec = stage_specs(mesh, jax.eval_shape(lambda: pp))
-    return compat.shard_map(avg, mesh=mesh, in_specs=(spec,), out_specs=spec,
+    return jax.shard_map(avg, mesh=mesh, in_specs=(spec,), out_specs=spec,
                          check_vma=False)(pp)

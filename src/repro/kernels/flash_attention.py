@@ -13,8 +13,12 @@ windowed long_500k path.
 Backward structure (FlashAttention-2): the forward additionally emits the
 per-row logsumexp ``lse = m + log(l)`` so the VJP saves ``(q, k, v, o,
 lse)`` — O(S·D) residuals — instead of rematerializing the O(Sq·Skv)
-score/softmax matrices. Three kernels then compute the gradients, each
-recomputing ``p = exp(s - lse)`` one block at a time:
+score/softmax matrices. Inside the kernels the per-row statistics (m, l,
+lse, delta) are [rows, 1] columns and cross HBM as [B, H, S, 1]: a
+(1, 1, bq, 1) block obeys Mosaic's rule that a block's last two dims
+are multiples of (8, 128) or equal to the array's, where a rank-3
+(1, 1, bq) block over [B, H, S] does not. Three kernels then compute the
+gradients, each recomputing ``p = exp(s - lse)`` one block at a time:
 
   * ``_bwd_preprocess_kernel``: ``delta = rowsum(dO * O)`` (the softmax
     Jacobian's diagonal correction), grid over q blocks.
@@ -40,9 +44,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
 
 NEG_INF = -1e30
 
@@ -55,7 +56,7 @@ def _block_and_pad(block: int, s: int) -> tuple:
 
 
 def _pad_seq(x, pad: int):
-    """Zero-pad the sequence axis (axis 2 of [B, H, S, D] / [B, H, S])."""
+    """Zero-pad the sequence axis (axis 2 of [B, H, S, D] / [B, H, S, 1])."""
     if pad == 0:
         return x
     widths = [(0, 0)] * x.ndim
@@ -121,12 +122,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
                            kv_len=kv_len)
         s = jnp.where(mask, s, NEG_INF)
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_prev = m_ref[...]                               # [bq, 1]
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
+        l_ref[...] = l_ref[...] * corr + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * corr + jax.lax.dot(
             p.astype(jnp.float32), v.astype(jnp.float32),
             preferred_element_type=jnp.float32)
         m_ref[...] = m_new
@@ -134,7 +135,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref, acc_ref,
     @pl.when(kv_i == pl.num_programs(3) - 1)
     def _done():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
         lse_ref[0, 0] = m_ref[...] + jnp.log(l)
 
 
@@ -170,23 +171,24 @@ def flash_attention(q, k, v, *, scale: Optional[float] = None,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, qi, ki: (b_, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, qi, ki: (b_, h, qi)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b_, h, qi, ki: (b_, h, qi, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, hq, spq, d), q.dtype),
-            jax.ShapeDtypeStruct((b, hq, spq), jnp.float32),
+            jax.ShapeDtypeStruct((b, hq, spq, 1), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq,), jnp.float32),
-            pltpu.VMEM((bq,), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
+            pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(q_, k_, v_)
-    o, lse = o[:, :, :sq], lse[:, :, :sq]
+    o, lse = o[:, :, :sq], lse[:, :, :sq, 0]
     return (o, lse) if return_lse else o
 
 
@@ -305,7 +307,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32), *operands)
@@ -443,7 +445,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hkv, g * c, d), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table.astype(jnp.int32), meta, *operands)
@@ -454,7 +456,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
 def _bwd_preprocess_kernel(o_ref, do_ref, delta_ref):
     o = o_ref[0, 0].astype(jnp.float32)
     do = do_ref[0, 0].astype(jnp.float32)
-    delta_ref[0, 0] = (o * do).sum(axis=1)
+    delta_ref[0, 0] = (o * do).sum(axis=1, keepdims=True)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
@@ -482,8 +484,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0, 0].astype(jnp.float32)    # [bk, d]
         v = v_ref[0, 0].astype(jnp.float32)    # [bk, d]
         do = do_ref[0, 0].astype(jnp.float32)  # [bq, d]
-        lse = lse_ref[0, 0]                    # [bq] f32
-        delta = delta_ref[0, 0]                # [bq] f32
+        lse = lse_ref[0, 0]                    # [bq, 1] f32
+        delta = delta_ref[0, 0]                # [bq, 1] f32
 
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
@@ -496,13 +498,13 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         mask &= qrow < q_len                   # padded q tail contributes 0
         s = jnp.where(mask, s, NEG_INF)
 
-        p = jnp.exp(s - lse[:, None])          # [bq, bk], recomputed
+        p = jnp.exp(s - lse)                   # [bq, bk], recomputed
         dv_acc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dk_acc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -544,10 +546,10 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                            kv_len=kv_len)
         s = jnp.where(mask, s, NEG_INF)
 
-        p = jnp.exp(s - lse[:, None])
+        p = jnp.exp(s - lse)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dq_acc[...] += jax.lax.dot(ds, k,
                                    preferred_element_type=jnp.float32)
 
@@ -571,7 +573,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
     bq, pq = _block_and_pad(block_q, sq)
     bk, pk = _block_and_pad(block_k, skv)
     q_, o_, do_ = _pad_seq(q, pq), _pad_seq(o, pq), _pad_seq(do, pq)
-    lse_ = _pad_seq(lse.astype(jnp.float32), pq)
+    lse_ = _pad_seq(lse.astype(jnp.float32)[..., None], pq)
     k_, v_ = _pad_seq(k, pk), _pad_seq(v, pk)
     spq, spk = sq + pq, skv + pk
     nqb, nkb = spq // bq, spk // bk
@@ -583,9 +585,10 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, qi: (b_, h, qi, 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, qi: (b_, h, qi, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, bq), lambda b_, h, qi: (b_, h, qi)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, spq), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_specs=pl.BlockSpec((1, 1, bq, 1),
+                               lambda b_, h, qi: (b_, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, spq, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
     )(o_, do_)
@@ -608,12 +611,12 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             pl.BlockSpec((1, 1, bq, d),
                          lambda b_, h, ki, i: (b_, h * g + i // nqb,
                                                i % nqb, 0)),
-            pl.BlockSpec((1, 1, bq),
+            pl.BlockSpec((1, 1, bq, 1),
                          lambda b_, h, ki, i: (b_, h * g + i // nqb,
-                                               i % nqb)),
-            pl.BlockSpec((1, 1, bq),
+                                               i % nqb, 0)),
+            pl.BlockSpec((1, 1, bq, 1),
                          lambda b_, h, ki, i: (b_, h * g + i // nqb,
-                                               i % nqb)),
+                                               i % nqb, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, h, ki, i: (b_, h, ki, 0)),
@@ -627,7 +630,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             pltpu.VMEM((bk, d), jnp.float32),
             pltpu.VMEM((bk, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -646,14 +649,16 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h, qi, ki: (b_, h // g, ki, 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h, qi, ki: (b_, h, qi, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, qi, ki: (b_, h, qi)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h, qi, ki: (b_, h, qi)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b_, h, qi, ki: (b_, h, qi, 0)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b_, h, qi, ki: (b_, h, qi, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d),
                                lambda b_, h, qi, ki: (b_, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, hq, spq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -15,9 +15,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
+from repro.kernels.tiling import pad2, tile
 
 
 def _kernel(x_ref, w_ref, a_ref, b_ref, y_ref, acc_ref, xa_ref, *,
@@ -45,13 +43,19 @@ def _kernel(x_ref, w_ref, a_ref, b_ref, y_ref, acc_ref, xa_ref, *,
 def lora_matmul(x, w, a, b, *, scale: float = 1.0, block_m: int = 256,
                 block_n: int = 256, block_k: int = 512,
                 interpret: bool = False):
-    """x: [M, K]; w: [K, N]; a: [K, r]; b: [r, N] -> [M, N]."""
+    """x: [M, K]; w: [K, N]; a: [K, r]; b: [r, N] -> [M, N]. Any dims:
+    tiles are clamped to legal Mosaic blocks and the operands
+    zero-padded where no legal tile divides a dim (zeros add nothing to
+    the products; the padded output rows/cols are sliced off)."""
     m, kdim = x.shape
     n = w.shape[1]
     r = a.shape[1]
-    bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, kdim)
-    assert m % bm == 0 and n % bn == 0 and kdim % bk == 0
-    grid = (m // bm, n // bn, kdim // bk)
+    bm, mp = tile(block_m, m, 8)
+    bn, np_ = tile(block_n, n, 128)
+    bk, kp = tile(block_k, kdim, 128)
+    x, w = pad2(x, mp, kp), pad2(w, kp, np_)
+    a, b = pad2(a, kp, r), pad2(b, r, np_)
+    grid = (mp // bm, np_ // bn, kp // bk)
 
     kernel = functools.partial(_kernel, scale=scale)
     return pl.pallas_call(
@@ -64,12 +68,12 @@ def lora_matmul(x, w, a, b, *, scale: float = 1.0, block_m: int = 256,
             pl.BlockSpec((r, bn), lambda mi, ni, ki: (0, ni)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda mi, ni, ki: (mi, ni)),
-        out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((mp, np_), x.dtype),
         scratch_shapes=[
             pltpu.VMEM((bm, bn), jnp.float32),
             pltpu.VMEM((bm, r), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(x, w, a, b)
+    )(x, w, a, b)[:m, :n]

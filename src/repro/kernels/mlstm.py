@@ -16,10 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import pallas_tpu_compiler_params
-
-_CompilerParams = pallas_tpu_compiler_params()
-
 
 def _kernel(q_ref, k_ref, v_ref, ig_ref, lf_ref, h_ref,
             c_fin_ref, n_fin_ref, m_fin_ref,
@@ -114,7 +110,7 @@ def mlstm_chunked(q, k, v, ig, lf, *, chunk: int = 64,
             pltpu.VMEM((dh, 1), jnp.float32),
             pltpu.VMEM((1, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, ig, lf)

@@ -1,8 +1,9 @@
 """jit'd public wrappers over the Pallas kernels.
 
-On CPU (this container) the kernels run in interpret mode — the kernel
-body executes in Python for correctness validation; on TPU backends they
-compile to Mosaic. ``interpret=None`` auto-detects.
+On a TPU the kernels compile to Mosaic; on the CPU (where the tests run)
+they run in interpret mode — the kernel body executes in Python for
+correctness validation. ``interpret=None`` picks by backend and refuses
+any other backend.
 
 Autodiff: ``flash_attention_ad`` and ``lora_matmul_ad`` carry
 ``custom_vjp`` rules whose backward passes are themselves kernels —
@@ -26,18 +27,19 @@ from repro.kernels import quantize as _qz
 
 
 def _auto_interpret(interpret: Optional[bool]) -> bool:
+    """Compile with Mosaic on a TPU; interpret on the CPU, where the
+    tests run. Any other backend has no Pallas TPU path, and silently
+    interpreting there would hide that the device is not being used."""
     if interpret is not None:
         return interpret
-    return jax.default_backend() != "tpu"
-
-
-def _fit_block(block: int, dim: int) -> int:
-    """Largest divisor of ``dim`` that is <= ``block`` (tile clamping for
-    kernels that require exact divisibility)."""
-    b = max(1, min(block, dim))
-    while dim % b:
-        b -= 1
-    return b
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels compile only for 'tpu' and interpret only on "
+        f"'cpu'; the default backend is {backend!r}")
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "causal", "window",
@@ -216,12 +218,9 @@ def _lora_ad_fwd(x, w, a, b, scale, block_m, block_n, block_k, interpret):
 
 def _lora_ad_bwd(scale, block_m, block_n, block_k, interpret, res, g):
     x, w, a, b = res
-    m, kdim = x.shape
-    n = w.shape[1]
     dx = _lm.lora_matmul(
-        g, w.T, b.T, a.T, scale=scale,
-        block_m=_fit_block(block_m, m), block_n=_fit_block(block_n, kdim),
-        block_k=_fit_block(block_k, n), interpret=interpret).astype(x.dtype)
+        g, w.T, b.T, a.T, scale=scale, block_m=block_m, block_n=block_n,
+        block_k=block_k, interpret=interpret).astype(x.dtype)
     xf = x.astype(jnp.float32)
     gf = g.astype(jnp.float32)
     dw = (xf.T @ gf).astype(w.dtype)
@@ -238,9 +237,6 @@ _lora_ad.defvjp(_lora_ad_fwd, _lora_ad_bwd)
 def lora_matmul_ad(x, w, a, b, *, scale=1.0, block_m=256, block_n=256,
                    block_k=512, interpret=None):
     """Differentiable fused LoRA matmul (closed-form VJP; dx reuses the
-    fused kernel). Tiles are clamped to valid divisors of each dim."""
-    m, kdim = x.shape
-    n = w.shape[1]
-    return _lora_ad(x, w, a, b, float(scale),
-                    _fit_block(block_m, m), _fit_block(block_n, n),
-                    _fit_block(block_k, kdim), _auto_interpret(interpret))
+    fused kernel). Tiles are clamped to legal Mosaic blocks per dim."""
+    return _lora_ad(x, w, a, b, float(scale), int(block_m), int(block_n),
+                    int(block_k), _auto_interpret(interpret))

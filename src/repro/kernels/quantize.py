@@ -18,18 +18,27 @@ the differentiated path.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.core.compat import pallas_tpu_compiler_params
+from repro.kernels.tiling import pad2, tile
 
-_CompilerParams = pallas_tpu_compiler_params()
 
 LANES = 128          #: fixed lane width of the quantization row layout
 QMAX = 127.0         #: symmetric int8 range
+ROW_ALIGN = 32       #: int8 sublane tile: row blocks are multiples of this
+
+
+def uniform24(bits):
+    """u ~ U[0, 1) from uint32 random bits: the top 24 bits, scaled by
+    2**-24. Goes through int32 because Mosaic has no uint32 -> float32
+    cast; every 24-bit integer is exact in float32, and 2**31 maps to
+    exactly 0.5."""
+    top = jax.lax.shift_right_logical(
+        jax.lax.bitcast_convert_type(bits, jnp.int32), 8)
+    return top.astype(jnp.float32) * (2.0 ** -24)
 
 
 def _quant_kernel(x_ref, bits_ref, q_ref, scale_ref):
@@ -38,7 +47,7 @@ def _quant_kernel(x_ref, bits_ref, q_ref, scale_ref):
     scale = jnp.where(absmax > 0.0, absmax / QMAX, 1.0)
     scale_ref[...] = jnp.where(absmax > 0.0, scale, 0.0)
     # unbiased stochastic rounding: E[floor(s + u)] = s for u ~ U[0, 1)
-    u = bits_ref[...].astype(jnp.float32) * (2.0 ** -32)
+    u = uniform24(bits_ref[...])
     s = x / scale
     q = jnp.clip(jnp.floor(s + u), -QMAX, QMAX)
     q_ref[...] = q.astype(jnp.int8)
@@ -47,13 +56,6 @@ def _quant_kernel(x_ref, bits_ref, q_ref, scale_ref):
 def _dequant_kernel(q_ref, scale_ref, x_ref):
     q = q_ref[...].astype(jnp.float32)
     x_ref[...] = (q * scale_ref[...]).astype(x_ref.dtype)
-
-
-def _row_blocks(m: int, block_rows: int) -> int:
-    b = max(1, min(block_rows, m))
-    while m % b:
-        b -= 1
-    return b
 
 
 def quantize_int8(x, bits, *, block_rows: int = 256,
@@ -65,11 +67,10 @@ def quantize_int8(x, bits, *, block_rows: int = 256,
     m, n = x.shape
     assert n == LANES, f"quantize rows must be {LANES} lanes wide, got {n}"
     assert bits.shape == x.shape
-    bm = _row_blocks(m, block_rows)
-    grid = (m // bm,)
-    return pl.pallas_call(
+    bm, mp = tile(block_rows, m, ROW_ALIGN)   # zero rows: q 0, scale 0
+    q, scale = pl.pallas_call(
         _quant_kernel,
-        grid=grid,
+        grid=(mp // bm,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda mi: (mi, 0)),
             pl.BlockSpec((bm, n), lambda mi: (mi, 0)),
@@ -79,13 +80,14 @@ def quantize_int8(x, bits, *, block_rows: int = 256,
             pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((m, n), jnp.int8),
-            jax.ShapeDtypeStruct((m, 1), jnp.float32),
+            jax.ShapeDtypeStruct((mp, n), jnp.int8),
+            jax.ShapeDtypeStruct((mp, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(x, bits)
+    )(pad2(x, mp, n), pad2(bits, mp, n))
+    return q[:m], scale[:m]
 
 
 def dequantize_int8(q, scale, *, dtype=jnp.float32, block_rows: int = 256,
@@ -94,18 +96,18 @@ def dequantize_int8(q, scale, *, dtype=jnp.float32, block_rows: int = 256,
     m, n = q.shape
     assert n == LANES
     assert scale.shape == (m, 1)
-    bm = _row_blocks(m, block_rows)
-    grid = (m // bm,)
-    return pl.pallas_call(
+    bm, mp = tile(block_rows, m, ROW_ALIGN)
+    x = pl.pallas_call(
         _dequant_kernel,
-        grid=grid,
+        grid=(mp // bm,),
         in_specs=[
             pl.BlockSpec((bm, n), lambda mi: (mi, 0)),
             pl.BlockSpec((bm, 1), lambda mi: (mi, 0)),
         ],
         out_specs=pl.BlockSpec((bm, n), lambda mi: (mi, 0)),
-        out_shape=jax.ShapeDtypeStruct((m, n), dtype),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((mp, n), dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
-    )(q, scale)
+    )(pad2(q, mp, n), pad2(scale, mp, 1))
+    return x[:m]

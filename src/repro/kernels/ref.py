@@ -159,7 +159,7 @@ def quantize_int8_ref(x, bits):
     xf = x.astype(jnp.float32)
     absmax = jnp.max(jnp.abs(xf), axis=1, keepdims=True)
     safe = jnp.where(absmax > 0.0, absmax / 127.0, 1.0)
-    u = bits.astype(jnp.float32) * (2.0 ** -32)
+    u = (bits >> 8).astype(jnp.float32) * (2.0 ** -24)   # top 24 bits
     q = jnp.clip(jnp.floor(xf / safe + u), -127.0, 127.0).astype(jnp.int8)
     scale = jnp.where(absmax > 0.0, safe, 0.0)
     return q, scale

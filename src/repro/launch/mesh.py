@@ -18,13 +18,8 @@ import jax
 
 
 def _mk(shape, axes):
-    # axis_types only exists on newer jax; older versions default to Auto
-    try:
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    except (AttributeError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 #: public alias used by repro.api.MeshSpec
@@ -46,13 +41,3 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
     if pod:
         return _mk((pod, data, model), ("pod", "data", "model"))
     return _mk((data, model), ("data", "model"))
-
-
-def require_host_devices(n: int) -> None:
-    """Assert the forced-host-platform device count is available."""
-    have = len(jax.devices())
-    if have < n:
-        raise RuntimeError(
-            f"need {n} devices, have {have}; set "
-            f"XLA_FLAGS=--xla_force_host_platform_device_count={n} before "
-            f"the first jax import")

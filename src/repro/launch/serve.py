@@ -65,7 +65,9 @@ def main():
     args = ap.parse_args()
 
     from repro.api import MeshSpec, Session
+    from repro.launch.compile_cache import use_compile_cache
 
+    use_compile_cache()
     session = Session(args.arch, full=args.full, strategy="tensor",
                       seed=args.seed,
                       mesh=MeshSpec((1,), axes=("data",),

@@ -1,8 +1,13 @@
 """Training launcher — a thin CLI over :class:`repro.api.Session`.
 
-On the container (CPU) this runs REDUCED variants on a small forced-host
-mesh; on a real TPU slice the same flags drive the full configs on the
-production mesh. The FHDP strategy is the paper's system; ``tensor`` is
+On the CPU (``JAX_PLATFORMS=cpu``) this runs the REDUCED variants on a
+small forced-host mesh, with the Pallas kernels interpreted. On a TPU the
+kernels compile to Mosaic and ``--full`` selects the published widths;
+the mesh must fit the chips present, so one chip needs ``--mesh 1``.
+``chip_smoke.py`` at the repo root is what has run on a TPU v5e chip:
+``distill_fl`` over the full flad-adllm, and ``pipeline`` over the full
+flad-vision on a (2, 2) mesh of four chips. The FHDP strategy is the
+paper's system; ``tensor`` is
 the datacenter-style baseline; ``fedavg``/``fl_pipeline`` run FedAvg
 rounds instead of steps. All wiring (mesh, devices, strategy, hooks)
 lives in :mod:`repro.api` — this file only parses flags.
@@ -83,7 +88,10 @@ def main():
     args = ap.parse_args()
 
     from repro.api import LoopHooks, MeshSpec, Session
+    from repro.launch.compile_cache import use_compile_cache
     from repro.recovery.backup import EdgeBackup
+
+    use_compile_cache()
 
     options = {}
     fl = args.strategy in ("fedavg", "fl_pipeline", "hier_fl",

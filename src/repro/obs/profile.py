@@ -5,8 +5,8 @@ Two complementary levels:
 
   * :func:`profiled` — a context manager wrapping the jitted hot loop in
     a ``jax.profiler`` trace when a capture directory is set (view the
-    result in TensorBoard / Perfetto). Zero-cost no-op when disabled or
-    when the profiler is unavailable in this jax build.
+    result in TensorBoard / Perfetto). Zero-cost no-op when disabled; a
+    requested capture that the profiler cannot take raises.
   * :func:`kernel_cost_args` — static per-kernel cost annotations for
     span ``args``: padded tokens and attention MACs priced through the
     same :class:`repro.serve.loadgen.PrefillCostModel` accounting the
@@ -45,11 +45,7 @@ def profiled(options: Optional[ProfileOptions] = None):
     if options is None or options.jax_trace_dir is None:
         yield
         return
-    try:
-        import jax.profiler as _prof
-    except Exception:                      # pragma: no cover - jax stub
-        yield
-        return
+    import jax.profiler as _prof
     with _prof.trace(options.jax_trace_dir,
                      create_perfetto_link=options.create_perfetto_link):
         yield

@@ -78,6 +78,56 @@ def test_meshspec_parse_and_axes():
         MeshSpec.parse("2,2,2,2")
 
 
+def test_meshspec_forces_host_devices_only_on_pinned_cpu(monkeypatch):
+    """Off the CPU (here: a platform that is not pinned to cpu) building
+    a mesh leaves XLA_FLAGS alone; on the pinned CPU it forces the host
+    device count, as before."""
+    import os
+
+    from repro.api import mesh as api_mesh
+    jax.devices()          # backend up first: the flags below are only read
+    monkeypatch.setenv("XLA_FLAGS", "--xla_cpu_enable_fast_math=false")
+    for pinned, forced in ((False, False), (True, True)):
+        monkeypatch.setattr(api_mesh, "_devices_locked", False)
+        monkeypatch.setattr(api_mesh, "_cpu_pinned", lambda p=pinned: p)
+        MeshSpec((2,)).build()
+        flags = os.environ["XLA_FLAGS"]
+        assert ("--xla_force_host_platform_device_count=2" in flags) \
+            is forced, (pinned, flags)
+        assert flags.startswith("--xla_cpu_enable_fast_math=false")
+
+
+def test_meshspec_larger_than_devices_names_platform_and_count(monkeypatch):
+    from repro.api import mesh as api_mesh
+    monkeypatch.setattr(api_mesh, "_cpu_pinned", lambda: False)
+    have = len(jax.devices())
+    with pytest.raises(RuntimeError,
+                       match=f"need {have + 1} devices, have {have} cpu"):
+        MeshSpec((have + 1,)).build()
+
+
+def test_compile_cache_env_wins_else_fixed_checkout_path(monkeypatch,
+                                                         tmp_path):
+    """JAX_COMPILATION_CACHE_DIR is used as set; without it the cache is
+    the fixed <checkout>/.jax_cache (a moving path would never hit)."""
+    import pathlib
+
+    from repro.launch import compile_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+        assert cc.use_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        assert cc.use_compile_cache() == str(cc.DEFAULT_DIR)
+        assert jax.config.jax_compilation_cache_dir == str(cc.DEFAULT_DIR)
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert cc.DEFAULT_DIR == root / ".jax_cache"
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)
+
+
 def test_session_accepts_concrete_mesh(mesh24):
     ses = _session("tensor", mesh24)
     assert ses.mesh is mesh24

@@ -127,6 +127,7 @@ def test_lora_matmul(dtype, m, k, n, r, scale):
 @pytest.mark.parametrize("m,k,n,r,scale", [
     (128, 256, 192, 8, 0.5),
     (100, 96, 132, 4, 1.0),    # dims not multiples of the tile
+    (300, 384, 136, 4, 2.0),   # no legal tile divides M or N: padded
 ])
 def test_lora_matmul_grad(dtype, m, k, n, r, scale):
     """lora_matmul_ad's closed-form VJP vs jax.vjp over the oracle (the
@@ -170,7 +171,8 @@ def test_flash_attention_matches_model_attention():
     assert float(jnp.max(jnp.abs(xla - pall))) < 5e-5
 
 
-@pytest.mark.parametrize("m,block_rows", [(8, 256), (520, 256), (96, 32)])
+@pytest.mark.parametrize("m,block_rows", [(8, 256), (520, 256), (96, 32),
+                                          (300, 256), (41, 40)])
 def test_quantize_int8_matches_ref(m, block_rows):
     ks = jax.random.split(KEY, 2)
     x = jax.random.normal(ks[0], (m, 128), jnp.float32) * 3.0
@@ -185,6 +187,47 @@ def test_quantize_int8_matches_ref(m, block_rows):
     got = ops.dequantize_int8(q, s, block_rows=block_rows, interpret=True)
     want = ref.dequantize_int8_ref(q_ref, s_ref)
     assert jnp.allclose(got, want)
+
+
+def test_uniform24_is_exact_and_below_one():
+    """The kernel's u = top 24 bits * 2**-24 (no uint32 -> float32 cast
+    on the chip): exact in float32, in [0, 1), and 2**31 -> 0.5 exactly,
+    the round-to-nearest word the int8 KV cache pins."""
+    from repro.kernels.quantize import uniform24
+    from repro.serve.kvcache import NEAREST_BITS
+    bits = jnp.asarray([0, 255, 256, 1 << 31, (1 << 32) - 1], jnp.uint32)
+    u = uniform24(bits)
+    want = jnp.asarray([0.0, 0.0, 2.0 ** -24, 0.5, 1.0 - 2.0 ** -24])
+    assert jnp.array_equal(u, want)
+    assert float(uniform24(NEAREST_BITS)) == 0.5
+    rand = jax.random.bits(KEY, (64, 128), jnp.uint32)
+    assert jnp.array_equal(uniform24(rand),
+                           (rand >> 8).astype(jnp.float32) * 2.0 ** -24)
+
+
+def test_quantize_int8_nearest_bits_round_to_nearest():
+    """With every bit word pinned to NEAREST_BITS the kernel rounds
+    floor(x / scale + 0.5) — the int8 KV cache's deterministic contract."""
+    from repro.serve.kvcache import NEAREST_BITS
+    x = jax.random.normal(KEY, (40, 128), jnp.float32)
+    bits = jnp.full(x.shape, NEAREST_BITS, jnp.uint32)
+    q, s = ops.quantize_int8(x, bits, interpret=True)
+    scale = jnp.max(jnp.abs(x), axis=1, keepdims=True) / 127.0
+    want = jnp.clip(jnp.floor(x / scale + 0.5), -127, 127).astype(jnp.int8)
+    assert jnp.array_equal(q, want)
+
+
+def test_auto_interpret_follows_the_backend(monkeypatch):
+    """Mosaic on a TPU, interpret on the CPU, and an error anywhere
+    else: no backend silently falls back to interpreted kernels."""
+    for backend, want in (("tpu", False), ("cpu", True)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert ops._auto_interpret(None) is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops._auto_interpret(None)
+    assert ops._auto_interpret(True) is True      # explicit choice wins
+    assert ops._auto_interpret(False) is False
 
 
 def test_quantize_int8_error_bound_and_zero_rows():

@@ -475,6 +475,14 @@ def test_profiled_disabled_is_a_noop():
         pass
 
 
+def test_profiled_capture_without_profiler_raises(monkeypatch, tmp_path):
+    """A requested capture is never silently dropped."""
+    monkeypatch.setitem(sys.modules, "jax.profiler", None)
+    with pytest.raises(ImportError):
+        with profiled(ProfileOptions(jax_trace_dir=str(tmp_path))):
+            pass
+
+
 def test_kernel_cost_args_prices_through_the_cost_model():
     cm = PrefillCostModel(s_per_token=1e-3, s_per_mac=1e-6)
     args = kernel_cost_args(padded_tokens=10, attn_mac=100, cost_model=cm)

@@ -1,0 +1,131 @@
+"""The main-path Pallas kernels compile for a TPU v5e at full flad-adllm
+widths (Hq=16, Hkv=8, head_dim 64, d_model 1024, bf16).
+
+Nothing runs: each test lowers a kernel for a described (not attached)
+v5e chip and compiles it with the TPU compiler, which refuses what
+interpret mode accepts (block shapes off the (8, 128) tiling, casts
+Mosaic lacks). The topology is described inside a module fixture, never
+at import: only one process at a time may load the TPU library.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+HQ, HKV, D, DMODEL = 16, 8, 64, 1024
+BF16 = jnp.bfloat16
+SLOTS, BS, T = 8, 16, 35              # 8 lanes, 16-token blocks, 560 tokens
+NB = 1 + SLOTS * T + 1                 # null block + lanes + headroom
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def sds(topo):
+    """ShapeDtypeStruct factory on one described v5e chip; the persistent
+    compilation cache stays off (its entries could not be read back
+    without a chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    yield lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _mosaic_calls(fn, *args) -> int:
+    hlo = jax.jit(fn).lower(*args).compile().as_text()
+    return hlo.count('custom_call_target="tpu_custom_call"')
+
+
+def _qkv(sds, s=1024):
+    return (sds((1, HQ, s, D), BF16), sds((1, HKV, s, D), BF16),
+            sds((1, HKV, s, D), BF16))
+
+
+def test_flash_forward_compiles(sds):
+    fwd = functools.partial(ops.flash_attention, return_lse=True,
+                            interpret=False)
+    assert _mosaic_calls(fwd, *_qkv(sds)) == 1
+
+
+def test_flash_backward_compiles(sds):
+    def fwd_bwd(q, k, v, g):
+        attn = functools.partial(ops.flash_attention_ad, interpret=False)
+        _, vjp = jax.vjp(attn, q, k, v)
+        return vjp(g)
+
+    # forward + the preprocess, dK/dV and dQ kernels
+    assert _mosaic_calls(fwd_bwd, *_qkv(sds),
+                         sds((1, HQ, 1024, D), BF16)) == 4
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_decode_compiles(sds, int8):
+    pool = sds((HKV, NB, BS, D), jnp.int8 if int8 else BF16)
+    scales = sds((HKV, NB, BS, 1), jnp.float32) if int8 else None
+
+    def decode(q, kp, vp, tbl, ctx, ks, vs):
+        return ops.paged_decode_attention(q, kp, vp, tbl, ctx, k_scales=ks,
+                                          v_scales=vs, interpret=False)
+
+    assert _mosaic_calls(decode, sds((SLOTS, HQ, D), BF16), pool, pool,
+                         sds((SLOTS, T), jnp.int32),
+                         sds((SLOTS,), jnp.int32), scales, scales) == 1
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_prefill_compiles(sds, int8):
+    pool = sds((HKV, NB, BS, D), jnp.int8 if int8 else BF16)
+    scales = sds((HKV, NB, BS, 1), jnp.float32) if int8 else None
+
+    def prefill(q, kp, vp, tbl, off, ctx, ks, vs):
+        return ops.paged_prefill_attention(q, kp, vp, tbl, off, ctx,
+                                           k_scales=ks, v_scales=vs,
+                                           interpret=False)
+
+    scalar = sds((), jnp.int32)
+    assert _mosaic_calls(prefill, sds((HQ, 16, D), BF16), pool, pool,
+                         sds((T,), jnp.int32), scalar, scalar, scales,
+                         scales) == 1
+
+
+# 300 rows used to pick a 150-row tile, which is not a multiple of 8
+@pytest.mark.parametrize("rows", [512, 300])
+def test_quantize_roundtrip_compiles(sds, rows):
+    def roundtrip(x, bits):
+        q, scale = ops.quantize_int8(x, bits, interpret=False)
+        return ops.dequantize_int8(q, scale, interpret=False)
+
+    assert _mosaic_calls(roundtrip, sds((rows, 128), jnp.float32),
+                         sds((rows, 128), jnp.uint32)) == 2
+
+
+def test_lora_matmul_forward_and_dx_compile(sds):
+    """wk at full width: x [1088, 1024] (8 x (128 tokens + 8 feature
+    tokens)), w [1024, 512], rank 4; the forward and the closed-form dx
+    both run the fused kernel."""
+    def fwd_dx(x, w, a, b, g):
+        f = functools.partial(ops.lora_matmul_ad, scale=2.0,
+                              interpret=False)
+        y, vjp = jax.vjp(lambda x_: f(x_, w, a, b), x)
+        return y, vjp(g)
+
+    m, n, r = 8 * 136, HKV * D, 4
+    assert _mosaic_calls(fwd_dx, sds((m, DMODEL), BF16),
+                         sds((DMODEL, n), BF16), sds((DMODEL, r), BF16),
+                         sds((r, n), BF16), sds((m, n), BF16)) == 2
