@@ -187,6 +187,7 @@ def flash_attention(q, k, v, *, scale: Optional[float] = None,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(q_, k_, v_)
     o, lse = o[:, :, :sq], lse[:, :, :sq, 0]
     return (o, lse) if return_lse else o
@@ -310,6 +311,7 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32), *operands)
     return o.reshape(b, hq, d)
 
@@ -448,6 +450,7 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="paged_prefill_attention",
     )(block_table.astype(jnp.int32), meta, *operands)
     return o.reshape(hkv, g, c, d).reshape(hq, c, d)
 
@@ -591,6 +594,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel")),
         interpret=interpret,
+        name="flash_attention_bwd_delta",
     )(o_, do_)
 
     # dK/dV: grid over KV blocks; the sequential inner dim walks the GQA
@@ -634,6 +638,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(q_, k_, v_, do_, lse_, delta)
 
     dq_kernel = functools.partial(
@@ -662,6 +667,7 @@ def flash_attention_bwd(q, k, v, o, lse, do, *,
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(q_, k_, v_, do_, lse_, delta)
 
     return dq[:, :, :sq], dk[:, :, :skv], dv[:, :, :skv]

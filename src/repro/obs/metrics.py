@@ -11,8 +11,8 @@ correlate them:
   * the event engine publishes per-edge uplink/backhaul byte counters,
     the observed-staleness histogram, and the migration counter;
   * the continuous scheduler publishes block-pool occupancy (+ its
-    high-watermark, via ``BlockAllocator.free_blocks``), prefix
-    hits/misses, decode tokens, and padded-token waste.
+    high-watermark) every step, and its speculative-acceptance
+    histogram and preemption counter.
 
 Instruments are host-side and allocation-light: a dict update per
 publish, no tensors, no PRNG — publishing cannot perturb a run.

@@ -1,8 +1,12 @@
-"""Profiling hooks: optional ``jax.profiler`` capture + static kernel
-cost annotations.
+"""Profiling hooks: ``jax.profiler`` capture, spans on the profiler's
+clock, and static kernel cost annotations.
 
-Two complementary levels:
+Three pieces:
 
+  * :func:`span` — a named host span recorded by ``jax.profiler`` on the
+    same clock as the device planes of a capture (the serving scheduler
+    marks the phases of each step with it). Outside a capture it costs
+    about a microsecond and records nothing; there is no other switch.
   * :func:`profiled` — a context manager wrapping the jitted hot loop in
     a ``jax.profiler`` trace when a capture directory is set (view the
     result in TensorBoard / Perfetto). Zero-cost no-op when disabled; a
@@ -20,6 +24,16 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 from typing import Dict, Optional
+
+from jax.profiler import TraceAnnotation
+
+
+def span(name: str) -> TraceAnnotation:
+    """``with span("scheduler.decode"): ...`` records a wall-clock span
+    named ``name`` in a running ``jax.profiler`` capture (it lands in
+    the capture's ``.xplane.pb``, host plane, on the clock of the device
+    planes). With no capture running nothing is recorded."""
+    return TraceAnnotation(name)
 
 
 @dataclasses.dataclass(frozen=True)

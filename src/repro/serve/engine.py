@@ -107,7 +107,9 @@ class PagedEngine:
         chunked-prefill Pallas kernel. Returns (logits [1, V] of the
         chunk's last true row — only meaningful on the final chunk — and
         the updated pools). Mirrors ``_decode_impl`` op for op so chunked
-        and monolithic prefill agree bit-for-bit in greedy streams."""
+        and monolithic prefill agree bit-for-bit in greedy streams.
+        Its phases carry the same ``jax.named_scope`` names as
+        ``_decode_impl``'s."""
         cfg, spec = self.cfg, self.spec
         nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
         c = tokens.shape[0]
@@ -125,44 +127,49 @@ class PagedEngine:
             h_in = carry
             lp, layer_pools = layer
             ap = lp["attn"]
-            h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
-            q = h @ ap["wq"]
-            k = h @ ap["wk"]
-            v = h @ ap["wv"]
-            if "bq" in ap:
-                q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
-            q = B._split_heads(q, nq, hd)                  # [1, Hq, C, D]
-            k = B._split_heads(k, nkv, hd)
-            v = B._split_heads(v, nkv, hd)
-            if "q_norm" in ap:
-                q = B._head_rmsnorm(q, ap["q_norm"], cfg.norm_eps)
-                k = B._head_rmsnorm(k, ap["k_norm"], cfg.norm_eps)
-            q = B.rope(q, positions, cfg.rope_theta)
-            k = B.rope(k, positions, cfg.rope_theta)
+            with jax.named_scope("attention"):
+                h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
+                q = h @ ap["wq"]
+                k = h @ ap["wk"]
+                v = h @ ap["wv"]
+                if "bq" in ap:
+                    q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
+                q = B._split_heads(q, nq, hd)              # [1, Hq, C, D]
+                k = B._split_heads(k, nkv, hd)
+                v = B._split_heads(v, nkv, hd)
+                if "q_norm" in ap:
+                    q = B._head_rmsnorm(q, ap["q_norm"], cfg.norm_eps)
+                    k = B._head_rmsnorm(k, ap["k_norm"], cfg.norm_eps)
+                q = B.rope(q, positions, cfg.rope_theta)
+                k = B.rope(k, positions, cfg.rope_theta)
 
-            new_pools = KC.append_token(layer_pools, spec, k[0], v[0],
-                                        phys, off)
-            from repro.kernels import ops as kops
-            o = kops.paged_prefill_attention(
-                q[0], new_pools["k"], new_pools["v"], table,
-                q_offset, q_offset + chunk_len, scale=scale,
-                k_scales=new_pools.get("k_scale"),
-                v_scales=new_pools.get("v_scale"))         # [Hq, C, D]
-            h_in = h_in + (o.transpose(1, 0, 2).reshape(1, c, nq * hd)
-                           @ ap["wo"]).astype(h_in.dtype)
-            hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
-            if "moe" in lp:
-                f, _ = B.moe_block(lp["moe"], hh, cfg)
-            else:
-                f = B.mlp(lp["ffn"], hh)
+                with jax.named_scope("kv_write"):
+                    new_pools = KC.append_token(layer_pools, spec, k[0],
+                                                v[0], phys, off)
+                from repro.kernels import ops as kops
+                o = kops.paged_prefill_attention(
+                    q[0], new_pools["k"], new_pools["v"], table,
+                    q_offset, q_offset + chunk_len, scale=scale,
+                    k_scales=new_pools.get("k_scale"),
+                    v_scales=new_pools.get("v_scale"))     # [Hq, C, D]
+                h_in = h_in + (o.transpose(1, 0, 2).reshape(1, c, nq * hd)
+                               @ ap["wo"]).astype(h_in.dtype)
+            with jax.named_scope("mlp"):
+                hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
+                if "moe" in lp:
+                    f, _ = B.moe_block(lp["moe"], hh, cfg)
+                else:
+                    f = B.mlp(lp["ffn"], hh)
             return h_in + f, new_pools
 
         x, new_pools = jax.lax.scan(body, x, (params["blocks"], pools))
-        h = B.rms_norm(params["ln_f"], x[:, chunk_len - 1], cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = B.unembed(params["embed"], h[:, None])[:, 0]
-        else:
-            logits = B.linear(params["head"], h).astype(jnp.float32)
+        with jax.named_scope("head"):
+            h = B.rms_norm(params["ln_f"], x[:, chunk_len - 1],
+                           cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = B.unembed(params["embed"], h[:, None])[:, 0]
+            else:
+                logits = B.linear(params["head"], h).astype(jnp.float32)
         return logits, new_pools
 
     def prefill_chunk(self, params, pools, tokens, table, q_offset,
@@ -186,7 +193,11 @@ class PagedEngine:
         tokens: [slots] int32 (the pending token per lane); tables:
         [slots, T] int32; ctx_lens: [slots] int32 (KV written so far —
         the pending token's position). Returns (logits [slots, V],
-        updated pools)."""
+        updated pools). Each layer's phases run under ``jax.named_scope``
+        names that device traces keep in the operations' metadata:
+        ``attention`` (norm, projections, rope, the paged kernel, the
+        output projection), ``attention/kv_write`` (the token's K/V
+        into its pool block), ``mlp``; then ``head``."""
         cfg, spec = self.cfg, self.spec
         nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
         slots = tokens.shape[0]
@@ -202,46 +213,51 @@ class PagedEngine:
             h_in = carry
             lp, layer_pools = layer
             ap = lp["attn"]
-            h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
-            q = h @ ap["wq"]
-            k = h @ ap["wk"]
-            v = h @ ap["wv"]
-            if "bq" in ap:
-                q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
-            q = B._split_heads(q, nq, hd)                  # [slots,Hq,1,D]
-            k = B._split_heads(k, nkv, hd)
-            v = B._split_heads(v, nkv, hd)
-            if "q_norm" in ap:
-                q = B._head_rmsnorm(q, ap["q_norm"], cfg.norm_eps)
-                k = B._head_rmsnorm(k, ap["k_norm"], cfg.norm_eps)
-            q = B.rope(q, positions, cfg.rope_theta)
-            k = B.rope(k, positions, cfg.rope_theta)
+            with jax.named_scope("attention"):
+                h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
+                q = h @ ap["wq"]
+                k = h @ ap["wk"]
+                v = h @ ap["wv"]
+                if "bq" in ap:
+                    q, k, v = q + ap["bq"], k + ap["bk"], v + ap["bv"]
+                q = B._split_heads(q, nq, hd)              # [slots,Hq,1,D]
+                k = B._split_heads(k, nkv, hd)
+                v = B._split_heads(v, nkv, hd)
+                if "q_norm" in ap:
+                    q = B._head_rmsnorm(q, ap["q_norm"], cfg.norm_eps)
+                    k = B._head_rmsnorm(k, ap["k_norm"], cfg.norm_eps)
+                q = B.rope(q, positions, cfg.rope_theta)
+                k = B.rope(k, positions, cfg.rope_theta)
 
-            k_tok = k[:, :, 0].transpose(1, 0, 2)          # [Hkv,slots,D]
-            v_tok = v[:, :, 0].transpose(1, 0, 2)
-            new_pools = KC.append_token(layer_pools, spec, k_tok, v_tok,
-                                        phys, off)
-            from repro.kernels import ops as kops
-            o = kops.paged_decode_attention(
-                q[:, :, 0], new_pools["k"], new_pools["v"], tables,
-                ctx_lens + 1, scale=scale,
-                k_scales=new_pools.get("k_scale"),
-                v_scales=new_pools.get("v_scale"))         # [slots,Hq,D]
-            h_in = h_in + (o.reshape(slots, 1, nq * hd)
-                           @ ap["wo"]).astype(h_in.dtype)
-            hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
-            if "moe" in lp:
-                f, _ = B.moe_block(lp["moe"], hh, cfg)
-            else:
-                f = B.mlp(lp["ffn"], hh)
+                with jax.named_scope("kv_write"):
+                    k_tok = k[:, :, 0].transpose(1, 0, 2)  # [Hkv,slots,D]
+                    v_tok = v[:, :, 0].transpose(1, 0, 2)
+                    new_pools = KC.append_token(layer_pools, spec, k_tok,
+                                                v_tok, phys, off)
+                from repro.kernels import ops as kops
+                o = kops.paged_decode_attention(
+                    q[:, :, 0], new_pools["k"], new_pools["v"], tables,
+                    ctx_lens + 1, scale=scale,
+                    k_scales=new_pools.get("k_scale"),
+                    v_scales=new_pools.get("v_scale"))     # [slots,Hq,D]
+                h_in = h_in + (o.reshape(slots, 1, nq * hd)
+                               @ ap["wo"]).astype(h_in.dtype)
+            with jax.named_scope("mlp"):
+                hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
+                if "moe" in lp:
+                    f, _ = B.moe_block(lp["moe"], hh, cfg)
+                else:
+                    f = B.mlp(lp["ffn"], hh)
             return h_in + f, new_pools
 
         x, new_pools = jax.lax.scan(body, x, (params["blocks"], pools))
-        x = B.rms_norm(params["ln_f"], x, cfg.norm_eps)
-        if cfg.tie_embeddings:
-            logits = B.unembed(params["embed"], x)[:, 0]
-        else:
-            logits = B.linear(params["head"], x).astype(jnp.float32)[:, 0]
+        with jax.named_scope("head"):
+            x = B.rms_norm(params["ln_f"], x, cfg.norm_eps)
+            if cfg.tie_embeddings:
+                logits = B.unembed(params["embed"], x)[:, 0]
+            else:
+                logits = B.linear(params["head"], x).astype(
+                    jnp.float32)[:, 0]
         return logits, new_pools
 
     def decode(self, params, pools, tokens, tables, ctx_lens) -> Tuple:

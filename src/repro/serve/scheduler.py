@@ -67,6 +67,20 @@ per-lane context rewinds to the accepted length. Lanes near completion
 shrink their window to the tokens they may still emit, which keeps
 every append inside the blocks reserved at admission.
 
+Profiler spans: :meth:`step` marks its phases with
+:func:`repro.obs.profile.span`, on the clock of a running ``jax.profiler``
+capture (nothing is recorded without one). The spans of one step are
+disjoint and cover it: ``scheduler.admit`` opens every step (admission,
+a copy-on-write block copy's dispatch); ``scheduler.prefill`` is the
+prefill unit's host work up to its dispatch; ``scheduler.decode`` builds
+the decode inputs, copies them to the device and dispatches the decode;
+``scheduler.tokens`` is each wait for sampled tokens (key fold-in,
+sampler, read back to host integers: a prefill's first token, then the
+decode's); ``scheduler.commit`` is the bookkeeping after a prefill's
+last chunk or a token read (appending tokens, retiring, registry
+samples). The speculative path's draft and verify round trips carry no
+span of their own.
+
 Determinism: greedy decoding makes the token streams a pure function of
 (params, prompts) — per-request streams are bit-identical between the two
 policies AND the two prefill modes for the dense family (each lane's
@@ -88,6 +102,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.profile import span
 from repro.serve import kvcache as KC
 from repro.serve.engine import DraftEngine, PagedEngine
 
@@ -507,27 +522,31 @@ class ContinuousScheduler:
             # exact); pin it rather than re-emitting into the stream.
             first = int(req.tokens[-1])
         else:
-            first = int(self.sampler(logits, self._next_key())[0])
-            req.tokens.append(first)
-            req.t_first_token = t
-            self.step_events.append(req)
-            if self.tracer is not None:
-                def emit(t_end, cost_model, *, req=req, slot=slot):
-                    from repro.obs import trace as T
-                    self.tracer.instant(
-                        "first_token", req.t_first_token, pid=T.SERVE_PID,
-                        tid=T.lane_tid(slot), cat="ttft",
-                        args={"trace_id": req.trace_id, "rid": req.rid,
-                              "ttft_s": req.ttft_s})
-                self._pending_trace.append(emit)
-            self.total_new_tokens += 1
-        self.ctx[slot] = len(chain)
-        self.pending_tok[slot] = first
-        self.prefill_done[slot] = True
-        if self.prefix is not None:
-            self.prefix.insert(chain, self.tables[slot])
-        if len(req.tokens) >= req.max_new_tokens:
-            self._retire(slot, t)
+            with span("scheduler.tokens"):
+                first = int(self.sampler(logits, self._next_key())[0])
+        with span("scheduler.commit"):
+            if not resumed:
+                req.tokens.append(first)
+                req.t_first_token = t
+                self.step_events.append(req)
+                if self.tracer is not None:
+                    def emit(t_end, cost_model, *, req=req, slot=slot):
+                        from repro.obs import trace as T
+                        self.tracer.instant(
+                            "first_token", req.t_first_token,
+                            pid=T.SERVE_PID, tid=T.lane_tid(slot),
+                            cat="ttft",
+                            args={"trace_id": req.trace_id, "rid": req.rid,
+                                  "ttft_s": req.ttft_s})
+                    self._pending_trace.append(emit)
+                self.total_new_tokens += 1
+            self.ctx[slot] = len(chain)
+            self.pending_tok[slot] = first
+            self.prefill_done[slot] = True
+            if self.prefix is not None:
+                self.prefix.insert(chain, self.tables[slot])
+            if len(req.tokens) >= req.max_new_tokens:
+                self._retire(slot, t)
 
     def _run_prefill(self, t: float) -> None:
         """Run AT MOST ONE prefill unit: the oldest admitted lane still
@@ -542,6 +561,14 @@ class ContinuousScheduler:
         if not self._prefill_queue:
             return
         slot = self._prefill_queue[0]
+        with span("scheduler.prefill"):
+            logits = self._dispatch_prefill(slot, t)
+        if logits is not None:
+            self._finish_prefill(slot, logits, t)
+
+    def _dispatch_prefill(self, slot: int, t: float):
+        """The prefill unit's host work up to its dispatch. Returns the
+        unit's logits once it completes the lane's chain, else None."""
         req = self.active[slot]
         chain = self._chain[slot]
         plen = len(chain)
@@ -565,8 +592,7 @@ class ContinuousScheduler:
                 self._pending_prefill_span(
                     "prefill", t, slot, req, 0, plen, mc, mc ** 2)
             self._prefill_queue.popleft()
-            self._finish_prefill(slot, logits, t)
-            return
+            return logits
         c = self.prefill_chunk
         pos = int(self.prefill_pos[slot])
         clen = min(c, plen - pos)
@@ -591,7 +617,8 @@ class ContinuousScheduler:
                                        pos, pos + clen, c, c * (pos + clen))
         if pos + clen == plen:
             self._prefill_queue.popleft()
-            self._finish_prefill(slot, logits, t)
+            return logits
+        return None
 
     # ---- tracing (repro.obs) ------------------------------------------
     def _pending_prefill_span(self, name: str, t0: float, slot: int, req,
@@ -628,43 +655,49 @@ class ContinuousScheduler:
         decode step across every prefill-complete lane. Returns the
         number of decode tokens emitted this step (``self.last_stats``
         carries the step's prefill cost breakdown for the sim clock)."""
-        self.last_stats = {"prefill_padded_tokens": 0, "prefill_attn_mac": 0,
-                           "prefill_wasted_tokens": 0}
-        self.step_events = []
-        self._admit(t)
+        with span("scheduler.admit"):
+            self.last_stats = {"prefill_padded_tokens": 0,
+                               "prefill_attn_mac": 0,
+                               "prefill_wasted_tokens": 0}
+            self.step_events = []
+            self._admit(t)
         self._run_prefill(t)
         ready = np.array([self.active[i] is not None and self.prefill_done[i]
                           for i in range(self.slots)])
         if not ready.any():
-            self._sample_metrics(t, 0)
+            self._sample_metrics(t)
             return 0
         if self.speculative:
             emitted = self._spec_step(ready, t)
-            self._sample_metrics(t, emitted)
+            self._sample_metrics(t)
             return emitted
-        # Lanes still prefilling are masked to the dead-lane contract so
-        # the fused decode never writes into their (possibly shared)
-        # blocks: table 0 -> null block, ctx 0, token 0.
-        dec_tables = np.where(ready[:, None], self.tables, 0)
-        dec_ctx = np.where(ready, self.ctx, 0).astype(np.int32)
-        dec_tok = np.where(ready, self.pending_tok, 0).astype(np.int32)
-        logits, self.pools = self.engine.decode(
-            self.params, self.pools, jnp.asarray(dec_tok),
-            jnp.asarray(dec_tables), jnp.asarray(dec_ctx))
-        self.decode_steps_run += 1
-        nxt = np.asarray(self.sampler(logits, self._next_key()), np.int32)
-        emitted = 0
-        for slot in np.flatnonzero(ready):
-            req = self.active[slot]
-            self.ctx[slot] += 1
-            tok = int(nxt[slot])
-            req.tokens.append(tok)
-            self.pending_tok[slot] = tok
-            self.total_new_tokens += 1
-            emitted += 1
-            if len(req.tokens) >= req.max_new_tokens:
-                self._retire(slot, t)
-        self._sample_metrics(t, emitted)
+        with span("scheduler.decode"):
+            # Lanes still prefilling are masked to the dead-lane contract
+            # so the fused decode never writes into their (possibly
+            # shared) blocks: table 0 -> null block, ctx 0, token 0.
+            dec_tables = np.where(ready[:, None], self.tables, 0)
+            dec_ctx = np.where(ready, self.ctx, 0).astype(np.int32)
+            dec_tok = np.where(ready, self.pending_tok, 0).astype(np.int32)
+            logits, self.pools = self.engine.decode(
+                self.params, self.pools, jnp.asarray(dec_tok),
+                jnp.asarray(dec_tables), jnp.asarray(dec_ctx))
+            self.decode_steps_run += 1
+        with span("scheduler.tokens"):
+            nxt = np.asarray(self.sampler(logits, self._next_key()),
+                             np.int32)
+        with span("scheduler.commit"):
+            emitted = 0
+            for slot in np.flatnonzero(ready):
+                req = self.active[slot]
+                self.ctx[slot] += 1
+                tok = int(nxt[slot])
+                req.tokens.append(tok)
+                self.pending_tok[slot] = tok
+                self.total_new_tokens += 1
+                emitted += 1
+                if len(req.tokens) >= req.max_new_tokens:
+                    self._retire(slot, t)
+            self._sample_metrics(t)
         return emitted
 
     def _spec_step(self, ready: np.ndarray, t: float) -> int:
@@ -781,33 +814,13 @@ class ContinuousScheduler:
             self._pending_trace.append(emit_spec)
         return emitted
 
-    def _sample_metrics(self, t: float, emitted: int) -> None:
-        """Per-step registry samples (host dicts only): pool occupancy +
-        its high-watermark, prefill waste, decode tokens, prefix hits."""
-        m = self.metrics
-        m.gauge("serve_pool_blocks_in_use",
-                "KV block-pool occupancy per step (peak = watermark)"
-                ).set(self.allocator.in_use)
-        m.gauge("serve_pool_blocks_free",
-                "free KV blocks per step").set(self.allocator.free_blocks)
-        pad = self.last_stats.get("prefill_padded_tokens", 0)
-        waste = self.last_stats.get("prefill_wasted_tokens", 0)
-        if pad:
-            m.counter("serve_prefill_padded_tokens",
-                      "padded prompt tokens pushed through prefill"
-                      ).inc(pad)
-        if waste:
-            m.counter("serve_prefill_wasted_tokens",
-                      "padding beyond real prompt tokens").inc(waste)
-        if emitted:
-            m.counter("serve_decode_tokens", "decode tokens emitted"
-                      ).inc(emitted)
-        if self.prefix is not None:
-            m.gauge("serve_prefix_hits", "prefix-cache hits (cumulative)"
-                    ).set(self.prefix.hits)
-            m.gauge("serve_prefix_misses",
-                    "prefix-cache misses (cumulative)"
-                    ).set(self.prefix.misses)
+    def _sample_metrics(self, t: float) -> None:
+        """Per-step registry sample (a host dict update): pool occupancy
+        and its high-watermark."""
+        self.metrics.gauge(
+            "serve_pool_blocks_in_use",
+            "KV block-pool occupancy per step (peak = watermark)"
+            ).set(self.allocator.in_use)
         if self.tracer is not None:
             from repro.obs import trace as T
             self.tracer.counter("kv blocks", t,

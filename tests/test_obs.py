@@ -4,7 +4,8 @@ byte-deterministic trace JSON, structural validity per
 scripts/validate_trace.py, track placement against the event log, the
 metrics registry semantics, and the satellite surfaces (history
 wall/sim clocks, ``trace_id`` echo, pool-occupancy report stats,
-``benchmarks/run.py --list``)."""
+``benchmarks/run.py --list``), and the scheduler's step spans on the
+profiler's clock, read back from a ``jax.profiler`` capture."""
 import importlib.util
 import json
 import os
@@ -391,6 +392,129 @@ def test_speculative_serve_tracing_and_metrics(lm_setup):
                       max_new_tokens=6)])
     series = reg.snapshot()["metrics"]["serve_spec_accepted_len"]["series"]
     assert series and series[0]["count"] > 0
+
+
+# ---- scheduler spans on the profiler's clock ------------------------------
+
+STEP_SPANS = ("scheduler.admit", "scheduler.prefill", "scheduler.decode",
+              "scheduler.tokens", "scheduler.commit")
+
+
+def _span_scheduler(cfg, params):
+    """Chunked prefill over 4-token chunks; request 0 fills one chunk and
+    request 1 two, so steps read 2, 1 and 2 tokens (a prefill's first
+    token beside the decode's). The sampler counts its calls."""
+    from repro.serve import ContinuousScheduler, PagedCacheSpec, PagedEngine
+    spec = PagedCacheSpec.for_requests(2, 16, block_size=4)
+    eng = PagedEngine(cfg, spec, max_context=16, slots=2)
+    sched = ContinuousScheduler(eng, params, prefill="chunked",
+                                prefill_chunk=4)
+    sample, calls = sched.sampler, []
+
+    def counted(logits, key):
+        calls.append(len(calls))
+        return sample(logits, key)
+    sched.sampler = counted
+    rng = np.random.default_rng(3)
+    for rid, (plen, new) in enumerate([(4, 5), (6, 3)]):
+        sched.submit(ServeRequest(
+            rid=rid, max_new_tokens=new,
+            prompt=rng.integers(1, cfg.vocab_size, (plen,)).astype(np.int32)))
+    return sched, calls
+
+
+@pytest.fixture(scope="module")
+def step_capture(lm_setup, tmp_path_factory):
+    """Four scheduler steps inside a ``jax.profiler`` capture, each call
+    wrapped in a ``test.step`` span, and the same four steps of a twin
+    scheduler without one. Returns the host spans read back from the
+    ``.xplane.pb`` (name, start_ns, end_ns; sorted), the token reads per
+    step, and both schedulers' streams."""
+    import glob
+    from jax.profiler import ProfileData, TraceAnnotation
+    cfg, params = lm_setup
+    steps = 4
+    plain, _ = _span_scheduler(cfg, params)
+    for i in range(steps):
+        plain.step(float(i))
+    sched, calls = _span_scheduler(cfg, params)
+    out = str(tmp_path_factory.mktemp("xplane"))
+    reads = []
+    with jax.profiler.trace(out):
+        for i in range(steps):
+            n = len(calls)
+            with TraceAnnotation("test.step"):
+                sched.step(float(i))
+            reads.append(len(calls) - n)
+    path, = glob.glob(os.path.join(out, "**", "*.xplane.pb"), recursive=True)
+    spans = sorted(
+        ((e.name, e.start_ns, e.start_ns + e.duration_ns)
+         for plane in ProfileData.from_file(path).planes
+         if plane.name.startswith("/host:")
+         for line in plane.lines for e in line.events
+         if e.name in STEP_SPANS + ("test.step",)),
+        key=lambda s: s[1])
+    streams = lambda s: {r.rid: list(r.tokens) for r in
+                         list(s.finished) + [r for r in s.active if r]}
+    return dict(spans=spans, reads=reads, steps=steps,
+                traced=streams(sched), plain=streams(plain))
+
+
+def _per_step(cap):
+    """The program's spans grouped by the ``test.step`` call they fall in."""
+    calls = [s for s in cap["spans"] if s[0] == "test.step"]
+    return [[s for s in cap["spans"] if s[0] != "test.step"
+             and t0 <= s[1] and s[2] <= t1] for _, t0, t1 in calls]
+
+
+def test_span_is_the_profilers_trace_annotation():
+    from repro.obs import span
+    with span("scheduler.admit") as s:
+        assert isinstance(s, jax.profiler.TraceAnnotation)
+
+
+def test_scheduler_opens_every_step_with_one_admit_span(step_capture):
+    per_step = _per_step(step_capture)
+    assert len(per_step) == step_capture["steps"]
+    assert [sum(n == "scheduler.admit" for n, _, _ in st)
+            for st in per_step] == [1] * step_capture["steps"]
+    assert all(st[0][0] == "scheduler.admit" for st in per_step)
+    # every program span lies inside a step call
+    assert sum(map(len, per_step)) == sum(
+        s[0] != "test.step" for s in step_capture["spans"])
+
+
+def test_scheduler_step_spans_are_disjoint_and_cover_the_step(step_capture):
+    calls = [s for s in step_capture["spans"] if s[0] == "test.step"]
+    for (_, t0, t1), st in zip(calls, _per_step(step_capture)):
+        for a, b in zip(st, st[1:]):
+            assert a[2] <= b[1], (a, b)
+        covered = sum(e - s for _, s, e in st)
+        assert covered >= 0.9 * (t1 - t0)
+        assert {n for n, _, _ in st} <= set(STEP_SPANS)
+
+
+def test_scheduler_tokens_span_per_token_read(step_capture):
+    per_step = _per_step(step_capture)
+    tokens = [sum(n == "scheduler.tokens" for n, _, _ in st)
+              for st in per_step]
+    assert tokens == step_capture["reads"]
+    assert tokens[:3] == [2, 1, 2]     # a prefill's first token + decode
+    commits = [sum(n == "scheduler.commit" for n, _, _ in st)
+               for st in per_step]
+    assert commits == tokens
+    # each token read is followed by its commit
+    for st in per_step:
+        names = [n for n, _, _ in st]
+        for i, n in enumerate(names):
+            if n == "scheduler.tokens":
+                assert names[i + 1] == "scheduler.commit"
+
+
+def test_scheduler_spans_leave_greedy_streams_unchanged(step_capture):
+    assert step_capture["traced"] == step_capture["plain"]
+    assert {rid: len(t) for rid, t in step_capture["traced"].items()} \
+        == {0: 5, 1: 3}
 
 
 def test_serve_request_trace_id_defaults_to_rid():
