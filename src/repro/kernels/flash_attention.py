@@ -33,6 +33,13 @@ Uneven sequence lengths (e.g. vision token counts) are handled by padding
 Sq/Skv up to a block multiple and masking the tail from absolute
 positions (``kp < kv_len``); padded q rows carry zero cotangents, so they
 contribute nothing to dK/dV and their dQ rows are sliced off.
+
+The serving kernels read K/V through a block table over physical page
+pools. Chunked prefill walks the table as its innermost sequential grid
+dimension, like the KV blocks above. Decode instead gives each request
+one grid step and walks only its live pages in a ``fori_loop``, copying
+them by hand into double-buffered VMEM, so its cost follows the live
+context and not the table's width.
 """
 from __future__ import annotations
 
@@ -194,61 +201,127 @@ def flash_attention(q, k, v, *, scale: Optional[float] = None,
 
 
 # --------------------------------------------------------- paged decode ----
-def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, *rest,
-                         scale: float, bs: int, quantized: bool):
+# KV tokens one step of a lane's loop attends to: as many whole pages as
+# fit in one MXU-wide tile, so P = DECODE_BLOCK_TOKENS // bs pages (at
+# least one, at most the table's width).
+DECODE_BLOCK_TOKENS = 128
+
+
+def _token_scales(sc, bs: int, hkv: int, d: int):
+    """int8 scales of a block's pages, [P, 1, bs * hkv] (lane ``t * hkv +
+    h`` for token t, head h), spread to the [P * bs, hkv * d] multiplier
+    of each token's row (lane ``h * d + e``). The spread is an exact
+    0/1 matmul, so each multiplier is its scale bit for bit."""
+    pages, _, n = sc.shape
+    rows = jnp.broadcast_to(sc, (pages, bs, n)).reshape(pages * bs, n)
+    tok = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 0) % bs
+    lane = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+    rows = jnp.where(lane // hkv == tok, rows, 0.0)
+    spread = (jax.lax.broadcasted_iota(jnp.int32, (n, hkv * d), 0) % hkv
+              == jax.lax.broadcasted_iota(jnp.int32, (n, hkv * d), 1) // d)
+    return jax.lax.dot(rows, spread.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
+def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, v_hbm, *rest,
+                         scale: float, bs: int, pages: int, t: int,
+                         hkv: int, quantized: bool):
     """One decode token per request against a paged KV pool.
 
-    Grid (batch, kv-head, table-slot); the innermost dimension walks the
-    request's block table sequentially while (m, l, acc) persist in VMEM
-    scratch — the same online-softmax recurrence as ``_fwd_kernel``, with
-    the physical KV tile resolved through the scalar-prefetched block
-    table (``tbl_ref[b, i]``) instead of a contiguous index map. Slots at
-    or past the request's context length are dead (their table entries
-    point at the reserved null block) and skip compute entirely, the
-    paged analogue of ``_block_live``.
+    Grid (batch,): one step per lane, all heads at once. The pools stay
+    in HBM (``pl.ANY``) as [NB, bs, Hkv * D], a page of every KV head per
+    row block, and a ``fori_loop`` walks the lane's ``cdiv(ctx, P * bs)``
+    compute blocks. Each block's pages are copied from the
+    scalar-prefetched block table (``tbl_ref[b * t + i]``), one DMA per
+    page, into a double-buffered VMEM tile: block ``j + 1``'s copies start
+    before block ``j`` is computed. Only live pages are copied: a ragged
+    last block copies its live pages alone (the rest of its tile is
+    stale, masked out of the scores and zeroed in V), table slots at or
+    past the context are never read, and a lane with ``ctx == 0`` copies
+    nothing and writes exact zeros. So the cost follows the pages the
+    live lanes hold, not the table's width.
+
+    The query arrives block-diagonal, [Hq, Hkv * D] with query head ``r``
+    in the lanes of its KV head ``r // G``, so one matmul scores every
+    head against the page rows. (m, l, acc) are the loop's carry: the
+    float32 online-softmax recurrence of ``_fwd_kernel``. ``acc`` is
+    [Hq, Hkv * D]; its diagonal blocks are the heads' outputs, folded to
+    [Hq, D] at the end by an exact 0/1 matmul.
     """
     if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem = rest
+        pairs = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
+                 (vs_hbm, vs_buf))
     else:
-        o_ref, m_ref, l_ref, acc_ref = rest
+        o_ref, k_buf, v_buf, sem = rest
+        pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
-    i = pl.program_id(2)
-
-    @pl.when(i == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
     ctx = ctx_ref[b]
+    n_pages = pl.cdiv(ctx, bs)
+    n_blocks = pl.cdiv(ctx, pages * bs)
+    hq, w = q_ref.shape[1:]
+    g, d, blk = hq // hkv, w // hkv, pages * bs
 
-    @pl.when(i * bs < ctx)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)        # [g, d]
-        k = k_ref[0, 0].astype(jnp.float32)        # [bs, d]
-        v = v_ref[0, 0].astype(jnp.float32)        # [bs, d]
+    def each_live_page(j, slot, op):
+        """Start or wait the copies of block ``j``'s live pages into
+        buffer ``slot``; the table is read for live pages only."""
+        for p in range(pages):
+            @pl.when(j * pages + p < n_pages)
+            def _page():
+                page = tbl_ref[b * t + j * pages + p]
+                for hbm, buf in pairs:
+                    op(pltpu.make_async_copy(hbm.at[page], buf.at[slot, p],
+                                             sem.at[slot]))
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        each_live_page(0, 0, lambda c: c.start())
+
+    q = q_ref[0].astype(jnp.float32)                   # [hq, w]
+
+    def body(j, carry):
+        m_prev, l_prev, acc_prev = carry
+        slot = j % 2
+
+        @pl.when(j + 1 < n_blocks)
+        def _next():
+            each_live_page(j + 1, 1 - slot, lambda c: c.start())
+
+        each_live_page(j, slot, lambda c: c.wait())
+        k = k_buf[slot].astype(jnp.float32).reshape(blk, w)
+        v = v_buf[slot].astype(jnp.float32).reshape(blk, w)
         if quantized:
-            k = k * ks_ref[0, 0]                   # per-row absmax scales
-            v = v * vs_ref[0, 0]
-        g = q.shape[0]
+            k = k * _token_scales(ks_buf[slot], bs, hkv, d)
+            v = v * _token_scales(vs_buf[slot], bs, hkv, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
-        kp = i * bs + jax.lax.broadcasted_iota(jnp.int32, (g, bs), 1)
-        s = jnp.where(kp < ctx, s, NEG_INF)        # partial final block
+        kp = j * blk + jax.lax.broadcasted_iota(jnp.int32, (hq, blk), 1)
+        s = jnp.where(kp < ctx, s, NEG_INF)            # partial last page
+        vp = j * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, w), 0)
+        v = jnp.where(vp < ctx, v, 0.0)                # stale rows
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
+        l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
+        acc_new = acc_prev * corr + jax.lax.dot(
             p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        return m_new, l_new, acc_new
 
-    @pl.when(i == pl.num_programs(2) - 1)
-    def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+    init = (jnp.full((hq, 1), NEG_INF, jnp.float32),
+            jnp.zeros((hq, 1), jnp.float32),
+            jnp.zeros((hq, w), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    row = jax.lax.broadcasted_iota(jnp.int32, (hq, w), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hq, w), 1)
+    acc = jnp.where(row // g == lane // d, acc, 0.0)   # own head's lanes
+    fold = (jax.lax.broadcasted_iota(jnp.int32, (w, d), 0) % d
+            == jax.lax.broadcasted_iota(jnp.int32, (w, d), 1))
+    o = jax.lax.dot(acc, fold.astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
@@ -259,61 +332,63 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
 
     q: [B, Hq, D] (one query token per request); k_pages/v_pages:
     [Hkv, NB, bs, D] physical block pools; block_tables: [B, T] int32
-    logical->physical maps (dead slots point at the reserved null block
-    0); ctx_lens: [B] int32 visible KV length per request (requests with
-    ``ctx_lens == 0`` return zeros). With ``k_scales``/``v_scales``
-    ([Hkv, NB, bs, 1] float32) the pools are int8 and dequantized
-    in-kernel. Returns [B, Hq, D].
+    logical->physical maps (slots at or past a request's context are
+    never read); ctx_lens: [B] int32 visible KV length per request
+    (requests with ``ctx_lens == 0`` return zeros). With
+    ``k_scales``/``v_scales`` ([Hkv, NB, bs, 1] float32) the pools are
+    int8 and dequantized in-kernel. Returns [B, Hq, D].
+
+    The kernel reads the pools as [NB, bs, Hkv * D]: Mosaic slices a
+    memory reference only where its lanes stay whole, which a D of 64
+    (padded to 128 lanes) is not, while a page of all heads is.
     """
     b, hq, d = q.shape
-    hkv, _, bs, _ = k_pages.shape
+    hkv, nb, bs, _ = k_pages.shape
     g = hq // hkv
     t = block_tables.shape[1]
+    pages = max(1, min(t, DECODE_BLOCK_TOKENS // bs))
     scale = scale if scale is not None else d ** -0.5
     quantized = k_scales is not None
-    qg = q.reshape(b, hkv, g, d)
+
+    def rows(pool):                                # -> [NB, bs, Hkv * D]
+        # merging (NB, bs) first leaves XLA one relayout, where
+        # transposing the 4-D pool first costs it two
+        return pool.reshape(hkv, nb * bs, -1).transpose(1, 0, 2).reshape(
+            nb, bs, -1)
+
+    own = (jnp.arange(hq)[:, None] // g == jnp.arange(hkv)[None, :])
+    qbd = jnp.where(own[None, :, :, None], q[:, :, None, :],
+                    jnp.zeros((), q.dtype)).reshape(b, hq, hkv * d)
+    operands = [qbd, rows(k_pages), rows(v_pages)]
+    scratch = [pltpu.VMEM((2, pages, bs, hkv * d), k_pages.dtype),
+               pltpu.VMEM((2, pages, bs, hkv * d), v_pages.dtype)]
+    if quantized:                                  # -> [NB, 1, bs * Hkv]
+        operands += [rows(s).reshape(nb, 1, bs * hkv)
+                     for s in (k_scales, v_scales)]
+        scratch += [pltpu.VMEM((2, pages, 1, bs * hkv), jnp.float32)] * 2
 
     kernel = functools.partial(_paged_decode_kernel, scale=scale, bs=bs,
+                               pages=pages, t=t, hkv=hkv,
                                quantized=quantized)
-    in_specs = [
-        pl.BlockSpec((1, 1, g, d), lambda b_, h, i, tbl, ctx: (b_, h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d),
-                     lambda b_, h, i, tbl, ctx: (h, tbl[b_, i], 0, 0)),
-        pl.BlockSpec((1, 1, bs, d),
-                     lambda b_, h, i, tbl, ctx: (h, tbl[b_, i], 0, 0)),
-    ]
-    operands = [qg, k_pages, v_pages]
-    if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, bs, 1),
-                         lambda b_, h, i, tbl, ctx: (h, tbl[b_, i], 0, 0)),
-            pl.BlockSpec((1, 1, bs, 1),
-                         lambda b_, h, i, tbl, ctx: (h, tbl[b_, i], 0, 0)),
-        ]
-        operands += [k_scales, v_scales]
-
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, t),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda b_, h, i, tbl, ctx: (b_, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g,), jnp.float32),
-            pltpu.VMEM((g, d), jnp.float32),
-        ],
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, hq, hkv * d),
+                               lambda b_, tbl, ctx: (b_, 0, 0))]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * (len(operands) - 1),
+        out_specs=pl.BlockSpec((1, hq, d), lambda b_, tbl, ctx: (b_, 0, 0)),
+        scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
     )
-    o = pl.pallas_call(
+    return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel",)),
         interpret=interpret,
         name="paged_decode_attention",
-    )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32), *operands)
-    return o.reshape(b, hq, d)
+    )(block_tables.astype(jnp.int32).reshape(-1),
+      ctx_lens.astype(jnp.int32), *operands)
 
 
 # --------------------------------------------------------- paged prefill ---
@@ -324,7 +399,7 @@ def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, v_ref, *rest,
 
     Grid (kv-head, table-slot); the innermost dimension walks the
     request's block table sequentially while (m, l, acc) persist in VMEM
-    scratch — the chunked-prefill analogue of ``_paged_decode_kernel``.
+    scratch — ``_fwd_kernel``'s KV walk through a block table.
     The query chunk is laid out [Hkv, G*C, D] (GQA group-major), so row
     ``r`` is chunk offset ``r % C`` at absolute position ``q_offset +
     r % C``; the causal mask is applied from those absolute positions

@@ -50,8 +50,8 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens,
     [B, T] int32; ctx_lens: [B] int32. Gathers each request's logical KV
     view through its block table, dequantizes when scales are given,
     masks positions >= ctx_len, and runs dense softmax attention.
-    Requests with ``ctx_lens == 0`` return zeros (matching the kernel's
-    never-initialized accumulator path)."""
+    Requests with ``ctx_lens == 0`` return zeros (matching the kernel,
+    which attends to nothing for them)."""
     b, hq, d = q.shape
     hkv, _, bs, _ = k_pages.shape
     g = hq // hkv

@@ -14,8 +14,9 @@ per-request contiguous cache:
     per-request context lengths) -> append the token's K/V into its
     physical block -> the paged Pallas decode kernel
     (:func:`repro.kernels.ops.paged_decode_attention`) -> wo/ffn. All
-    ``slots`` batch lanes run every step; dead lanes point at the null
-    block and cost one masked tile.
+    ``slots`` batch lanes run the projections every step; dead lanes
+    point at the null block and attend to an empty context, so the
+    kernel copies none of their pages and writes zeros for them.
 
 The numerics match the contiguous path op for op (same rope-after-norm
 order, float32 softmax statistics), which is what the paged-vs-contiguous
@@ -208,6 +209,9 @@ class PagedEngine:
         blk = (ctx_lens // spec.block_size)[:, None]
         phys = jnp.take_along_axis(tables, blk, axis=1)[:, 0]   # [slots]
         off = ctx_lens % spec.block_size
+        # the kernel's context: the pending token included, none for a
+        # dead lane (its table starts at the null block)
+        attend = jnp.where(tables[:, 0] != 0, ctx_lens + 1, 0)
 
         def body(carry, layer):
             h_in = carry
@@ -237,7 +241,7 @@ class PagedEngine:
                 from repro.kernels import ops as kops
                 o = kops.paged_decode_attention(
                     q[:, :, 0], new_pools["k"], new_pools["v"], tables,
-                    ctx_lens + 1, scale=scale,
+                    attend, scale=scale,
                     k_scales=new_pools.get("k_scale"),
                     v_scales=new_pools.get("v_scale"))     # [slots,Hq,D]
                 h_in = h_in + (o.reshape(slots, 1, nq * hd)

@@ -262,13 +262,14 @@ def test_quantize_int8_stochastic_rounding_unbiased():
     assert mean_err < 0.25 * step, (mean_err, step)
 
 
-def _paged_setup(k, b, hkv, nb, bs, d, ctx_list):
-    """Random pools + a valid block table for the given context lengths."""
+def _paged_setup(k, b, hkv, nb, bs, d, ctx_list, t=None):
+    """Random pools + a valid block table for the given context lengths
+    (``t`` table slots; one more than the longest context needs)."""
     import numpy as np
     ks = jax.random.split(k, 3)
     kp = jax.random.normal(ks[0], (hkv, nb, bs, d), jnp.float32)
     vp = jax.random.normal(ks[1], (hkv, nb, bs, d), jnp.float32)
-    t = max(-(-c // bs) for c in ctx_list) + 1
+    t = t or max(-(-c // bs) for c in ctx_list) + 1
     tbl = np.zeros((b, t), np.int32)
     free = list(range(1, nb))
     for i, c in enumerate(ctx_list):
@@ -277,18 +278,25 @@ def _paged_setup(k, b, hkv, nb, bs, d, ctx_list):
     return kp, vp, jnp.asarray(tbl), jnp.asarray(ctx_list, jnp.int32)
 
 
+# The kernel walks compute blocks of 128 // bs pages (at most T), so
+# bs 32 gives 4-page blocks and bs 64 2-page blocks at test sizes.
 @pytest.mark.parametrize(
-    "b,hq,hkv,d,bs,ctx_list",
+    "b,hq,hkv,d,bs,ctx_list,t",
     [
-        (4, 4, 2, 32, 8, [13, 1, 0, 48]),   # GQA, partial/dead/full blocks
-        (2, 8, 8, 64, 16, [16, 31]),        # MHA, exact and off-by-one
-        (3, 2, 1, 128, 4, [4, 9, 2]),       # MQA, tiny blocks
+        (4, 4, 2, 32, 8, [13, 1, 0, 48], None),  # GQA, partial/dead/full
+        (2, 8, 8, 64, 16, [16, 31], None),       # MHA, exact, off-by-one
+        (3, 2, 1, 128, 4, [4, 9, 2], None),      # MQA, tiny blocks
+        # 3 and 2 compute blocks, ragged last blocks; T 11 is not a
+        # multiple of the 4-page block
+        (2, 4, 2, 32, 32, [300, 129], None),
+        (2, 2, 1, 16, 64, [320, 0], 5),          # a lane at the full table
+        (3, 4, 2, 32, 16, [0, 0, 0], None),      # every lane dead
     ])
-def test_paged_decode_attention(b, hq, hkv, d, bs, ctx_list):
+def test_paged_decode_attention(b, hq, hkv, d, bs, ctx_list, t):
     """Paged single-token decode kernel vs the dense gather oracle,
     including dead lanes (ctx=0 -> exact zeros) and partial last blocks."""
     nb = 1 + sum(-(-c // bs) for c in ctx_list) + 2
-    kp, vp, tbl, ctx = _paged_setup(KEY, b, hkv, nb, bs, d, ctx_list)
+    kp, vp, tbl, ctx = _paged_setup(KEY, b, hkv, nb, bs, d, ctx_list, t)
     q = jax.random.normal(jax.random.fold_in(KEY, 7), (b, hq, d),
                           jnp.float32)
     got = ops.paged_decode_attention(q, kp, vp, tbl, ctx, interpret=True)
@@ -299,10 +307,13 @@ def test_paged_decode_attention(b, hq, hkv, d, bs, ctx_list):
             assert float(jnp.abs(got[i]).max()) == 0.0
 
 
-def test_paged_decode_attention_int8():
+@pytest.mark.parametrize("bs,ctx_list", [
+    (8, [5, 17, 24]),
+    (32, [300, 0, 97]),            # several compute blocks, ragged last
+])
+def test_paged_decode_attention_int8(bs, ctx_list):
     """int8 pools dequantize in-kernel through per-row scales."""
-    b, hq, hkv, d, bs = 3, 4, 2, 32, 8
-    ctx_list = [5, 17, 24]
+    b, hq, hkv, d = 3, 4, 2, 32
     nb = 1 + sum(-(-c // bs) for c in ctx_list) + 1
     kp, vp, tbl, ctx = _paged_setup(KEY, b, hkv, nb, bs, d, ctx_list)
     ks = jax.random.split(jax.random.fold_in(KEY, 11), 5)
@@ -320,6 +331,51 @@ def test_paged_decode_attention_int8():
     want = ref.paged_decode_attention_ref(q, kq, vq, tbl, ctx,
                                           k_scales=ksc, v_scales=vsc)
     assert float(jnp.max(jnp.abs(got - want))) < 5e-6
+
+
+def _poison_dead(pool, tbl, ctx_list, bs):
+    """NaN in every pool row outside the lanes' live ranges: every block
+    no lane's context reaches (the null block 0 included) and the rows
+    past the context in each lane's partial last page."""
+    import numpy as np
+    live = np.zeros(pool.shape[1:3], bool)            # [NB, bs]
+    for row, c in zip(np.asarray(tbl), ctx_list):
+        for j in range(-(-c // bs)):
+            live[row[j], :min(bs, c - j * bs)] = True
+    return jnp.where(jnp.asarray(live)[None, :, :, None], pool, jnp.nan)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_paged_decode_never_reads_dead_pages(int8):
+    """With NaN wherever no lane's context reaches, the output is finite
+    and matches the oracle on clean pools: the kernel never computes
+    with a dead page, a dead lane's null block or the tail of a
+    partial page."""
+    b, hq, hkv, d, bs = 4, 4, 2, 32, 32
+    ctx_list = [300, 0, 129, 31]          # multi-block, dead, ragged, short
+    nb = 1 + sum(-(-c // bs) for c in ctx_list) + 3
+    kp, vp, tbl, ctx = _paged_setup(KEY, b, hkv, nb, bs, d, ctx_list)
+    q = jax.random.normal(jax.random.fold_in(KEY, 13), (b, hq, d),
+                          jnp.float32)
+    if int8:
+        ks = jax.random.split(jax.random.fold_in(KEY, 17), 2)
+        kp = jnp.clip(jnp.round(kp * 40), -127, 127).astype(jnp.int8)
+        vp = jnp.clip(jnp.round(vp * 40), -127, 127).astype(jnp.int8)
+        scales = [jax.random.uniform(k, kp.shape[:-1] + (1,), jnp.float32,
+                                     1e-3, 2e-2) for k in ks]
+        want = ref.paged_decode_attention_ref(
+            q, kp, vp, tbl, ctx, k_scales=scales[0], v_scales=scales[1])
+        ksc, vsc = (_poison_dead(s_, tbl, ctx_list, bs) for s_ in scales)
+        got = ops.paged_decode_attention(q, kp, vp, tbl, ctx, k_scales=ksc,
+                                         v_scales=vsc, interpret=True)
+    else:
+        want = ref.paged_decode_attention_ref(q, kp, vp, tbl, ctx)
+        got = ops.paged_decode_attention(
+            q, _poison_dead(kp, tbl, ctx_list, bs),
+            _poison_dead(vp, tbl, ctx_list, bs), tbl, ctx, interpret=True)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.max(jnp.abs(got - want))) < 5e-6
+    assert float(jnp.abs(got[1]).max()) == 0.0
 
 
 def _prefill_pool_setup(key, hkv, bs, d, s, spare=2, int8=False):

@@ -140,7 +140,9 @@ def test_paged_engine_matches_contiguous(dense_setup):
     for _ in range(n_decode - 1):
         logits, pools = eng.decode(params, pools, jnp.asarray(pend),
                                    jnp.asarray(tables), jnp.asarray(ctx))
-        ctx += 1
+        # a new array: on the CPU jnp.asarray may share the numpy buffer
+        # with the decode still running, which an in-place += would race
+        ctx = ctx + 1
         pend = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
         for i in range(2):
             streams[i].append(int(pend[i]))

@@ -74,18 +74,24 @@ def test_flash_backward_compiles(sds):
                          sds((1, HQ, 1024, D), BF16)) == 4
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_paged_decode_compiles(sds, int8):
-    pool = sds((HKV, NB, BS, D), jnp.int8 if int8 else BF16)
-    scales = sds((HKV, NB, BS, 1), jnp.float32) if int8 else None
+# the fleet cell's geometry besides: 32 lanes, 144-slot tables over a
+# 2,048-block pool (1 GiB of bf16 K/V across 16 layers)
+@pytest.mark.parametrize(
+    "int8,slots,t,nb",
+    [(False, SLOTS, T, NB), (True, SLOTS, T, NB),
+     (False, 32, 144, 2048), (True, 32, 144, 2048)],
+    ids=["bf16", "int8", "bf16-fleet", "int8-fleet"])
+def test_paged_decode_compiles(sds, int8, slots, t, nb):
+    pool = sds((HKV, nb, BS, D), jnp.int8 if int8 else BF16)
+    scales = sds((HKV, nb, BS, 1), jnp.float32) if int8 else None
 
     def decode(q, kp, vp, tbl, ctx, ks, vs):
         return ops.paged_decode_attention(q, kp, vp, tbl, ctx, k_scales=ks,
                                           v_scales=vs, interpret=False)
 
-    assert _mosaic_calls(decode, sds((SLOTS, HQ, D), BF16), pool, pool,
-                         sds((SLOTS, T), jnp.int32),
-                         sds((SLOTS,), jnp.int32), scales, scales) == 1
+    assert _mosaic_calls(decode, sds((slots, HQ, D), BF16), pool, pool,
+                         sds((slots, t), jnp.int32),
+                         sds((slots,), jnp.int32), scales, scales) == 1
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
