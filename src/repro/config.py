@@ -20,6 +20,46 @@ class MoEConfig:
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
     router_z_weight: float = 1e-3
+    # DeepSeek-V3-style routing (serving's held-expert layer reads these;
+    # the defaults are the softmax top-k that ``moe_block`` computes)
+    num_shared_experts: int = 0     # one SwiGLU of width n * d_expert
+    score_func: str = "softmax"     # softmax | sigmoid
+    route_scale: float = 1.0        # routed_scaling_factor
+    norm_topk: bool = True          # renormalize the chosen top-k scores
+    selection_bias: bool = False    # e_score_correction_bias on selection
+    first_dense_layers: int = 0     # leading dense SwiGLU layers (d_ff)
+    # expert parallelism: this chip holds experts [expert_offset,
+    # expert_offset + experts_held) of num_experts (0 = all of them)
+    expert_offset: int = 0
+    experts_held: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2/V3). ``kv_lora_rank`` 0
+    means the model has none. Keys and values come from a shared latent
+    of ``kv_lora_rank`` plus a ``qk_rope_head_dim`` rotary key shared by
+    every head; ``q_lora_rank`` 0 projects queries directly."""
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_interleave: bool = True    # the published config class's default
+    kv_norm_eps: float = 1e-6       # the latent's RMSNorm (its own default)
+
+    @property
+    def qk_head_dim(self) -> int:
+        return self.qk_nope_head_dim + self.qk_rope_head_dim
+
+    @property
+    def row(self) -> int:
+        """Values one token keeps per layer: the latent and its rotary key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
 
 
 @dataclasses.dataclass(frozen=True)
@@ -49,6 +89,7 @@ class ModelConfig:
     tie_embeddings: bool = False
     moe: MoEConfig = MoEConfig()
     ssm: SSMConfig = SSMConfig()
+    mla: MLAConfig = MLAConfig()
     # encoder-decoder split (family == 'encdec'); num_layers = enc + dec
     enc_layers: int = 0
     dec_layers: int = 0
@@ -78,6 +119,12 @@ class ModelConfig:
     @property
     def dtype(self):
         return jnp.dtype(self.param_dtype)
+
+    @property
+    def latent(self) -> bool:
+        """True for latent attention (MLA): the KV pool holds one latent
+        row per token per layer instead of K and V per KV head."""
+        return self.mla.kv_lora_rank > 0
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)

@@ -224,9 +224,9 @@ def _token_scales(sc, bs: int, hkv: int, d: int):
                        preferred_element_type=jnp.float32)
 
 
-def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, v_hbm, *rest,
+def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, *rest,
                          scale: float, bs: int, pages: int, t: int,
-                         hkv: int, quantized: bool):
+                         hkv: int, quantized: bool, latent_v: int):
     """One decode token per request against a paged KV pool.
 
     Grid (batch,): one step per lane, all heads at once. The pools stay
@@ -248,13 +248,21 @@ def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, v_hbm, *rest,
     float32 online-softmax recurrence of ``_fwd_kernel``. ``acc`` is
     [Hq, Hkv * D]; its diagonal blocks are the heads' outputs, folded to
     [Hq, D] at the end by an exact 0/1 matmul.
+
+    ``latent_v`` > 0 is latent attention (MLA): one pool of rows that are
+    the keys, whose first ``latent_v`` lanes are also the values, with
+    one KV head. Each page is then copied once, and there is nothing to
+    fold.
     """
-    if quantized:
-        ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem = rest
+    if latent_v:
+        o_ref, k_buf, sem = rest
+        pairs = ((k_hbm, k_buf),)
+    elif quantized:
+        v_hbm, ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem = rest
         pairs = ((k_hbm, k_buf), (v_hbm, v_buf), (ks_hbm, ks_buf),
                  (vs_hbm, vs_buf))
     else:
-        o_ref, k_buf, v_buf, sem = rest
+        v_hbm, o_ref, k_buf, v_buf, sem = rest
         pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
     ctx = ctx_ref[b]
@@ -290,7 +298,10 @@ def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, v_hbm, *rest,
 
         each_live_page(j, slot, lambda c: c.wait())
         k = k_buf[slot].astype(jnp.float32).reshape(blk, w)
-        v = v_buf[slot].astype(jnp.float32).reshape(blk, w)
+        if latent_v:
+            v = k[:, :latent_v]
+        else:
+            v = v_buf[slot].astype(jnp.float32).reshape(blk, w)
         if quantized:
             k = k * _token_scales(ks_buf[slot], bs, hkv, d)
             v = v * _token_scales(vs_buf[slot], bs, hkv, d)
@@ -298,7 +309,7 @@ def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, v_hbm, *rest,
                                 preferred_element_type=jnp.float32) * scale
         kp = j * blk + jax.lax.broadcasted_iota(jnp.int32, (hq, blk), 1)
         s = jnp.where(kp < ctx, s, NEG_INF)            # partial last page
-        vp = j * blk + jax.lax.broadcasted_iota(jnp.int32, (blk, w), 0)
+        vp = j * blk + jax.lax.broadcasted_iota(jnp.int32, v.shape, 0)
         v = jnp.where(vp < ctx, v, 0.0)                # stale rows
 
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
@@ -311,8 +322,11 @@ def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, v_hbm, *rest,
 
     init = (jnp.full((hq, 1), NEG_INF, jnp.float32),
             jnp.zeros((hq, 1), jnp.float32),
-            jnp.zeros((hq, w), jnp.float32))
+            jnp.zeros((hq, latent_v or w), jnp.float32))
     _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+    if latent_v:
+        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        return
     row = jax.lax.broadcasted_iota(jnp.int32, (hq, w), 0)
     lane = jax.lax.broadcasted_iota(jnp.int32, (hq, w), 1)
     acc = jnp.where(row // g == lane // d, acc, 0.0)   # own head's lanes
@@ -327,7 +341,7 @@ def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, v_hbm, *rest,
 def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
                            scale: Optional[float] = None,
                            k_scales=None, v_scales=None,
-                           interpret: bool = False):
+                           latent_v: int = 0, interpret: bool = False):
     """Single-token decode attention over a paged KV cache.
 
     q: [B, Hq, D] (one query token per request); k_pages/v_pages:
@@ -341,6 +355,11 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     The kernel reads the pools as [NB, bs, Hkv * D]: Mosaic slices a
     memory reference only where its lanes stay whole, which a D of 64
     (padded to 128 lanes) is not, while a page of all heads is.
+
+    Latent attention (MLA) passes ``v_pages=None`` and ``latent_v``: the
+    one pool ([1, NB, bs, D], D the latent and its rotary key) holds the
+    keys, and its first ``latent_v`` lanes are the values. Returns [B,
+    Hq, latent_v] then.
     """
     b, hq, d = q.shape
     hkv, nb, bs, _ = k_pages.shape
@@ -349,6 +368,10 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
     pages = max(1, min(t, DECODE_BLOCK_TOKENS // bs))
     scale = scale if scale is not None else d ** -0.5
     quantized = k_scales is not None
+    if (v_pages is None) != bool(latent_v) or (latent_v and (
+            hkv != 1 or quantized)):
+        raise ValueError("latent attention is one bf16 pool, one KV head, "
+                         "and no V pool; a V pool needs latent_v=0")
 
     def rows(pool):                                # -> [NB, bs, Hkv * D]
         # merging (NB, bs) first leaves XLA one relayout, where
@@ -356,33 +379,39 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         return pool.reshape(hkv, nb * bs, -1).transpose(1, 0, 2).reshape(
             nb, bs, -1)
 
-    own = (jnp.arange(hq)[:, None] // g == jnp.arange(hkv)[None, :])
-    qbd = jnp.where(own[None, :, :, None], q[:, :, None, :],
-                    jnp.zeros((), q.dtype)).reshape(b, hq, hkv * d)
-    operands = [qbd, rows(k_pages), rows(v_pages)]
-    scratch = [pltpu.VMEM((2, pages, bs, hkv * d), k_pages.dtype),
-               pltpu.VMEM((2, pages, bs, hkv * d), v_pages.dtype)]
+    if latent_v:
+        operands = [q, k_pages.reshape(nb, bs, d)]
+        scratch = [pltpu.VMEM((2, pages, bs, d), k_pages.dtype)]
+    else:
+        own = (jnp.arange(hq)[:, None] // g == jnp.arange(hkv)[None, :])
+        qbd = jnp.where(own[None, :, :, None], q[:, :, None, :],
+                        jnp.zeros((), q.dtype)).reshape(b, hq, hkv * d)
+        operands = [qbd, rows(k_pages), rows(v_pages)]
+        scratch = [pltpu.VMEM((2, pages, bs, hkv * d), k_pages.dtype),
+                   pltpu.VMEM((2, pages, bs, hkv * d), v_pages.dtype)]
     if quantized:                                  # -> [NB, 1, bs * Hkv]
         operands += [rows(s).reshape(nb, 1, bs * hkv)
                      for s in (k_scales, v_scales)]
         scratch += [pltpu.VMEM((2, pages, 1, bs * hkv), jnp.float32)] * 2
 
+    dv = latent_v or d
     kernel = functools.partial(_paged_decode_kernel, scale=scale, bs=bs,
                                pages=pages, t=t, hkv=hkv,
-                               quantized=quantized)
+                               quantized=quantized, latent_v=latent_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, hq, hkv * d),
                                lambda b_, tbl, ctx: (b_, 0, 0))]
         + [pl.BlockSpec(memory_space=pl.ANY)] * (len(operands) - 1),
-        out_specs=pl.BlockSpec((1, hq, d), lambda b_, tbl, ctx: (b_, 0, 0)),
+        out_specs=pl.BlockSpec((1, hq, dv),
+                               lambda b_, tbl, ctx: (b_, 0, 0)),
         scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hq, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hq, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
@@ -392,9 +421,9 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
 
 
 # --------------------------------------------------------- paged prefill ---
-def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, v_ref, *rest,
+def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, *rest,
                           scale: float, bs: int, chunk: int,
-                          quantized: bool):
+                          quantized: bool, latent_v: int):
     """One prompt chunk of a single request against a paged KV pool.
 
     Grid (kv-head, table-slot); the innermost dimension walks the
@@ -411,12 +440,15 @@ def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, v_ref, *rest,
     point at the reserved null block) and skip compute entirely; rows of
     the chunk past ``chunk_len`` (last-chunk padding) are garbage by
     contract and masked down to a nonempty-but-meaningless context so
-    they stay finite.
+    they stay finite. With ``latent_v`` (MLA) there is no V pool: the
+    values are the first ``latent_v`` lanes of the key rows.
     """
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    else:
+    if latent_v:
         o_ref, m_ref, l_ref, acc_ref = rest
+    elif quantized:
+        v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
+    else:
+        v_ref, o_ref, m_ref, l_ref, acc_ref = rest
     i = pl.program_id(1)
 
     @pl.when(i == 0)
@@ -432,7 +464,10 @@ def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, v_ref, *rest,
     def _compute():
         q = q_ref[0].astype(jnp.float32)           # [gc, d]
         k = k_ref[0, 0].astype(jnp.float32)        # [bs, d]
-        v = v_ref[0, 0].astype(jnp.float32)        # [bs, d]
+        if latent_v:
+            v = k[:, :latent_v]
+        else:
+            v = v_ref[0, 0].astype(jnp.float32)    # [bs, d]
         if quantized:
             k = k * ks_ref[0, 0]                   # per-row absmax scales
             v = v * vs_ref[0, 0]
@@ -461,7 +496,7 @@ def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, v_ref, *rest,
 
 def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
                             ctx_len, *, scale: Optional[float] = None,
-                            k_scales=None, v_scales=None,
+                            k_scales=None, v_scales=None, latent_v: int = 0,
                             interpret: bool = False):
     """Chunked-prefill attention for one request over a paged KV cache.
 
@@ -475,6 +510,10 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
     ``k_scales``/``v_scales`` ([Hkv, NB, bs, 1] float32) the pools are
     int8 and dequantized in-kernel. Rows past ``chunk_len`` are padding
     and return garbage (finite) values. Returns [Hq, C, D].
+
+    Latent attention (MLA) passes ``v_pages=None`` and ``latent_v``: one
+    pool [1, NB, bs, D] of key rows whose first ``latent_v`` lanes are the
+    values; each page is read once. Returns [Hq, C, latent_v] then.
     """
     hq, c, d = q.shape
     hkv, _, bs, _ = k_pages.shape
@@ -482,21 +521,29 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
     t = block_table.shape[0]
     scale = scale if scale is not None else d ** -0.5
     quantized = k_scales is not None
+    if (v_pages is None) != bool(latent_v) or (latent_v and (
+            hkv != 1 or quantized)):
+        raise ValueError("latent attention is one bf16 pool, one KV head, "
+                         "and no V pool; a V pool needs latent_v=0")
+    dv = latent_v or d
     # group-major rows: [Hq, C, D] -> [Hkv, G, C, D] -> [Hkv, G*C, D]
     qg = q.reshape(hkv, g, c, d).reshape(hkv, g * c, d)
     meta = jnp.stack([jnp.asarray(q_offset, jnp.int32),
                       jnp.asarray(ctx_len, jnp.int32)])
 
     kernel = functools.partial(_paged_prefill_kernel, scale=scale, bs=bs,
-                               chunk=c, quantized=quantized)
+                               chunk=c, quantized=quantized,
+                               latent_v=latent_v)
+    page = pl.BlockSpec((1, 1, bs, d),
+                        lambda h, i, tbl, meta_: (h, tbl[i], 0, 0))
     in_specs = [
         pl.BlockSpec((1, g * c, d), lambda h, i, tbl, meta_: (h, 0, 0)),
-        pl.BlockSpec((1, 1, bs, d),
-                     lambda h, i, tbl, meta_: (h, tbl[i], 0, 0)),
-        pl.BlockSpec((1, 1, bs, d),
-                     lambda h, i, tbl, meta_: (h, tbl[i], 0, 0)),
+        page,
     ]
-    operands = [qg, k_pages, v_pages]
+    operands = [qg, k_pages]
+    if not latent_v:
+        in_specs.append(page)
+        operands.append(v_pages)
     if quantized:
         in_specs += [
             pl.BlockSpec((1, 1, bs, 1),
@@ -510,24 +557,24 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
         num_scalar_prefetch=2,
         grid=(hkv, t),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g * c, d),
+        out_specs=pl.BlockSpec((1, g * c, dv),
                                lambda h, i, tbl, meta_: (h, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((g * c,), jnp.float32),
             pltpu.VMEM((g * c,), jnp.float32),
-            pltpu.VMEM((g * c, d), jnp.float32),
+            pltpu.VMEM((g * c, dv), jnp.float32),
         ],
     )
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hkv, g * c, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((hkv, g * c, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_prefill_attention",
     )(block_table.astype(jnp.int32), meta, *operands)
-    return o.reshape(hkv, g, c, d).reshape(hq, c, d)
+    return o.reshape(hkv, g, c, dv).reshape(hq, c, dv)
 
 
 # ------------------------------------------------------------ backward ----

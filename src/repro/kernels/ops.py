@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from repro.kernels import flash_attention as _fa
 from repro.kernels import lora_matmul as _lm
 from repro.kernels import mlstm as _ml
+from repro.kernels import moe as _moe
 from repro.kernels import quantize as _qz
 
 
@@ -101,17 +102,21 @@ def flash_attention_ad(q, k, v, scale=None, causal=True, window=None,
 
 # Serving hot path (repro.serve): single-token decode against the paged
 # KV pool. No autodiff — decode never backpropagates.
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "latent_v",
+                                             "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
                            scale=None, k_scales=None, v_scales=None,
-                           interpret=None):
+                           latent_v=0, interpret=None):
     """q: [B, Hq, D] decode queries; k_pages/v_pages: [Hkv, NB, bs, D]
     block pools; block_tables: [B, T] logical->physical maps; ctx_lens:
     [B] visible KV lengths. Pass ``k_scales``/``v_scales`` for int8
-    pools (dequantized in-kernel). Returns [B, Hq, D]."""
+    pools (dequantized in-kernel). Returns [B, Hq, D]. Latent attention
+    passes ``v_pages=None`` and ``latent_v``: values are the first
+    ``latent_v`` lanes of the one pool's rows (returns [B, Hq, latent_v])."""
     return _fa.paged_decode_attention(q, k_pages, v_pages, block_tables,
                                       ctx_lens, scale=scale,
                                       k_scales=k_scales, v_scales=v_scales,
+                                      latent_v=latent_v,
                                       interpret=_auto_interpret(interpret))
 
 
@@ -119,20 +124,23 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
 # pool. q_offset/ctx_len stay traced so every chunk of every prompt
 # length shares one compiled call. No autodiff — prefill never
 # backpropagates.
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+@functools.partial(jax.jit, static_argnames=("scale", "latent_v",
+                                             "interpret"))
 def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
                             ctx_len, *, scale=None, k_scales=None,
-                            v_scales=None, interpret=None):
+                            v_scales=None, latent_v=0, interpret=None):
     """q: [Hq, C, D] query chunk (row c at position q_offset + c);
     k_pages/v_pages: [Hkv, NB, bs, D] block pools already holding the
     chunk's own K/V rows; block_table: [T] logical->physical map;
     q_offset/ctx_len: int32 scalars (ctx_len = q_offset + chunk_len).
     Pass ``k_scales``/``v_scales`` for int8 pools (dequantized
-    in-kernel). Returns [Hq, C, D]; rows past chunk_len are garbage."""
+    in-kernel). Returns [Hq, C, D]; rows past chunk_len are garbage.
+    Latent attention passes ``v_pages=None`` and ``latent_v`` as in
+    :func:`paged_decode_attention`."""
     return _fa.paged_prefill_attention(q, k_pages, v_pages, block_table,
                                        q_offset, ctx_len, scale=scale,
                                        k_scales=k_scales,
-                                       v_scales=v_scales,
+                                       v_scales=v_scales, latent_v=latent_v,
                                        interpret=_auto_interpret(interpret))
 
 
@@ -159,6 +167,20 @@ def paged_verify_attention(q, k_pages, v_pages, block_tables, ctx_lens,
         for b in range(q.shape[0])
     ]
     return jnp.stack(outs)
+
+
+# Serving hot path (repro.serve): the held experts of an MoE layer as
+# one grouped matmul over expert-sorted, tile-padded rows.
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def moe_expert_ffn(x, tile_expert, n_live, layer, wi, wg, wo, *, tile,
+                   interpret=None):
+    """x: [M, d] rows sorted by expert, each expert's padded to whole
+    ``tile``s; tile_expert: [M / tile] int32; n_live: [1] int32 live
+    tiles; layer: [1] int32, the layer of the stacks wi/wg: [L, E, d, f],
+    wo: [L, E, f, d]. Returns [M, d] (zero rows past the live tiles)."""
+    return _moe.moe_expert_ffn(x, tile_expert, n_live, layer, wi, wg, wo,
+                               tile=tile,
+                               interpret=_auto_interpret(interpret))
 
 
 # Codec hot path (repro.comm): no custom_vjp — encode/decode runs outside

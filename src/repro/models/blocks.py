@@ -345,15 +345,18 @@ def mlp(p: dict, x: jnp.ndarray, lora: Optional[dict] = None,
 
 
 # ----------------------------------------------------------------- moe -----
-def init_moe(key, cfg: ModelConfig) -> dict:
+def init_moe(key, cfg: ModelConfig, experts: Optional[int] = None) -> dict:
+    """The router over all experts and the weights of ``experts`` of them
+    (all by default)."""
     d, e, de = cfg.d_model, cfg.moe.num_experts, cfg.moe.d_expert
+    n = e if experts is None else experts
     ks = jax.random.split(key, 4)
     dt = cfg.dtype
     return {
         "router": (jax.random.normal(ks[0], (d, e)) * d ** -0.5).astype(jnp.float32),
-        "wi": (jax.random.normal(ks[1], (e, d, de)) * d ** -0.5).astype(dt),
-        "wg": (jax.random.normal(ks[2], (e, d, de)) * d ** -0.5).astype(dt),
-        "wo": (jax.random.normal(ks[3], (e, de, d)) * de ** -0.5).astype(dt),
+        "wi": (jax.random.normal(ks[1], (n, d, de)) * d ** -0.5).astype(dt),
+        "wg": (jax.random.normal(ks[2], (n, d, de)) * d ** -0.5).astype(dt),
+        "wo": (jax.random.normal(ks[3], (n, de, d)) * de ** -0.5).astype(dt),
     }
 
 
@@ -419,6 +422,194 @@ def moe_block(p: dict, x: jnp.ndarray, cfg: ModelConfig):
     zloss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2) \
         * cfg.moe.router_z_weight
     return out.reshape(b, s, d), aux + zloss
+
+
+# ------------------------------------------------- held-expert layer -----
+def init_held_moe(key, cfg: ModelConfig) -> dict:
+    """``init_moe``'s router and the weights of the experts this chip
+    holds (``cfg.moe.held`` of them), the selection bias where the router
+    has one, and the shared experts as one SwiGLU."""
+    m = cfg.moe
+    k_moe, k_shared = jax.random.split(key)
+    p = init_moe(k_moe, cfg, m.held)
+    if m.selection_bias:
+        p["bias"] = jnp.zeros((m.num_experts,), jnp.float32)
+    if m.num_shared_experts:
+        p["shared"] = init_mlp(k_shared, cfg.replace(
+            d_ff=m.num_shared_experts * m.d_expert))
+    return p
+
+
+def route(p: dict, x: jnp.ndarray, cfg: ModelConfig):
+    """The published router over all experts, in float32. x: [T, d].
+    Returns (experts [T, k] int32, weights [T, k] float32): the top-k of
+    the scores (plus the selection bias, which moves the choice and not
+    the weights), renormalized where ``norm_topk``, times
+    ``route_scale``. DeepSeek-V3's group-limited selection with
+    ``n_group = topk_group = 1`` keeps every expert, so there are no
+    groups here."""
+    m = cfg.moe
+    logits = jnp.matmul(x.astype(jnp.float32), p["router"],
+                        precision=jax.lax.Precision.HIGHEST)
+    if m.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    elif m.score_func == "softmax":
+        scores = jax.nn.softmax(logits, axis=-1)
+    else:
+        raise ValueError(f"unknown score_func {m.score_func!r}")
+    choice = scores + p["bias"] if m.selection_bias else scores
+    _, idx = jax.lax.top_k(choice, m.top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if m.norm_topk:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * m.route_scale
+
+
+#: rows of one tile of the held-expert grouped matmul: every tile holds
+#: rows of one expert only, so each expert's rows are padded to it
+EXPERT_TILE = 16
+
+
+#: the held experts' weights in an expert layer's parameters
+EXPERT_WEIGHTS = ("wi", "wg", "wo")
+
+
+def held_moe(p: dict, x: jnp.ndarray, cfg: ModelConfig, layer,
+             valid=None):
+    """Dropless MoE over the experts this chip holds, plus the shared
+    experts. x: [B, S, d]; ``valid`` [B, S] bool marks the tokens to
+    route (padding rows and dead lanes get no expert). ``p``'s expert
+    weights (``EXPERT_WEIGHTS``) are the stacks of every MoE layer
+    ``[L, E, ...]`` and ``layer`` (an int32 scalar) picks this layer's:
+    a loop over the layers passes the stacks whole, which spares it a
+    copy of each layer's experts; its router, bias and shared experts are
+    this layer's.
+
+    Every token is routed over all ``num_experts``; the (token, expert)
+    assignments that land on experts ``[expert_offset, expert_offset +
+    held)`` are sorted by expert, each expert's rows padded to
+    ``EXPERT_TILE``, and computed by one grouped matmul over the held
+    experts (``kernels.ops.moe_expert_ffn``). No assignment is dropped.
+    What the experts held elsewhere would add is not computed here; on
+    one chip the layer runs without the exchange that would bring it.
+    Returns (out [B, S, d], stats int32 [3]: assignments on held
+    experts, held experts hit, most rows on one held expert)."""
+    from repro.kernels import ops as kops
+
+    m = cfg.moe
+    b, s, d = x.shape
+    t, k, eh = b * s, m.top_k, m.held
+    xt = x.reshape(t, d)
+    with jax.named_scope("router"):
+        idx, w = route(p, xt, cfg)
+    with jax.named_scope("experts"):
+        local = idx - m.expert_offset
+        held = (local >= 0) & (local < eh)
+        if valid is not None:
+            held &= valid.reshape(t, 1)
+        flat = jnp.where(held, local, eh).reshape(t * k)   # eh: not here
+        sizes = jnp.zeros((eh + 1,), jnp.int32).at[flat].add(1)[:eh]
+        tiles = -(-sizes // EXPERT_TILE)
+        tile_end = jnp.cumsum(tiles)
+        start = (tile_end - tiles) * EXPERT_TILE            # padded starts
+        # rank of each assignment within its expert (stable by token)
+        order = jnp.argsort(flat, stable=True)
+        fs = flat[order]
+        first = jnp.searchsorted(fs, jnp.arange(eh + 1, dtype=fs.dtype))
+        rank = jnp.arange(t * k) - first[fs]
+        n_rows = t * min(k, eh) + eh * (EXPERT_TILE - 1)
+        n_rows = -(-n_rows // EXPERT_TILE) * EXPERT_TILE
+        row_sorted = jnp.where(fs < eh,
+                               start[jnp.minimum(fs, eh - 1)] + rank, n_rows)
+        row = jnp.zeros((t * k,), jnp.int32).at[order].set(row_sorted)
+        xs = jnp.zeros((n_rows, d), x.dtype).at[row].set(
+            jnp.repeat(xt, k, axis=0), mode="drop")
+        n_tiles = n_rows // EXPERT_TILE
+        tile_expert = jnp.minimum(
+            jnp.searchsorted(tile_end, jnp.arange(n_tiles), side="right"),
+            eh - 1).astype(jnp.int32)
+        ys = kops.moe_expert_ffn(xs, tile_expert, tile_end[-1:],
+                                 jnp.reshape(layer, (1,)),
+                                 *(p[n] for n in EXPERT_WEIGHTS),
+                                 tile=EXPERT_TILE)
+        got = ys[jnp.minimum(row, n_rows - 1)].reshape(t, k, d)
+        wk = jnp.where(held, w, 0.0)
+        out = jnp.einsum("tkd,tk->td", got.astype(jnp.float32), wk)
+        stats = jnp.stack([sizes.sum(), (sizes > 0).sum(), sizes.max()])
+    if "shared" in p:
+        with jax.named_scope("shared"):
+            out = out + mlp(p["shared"], xt).astype(jnp.float32)
+    return out.astype(x.dtype).reshape(b, s, d), stats.astype(jnp.int32)
+
+
+# ------------------------------------------------ latent attention (MLA) --
+def init_mla(key, cfg: ModelConfig) -> dict:
+    """MLA weights in the published layout: ``wq`` [d, H * (nope +
+    rope)], ``wkva`` [d, kv_lora_rank + rope] (the latent and the shared
+    rotary key), the latent's RMSNorm, ``wkvb`` [kv_lora_rank, H * (nope
+    + v)] (each head's key and value from the latent), ``wo``."""
+    a, d, h = cfg.mla, cfg.d_model, cfg.num_heads
+    if a.q_lora_rank:
+        raise NotImplementedError("MLA with a query LoRA (q_lora_rank > 0)")
+    ks = jax.random.split(key, 4)
+    dt = cfg.dtype
+
+    def w(k, shape, fan_in):
+        return (jax.random.normal(k, shape) * fan_in ** -0.5).astype(dt)
+
+    r = a.kv_lora_rank
+    return {"wq": w(ks[0], (d, h * a.qk_head_dim), d),
+            "wkva": w(ks[1], (d, a.row), d),
+            "kv_norm": init_rmsnorm(r, dt),
+            "wkvb": w(ks[2], (r, h * (a.qk_nope_head_dim + a.v_head_dim)), r),
+            "wo": w(ks[3], (h * a.v_head_dim, d), h * a.v_head_dim)}
+
+
+def _deinterleave(x):
+    """[..., 2n] with rotary pairs at lanes (2i, 2i + 1) -> the two-halves
+    layout ``rope`` rotates: even lanes, then odd lanes."""
+    return jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+
+
+def mla_absorbed(p: dict, h: jnp.ndarray, positions, cfg: ModelConfig):
+    """Latent-space query and cache row of MLA with the key up-projection
+    absorbed into the query. h: [B, S, d] normed; positions [B, S].
+
+    Returns (q [B, S, H, r + rope]: ``q_nope_h · W_kvb^{k,h}ᵀ`` beside the
+    rotated ``q_pe_h``; row [B, S, r + rope]: the normed latent beside the
+    rotated shared key). ``q · row`` is each head's published score
+    ``q_nope·k_nope + q_pe·k_pe``."""
+    a, nh = cfg.mla, cfg.num_heads
+    b, s, _ = h.shape
+    r, nope = a.kv_lora_rank, a.qk_nope_head_dim
+    q = (h @ p["wq"]).reshape(b, s, nh, a.qk_head_dim)
+    kva = h @ p["wkva"]                                  # [B, S, r + rope]
+    c = rms_norm(p["kv_norm"], kva[..., :r], a.kv_norm_eps)
+    q_pe, k_pe = q[..., nope:], kva[..., None, r:]       # [B, S, 1, rope]
+    if a.rope_interleave:
+        q_pe, k_pe = _deinterleave(q_pe), _deinterleave(k_pe)
+    q_pe = rope(q_pe.transpose(0, 2, 1, 3), positions,
+                cfg.rope_theta).transpose(0, 2, 1, 3)
+    k_pe = rope(k_pe.transpose(0, 2, 1, 3), positions,
+                cfg.rope_theta)[:, 0]                    # [B, S, rope]
+    with jax.named_scope("latent_absorb"):
+        wk = p["wkvb"].reshape(r, nh, -1)[..., :nope]    # [r, H, nope]
+        q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope], wk,
+                           preferred_element_type=jnp.float32)
+    return (jnp.concatenate([q_lat.astype(h.dtype), q_pe], axis=-1),
+            jnp.concatenate([c, k_pe], axis=-1))
+
+
+def mla_output(p: dict, o_lat: jnp.ndarray, cfg: ModelConfig):
+    """Heads' latent outputs [B, S, H, r] -> [B, S, d]: each head's value
+    up-projection ``W_kvb^{v,h}``, then ``wo``."""
+    a, nh = cfg.mla, cfg.num_heads
+    b, s = o_lat.shape[:2]
+    wv = p["wkvb"].reshape(a.kv_lora_rank, nh, -1)[..., a.qk_nope_head_dim:]
+    with jax.named_scope("latent_absorb"):
+        o = jnp.einsum("bshr,rhv->bshv", o_lat.astype(wv.dtype), wv,
+                       preferred_element_type=jnp.float32)
+    return o.astype(p["wo"].dtype).reshape(b, s, nh * a.v_head_dim) @ p["wo"]
 
 
 # ------------------------------------------------------------ embedding ----

@@ -72,7 +72,40 @@ def apply_block(p: dict, x, cfg: ModelConfig, *, positions, cache=None,
     return x + f, new_cache, aux
 
 
+def init_latent(key, cfg: ModelConfig) -> dict:
+    """Parameters of a latent-attention (MLA) model with experts: the
+    leading dense layers stacked under ``dense``, the expert layers
+    (held experts only, ``cfg.moe.held``) under ``blocks``. Only the
+    paged engine runs this tree."""
+    nd = cfg.moe.first_dense_layers
+    ks = jax.random.split(key, 5)
+
+    def layer(k, ffn):
+        k1, k2 = jax.random.split(k)
+        return {"ln1": B.init_rmsnorm(cfg.d_model, cfg.dtype),
+                "attn": B.init_mla(k1, cfg),
+                "ln2": B.init_rmsnorm(cfg.d_model, cfg.dtype),
+                **ffn(k2)}
+
+    params = {
+        "embed": B.init_embedding(ks[0], cfg.vocab_size, cfg.d_model,
+                                  cfg.dtype),
+        "blocks": jax.vmap(lambda k: layer(
+            k, lambda k2: {"moe": B.init_held_moe(k2, cfg)}))(
+                jax.random.split(ks[1], cfg.num_layers - nd)),
+        "ln_f": B.init_rmsnorm(cfg.d_model, cfg.dtype),
+        "head": B.init_linear(ks[2], cfg.d_model, cfg.vocab_size, cfg.dtype),
+    }
+    if nd:
+        params["dense"] = jax.vmap(lambda k: layer(
+            k, lambda k2: {"ffn": B.init_mlp(k2, cfg)}))(
+                jax.random.split(ks[3], nd))
+    return params
+
+
 def init(key, cfg: ModelConfig) -> dict:
+    if cfg.latent:
+        return init_latent(key, cfg)
     ks = jax.random.split(key, 4)
     layer_keys = jax.random.split(ks[0], cfg.num_layers)
     params = {

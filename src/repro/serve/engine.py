@@ -21,6 +21,24 @@ per-request contiguous cache:
 The numerics match the contiguous path op for op (same rope-after-norm
 order, float32 softmax statistics), which is what the paged-vs-contiguous
 equivalence test in ``tests/test_serve.py`` pins down.
+
+Latent attention (MLA, ``cfg.latent``) runs the same two walks in its
+absorbed form: each layer writes one latent row per token (the normed
+latent beside the rotated shared key) into a one-head latent pool, each
+head's query is taken into the latent (``attention/latent_absorb``), the
+paged kernels attend over the rows with the latent's lanes as values,
+and the heads' value up-projections follow. Leading dense layers
+(``moe.first_dense_layers``) run as a walk of their own before the walk
+over the expert layers.
+
+MoE layers on this path are the dropless held-expert layer
+(:func:`repro.models.blocks.held_moe`: ``mlp/router``, ``mlp/experts``,
+``mlp/shared``), never the capacity-routed ``moe_block`` of training.
+For such a model both programs also return an int32 ``[L_moe, 3]`` of
+the expert load per layer (assignments on held experts, held experts
+hit, most rows on one held expert); ``decode`` and ``prefill_chunk``
+keep it on the device as ``moe_stats`` for the scheduler to read with
+the sampled tokens.
 """
 from __future__ import annotations
 
@@ -31,11 +49,28 @@ import jax
 import jax.numpy as jnp
 
 from repro.config import ModelConfig
+from repro.kernels import ops as kops
 from repro.models import blocks as B
 from repro.models import lm
 from repro.serve import kvcache as KC
 
 _PAGED_FAMILIES = ("dense", "moe")
+
+
+def _layer_xs(blocks: dict, pools):
+    """What a scan over the layers walks: (blocks, pools), and for a model
+    with experts also each layer's index, with the held experts' weights
+    taken out of ``blocks`` and returned beside as the whole stacks
+    ``[L, E, ...]``. The grouped matmul picks its layer's experts from the
+    stacks, so the scan slices and copies none of them."""
+    if "moe" not in blocks:
+        return (blocks, pools), None
+    moe = blocks["moe"]
+    stacks = {n: moe[n] for n in B.EXPERT_WEIGHTS}
+    blocks = dict(blocks, moe={n: w for n, w in moe.items()
+                               if n not in stacks})
+    n = jax.tree.leaves(pools)[0].shape[0]
+    return (blocks, pools, jnp.arange(n, dtype=jnp.int32)), stacks
 
 
 class PagedEngine:
@@ -50,6 +85,10 @@ class PagedEngine:
         if cfg.window is not None:
             raise NotImplementedError(
                 "paged serving assumes full causal attention (window=None)")
+        if cfg.moe.first_dense_layers and not cfg.latent:
+            raise NotImplementedError(
+                "leading dense layers are served for latent-attention "
+                "models only")
         if max_context > spec.max_tokens_per_req:
             raise ValueError(
                 f"max_context {max_context} exceeds the table capacity "
@@ -64,6 +103,10 @@ class PagedEngine:
         self._verify = jax.jit(self._verify_impl)
         self._write = jax.jit(functools.partial(KC.write_prefill, spec=spec))
         self._copy_block = jax.jit(self._copy_block_impl)
+        #: per-layer expert load of the last ``decode`` / ``prefill_chunk``
+        #: (int32 [L_moe, 3] on the device); None for a model without
+        #: experts
+        self.moe_stats = None
 
     # ---- pools --------------------------------------------------------
     def init_pools(self) -> Dict:
@@ -74,6 +117,11 @@ class PagedEngine:
         """tokens: [1, max_context] int32 (padded); length: scalar int32.
         Returns (last-token logits [1, V], k [L, Hkv, Smax, D], v)."""
         cfg = self.cfg
+        if cfg.latent or cfg.moe.num_experts:
+            raise NotImplementedError(
+                "monolithic prefill runs the training forward (capacity-"
+                "routed experts, no latent pool); serve a latent-attention "
+                "or expert model with prefill='chunked'")
         caches = lm.init_cache(cfg, 1, self.max_context)
         x, new_caches, _ = lm.forward(params, cfg, tokens, caches=caches,
                                       hidden_only=True)
@@ -110,7 +158,8 @@ class PagedEngine:
         the updated pools). Mirrors ``_decode_impl`` op for op so chunked
         and monolithic prefill agree bit-for-bit in greedy streams.
         Its phases carry the same ``jax.named_scope`` names as
-        ``_decode_impl``'s."""
+        ``_decode_impl``'s. A model with experts also returns their load
+        (int32 [L_moe, 3])."""
         cfg, spec = self.cfg, self.spec
         nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
         c = tokens.shape[0]
@@ -123,10 +172,23 @@ class PagedEngine:
         blk = pos // spec.block_size
         phys = jnp.where(rows < chunk_len, table[blk], 0)  # [C]
         off = pos % spec.block_size
+        valid = (rows < chunk_len)[None]                   # [1, C]
+        if cfg.latent:
+            def attend(q, pool):                           # [1, C, H, row]
+                o = kops.paged_prefill_attention(
+                    q[0].transpose(1, 0, 2), pool, None, table, q_offset,
+                    q_offset + chunk_len, scale=cfg.mla.qk_head_dim ** -0.5,
+                    latent_v=cfg.mla.kv_lora_rank)         # [H, C, r]
+                return o.transpose(1, 0, 2)[None]
+            x, new_pools, stats = self._latent_walk(
+                params, pools, x, positions, phys, off, valid, attend)
+            return self._head(params, x[:, chunk_len - 1]), new_pools, stats
+
+        xs, stacks = _layer_xs(params["blocks"], pools)
 
         def body(carry, layer):
             h_in = carry
-            lp, layer_pools = layer
+            lp, layer_pools = layer[:2]
             ap = lp["attn"]
             with jax.named_scope("attention"):
                 h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
@@ -147,7 +209,6 @@ class PagedEngine:
                 with jax.named_scope("kv_write"):
                     new_pools = KC.append_token(layer_pools, spec, k[0],
                                                 v[0], phys, off)
-                from repro.kernels import ops as kops
                 o = kops.paged_prefill_attention(
                     q[0], new_pools["k"], new_pools["v"], table,
                     q_offset, q_offset + chunk_len, scale=scale,
@@ -157,26 +218,84 @@ class PagedEngine:
                                @ ap["wo"]).astype(h_in.dtype)
             with jax.named_scope("mlp"):
                 hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
-                if "moe" in lp:
-                    f, _ = B.moe_block(lp["moe"], hh, cfg)
-                else:
-                    f = B.mlp(lp["ffn"], hh)
+                if stacks is not None:
+                    f, st = B.held_moe(dict(lp["moe"], **stacks), hh, cfg,
+                                       layer[2], valid)
+                    return h_in + f, (new_pools, st)
+                f = B.mlp(lp["ffn"], hh)
             return h_in + f, new_pools
 
-        x, new_pools = jax.lax.scan(body, x, (params["blocks"], pools))
-        with jax.named_scope("head"):
-            h = B.rms_norm(params["ln_f"], x[:, chunk_len - 1],
-                           cfg.norm_eps)
-            if cfg.tie_embeddings:
-                logits = B.unembed(params["embed"], h[:, None])[:, 0]
-            else:
-                logits = B.linear(params["head"], h).astype(jnp.float32)
-        return logits, new_pools
+        x, ys = jax.lax.scan(body, x, xs)
+        logits = self._head(params, x[:, chunk_len - 1])
+        return (logits,) + (ys if cfg.moe.num_experts else (ys,))
 
     def prefill_chunk(self, params, pools, tokens, table, q_offset,
                       chunk_len) -> Tuple:
-        return self._prefill_chunk(params, pools, tokens, table,
-                                   jnp.int32(q_offset), jnp.int32(chunk_len))
+        out = self._prefill_chunk(params, pools, tokens, table,
+                                  jnp.int32(q_offset), jnp.int32(chunk_len))
+        return self._keep_stats(out)
+
+    def _keep_stats(self, out) -> Tuple:
+        """(logits, pools), keeping a program's expert load aside."""
+        if len(out) == 3:
+            self.moe_stats = out[2]
+        return out[0], out[1]
+
+    def _head(self, params, h):
+        """Final norm and output head of hidden rows [B, d] -> [B, V]."""
+        with jax.named_scope("head"):
+            h = B.rms_norm(params["ln_f"], h, self.cfg.norm_eps)
+            if self.cfg.tie_embeddings:
+                return B.unembed(params["embed"], h[:, None])[:, 0]
+            return B.linear(params["head"], h).astype(jnp.float32)
+
+    def _latent_walk(self, params, pools, x, positions, phys, off, valid,
+                     attend):
+        """The layers of a latent-attention model over tokens x [B, S, d]
+        at ``positions`` [B, S]: each writes its rows at ``(phys, off)``
+        [B * S] and attends through ``attend(q, pool)`` (q [B, S, H, row]
+        -> latent outputs [B, S, H, r]). The leading dense layers walk
+        first, then the expert layers. Returns (x, pools, expert load
+        [L_moe, 3])."""
+        cfg = self.cfg
+
+        def layer(h_in, lp, pool):
+            with jax.named_scope("attention"):
+                h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
+                q, row = B.mla_absorbed(lp["attn"], h, positions, cfg)
+                with jax.named_scope("kv_write"):
+                    pool = KC.append_latent(
+                        pool, row.reshape(-1, row.shape[-1]), phys, off)
+                o = attend(jnp.pad(q, ((0, 0),) * 3 + (
+                    (0, pool.shape[-1] - q.shape[-1]),)), pool)
+                h_in = h_in + B.mla_output(lp["attn"], o, cfg).astype(
+                    h_in.dtype)
+            with jax.named_scope("mlp"):
+                return h_in, B.rms_norm(lp["ln2"], h_in, cfg.norm_eps), pool
+
+        def dense(h_in, xs):
+            lp, pool = xs
+            h_in, hh, pool = layer(h_in, lp, pool)
+            with jax.named_scope("mlp"):
+                f = B.mlp(lp["ffn"], hh)
+            return h_in + f, pool
+
+        xs, stacks = _layer_xs(params["blocks"], pools["latent"])
+
+        def expert(h_in, xs):
+            lp, pool, i = xs
+            h_in, hh, pool = layer(h_in, lp, pool)
+            with jax.named_scope("mlp"):
+                f, st = B.held_moe(dict(lp["moe"], **stacks), hh, cfg, i,
+                                   valid)
+            return h_in + f, (pool, st)
+
+        new_pools = {}
+        if "latent_dense" in pools:
+            x, new_pools["latent_dense"] = jax.lax.scan(
+                dense, x, (params["dense"], pools["latent_dense"]))
+        x, (new_pools["latent"], stats) = jax.lax.scan(expert, x, xs)
+        return x, new_pools, stats
 
     def _copy_block_impl(self, pools, src, dst):
         """Copy-on-write helper: clone physical block ``src`` into ``dst``
@@ -198,7 +317,8 @@ class PagedEngine:
         names that device traces keep in the operations' metadata:
         ``attention`` (norm, projections, rope, the paged kernel, the
         output projection), ``attention/kv_write`` (the token's K/V
-        into its pool block), ``mlp``; then ``head``."""
+        into its pool block), ``mlp``; then ``head``. A model with
+        experts also returns their load (int32 [L_moe, 3])."""
         cfg, spec = self.cfg, self.spec
         nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
         slots = tokens.shape[0]
@@ -212,10 +332,23 @@ class PagedEngine:
         # the kernel's context: the pending token included, none for a
         # dead lane (its table starts at the null block)
         attend = jnp.where(tables[:, 0] != 0, ctx_lens + 1, 0)
+        valid = (attend > 0)[:, None]                      # [slots, 1]
+        if cfg.latent:
+            def latent_attend(q, pool):                    # [slots,1,H,row]
+                return kops.paged_decode_attention(
+                    q[:, 0], pool, None, tables, attend,
+                    scale=cfg.mla.qk_head_dim ** -0.5,
+                    latent_v=cfg.mla.kv_lora_rank)[:, None]
+            x, new_pools, stats = self._latent_walk(
+                params, pools, x, positions, phys, off, valid,
+                latent_attend)
+            return self._head(params, x[:, 0]), new_pools, stats
+
+        xs, stacks = _layer_xs(params["blocks"], pools)
 
         def body(carry, layer):
             h_in = carry
-            lp, layer_pools = layer
+            lp, layer_pools = layer[:2]
             ap = lp["attn"]
             with jax.named_scope("attention"):
                 h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
@@ -238,7 +371,6 @@ class PagedEngine:
                     v_tok = v[:, :, 0].transpose(1, 0, 2)
                     new_pools = KC.append_token(layer_pools, spec, k_tok,
                                                 v_tok, phys, off)
-                from repro.kernels import ops as kops
                 o = kops.paged_decode_attention(
                     q[:, :, 0], new_pools["k"], new_pools["v"], tables,
                     attend, scale=scale,
@@ -248,13 +380,14 @@ class PagedEngine:
                                @ ap["wo"]).astype(h_in.dtype)
             with jax.named_scope("mlp"):
                 hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
-                if "moe" in lp:
-                    f, _ = B.moe_block(lp["moe"], hh, cfg)
-                else:
-                    f = B.mlp(lp["ffn"], hh)
+                if stacks is not None:
+                    f, st = B.held_moe(dict(lp["moe"], **stacks), hh, cfg,
+                                       layer[2], valid)
+                    return h_in + f, (new_pools, st)
+                f = B.mlp(lp["ffn"], hh)
             return h_in + f, new_pools
 
-        x, new_pools = jax.lax.scan(body, x, (params["blocks"], pools))
+        x, ys = jax.lax.scan(body, x, xs)
         with jax.named_scope("head"):
             x = B.rms_norm(params["ln_f"], x, cfg.norm_eps)
             if cfg.tie_embeddings:
@@ -262,10 +395,11 @@ class PagedEngine:
             else:
                 logits = B.linear(params["head"], x).astype(
                     jnp.float32)[:, 0]
-        return logits, new_pools
+        return (logits,) + (ys if cfg.moe.num_experts else (ys,))
 
     def decode(self, params, pools, tokens, tables, ctx_lens) -> Tuple:
-        return self._decode(params, pools, tokens, tables, ctx_lens)
+        return self._keep_stats(
+            self._decode(params, pools, tokens, tables, ctx_lens))
 
     # ---- speculative verify -------------------------------------------
     def _verify_impl(self, params, pools, tokens, tables, ctx_lens,
@@ -290,6 +424,9 @@ class PagedEngine:
         scheduler compares against the proposals for exact-match
         acceptance."""
         cfg, spec = self.cfg, self.spec
+        if cfg.latent:
+            raise NotImplementedError(
+                "speculative verify of a latent-attention model")
         nq, nkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.hd
         slots, c = tokens.shape
         scale = hd ** -0.5
@@ -304,9 +441,11 @@ class PagedEngine:
         phys = jnp.where(valid, phys, 0).reshape(-1)       # [slots*C]
         off = jnp.where(valid, safe_pos % spec.block_size, 0).reshape(-1)
 
+        xs, stacks = _layer_xs(params["blocks"], pools)
+
         def body(carry, layer):
             h_in = carry
-            lp, layer_pools = layer
+            lp, layer_pools = layer[:2]
             ap = lp["attn"]
             h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
             q = h @ ap["wq"]
@@ -327,7 +466,6 @@ class PagedEngine:
             v_rows = v.transpose(1, 0, 2, 3).reshape(nkv, slots * c, hd)
             new_pools = KC.append_token(layer_pools, spec, k_rows, v_rows,
                                         phys, off)
-            from repro.kernels import ops as kops
             o = kops.paged_verify_attention(
                 q, new_pools["k"], new_pools["v"], tables, ctx_lens,
                 chunk_lens, scale=scale,
@@ -337,13 +475,14 @@ class PagedEngine:
                                                            nq * hd)
                            @ ap["wo"]).astype(h_in.dtype)
             hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
-            if "moe" in lp:
-                f, _ = B.moe_block(lp["moe"], hh, cfg)
+            if stacks is not None:
+                f, _ = B.held_moe(dict(lp["moe"], **stacks), hh, cfg,
+                                  layer[2], valid)
             else:
                 f = B.mlp(lp["ffn"], hh)
             return h_in + f, new_pools
 
-        x, new_pools = jax.lax.scan(body, x, (params["blocks"], pools))
+        x, new_pools = jax.lax.scan(body, x, xs)
         x = B.rms_norm(params["ln_f"], x, cfg.norm_eps)
         if cfg.tie_embeddings:
             logits = B.unembed(params["embed"], x)

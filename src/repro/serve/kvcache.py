@@ -258,7 +258,27 @@ class PrefixCache:
 # ---------------------------------------------------------------- pools ----
 def init_pools(cfg: ModelConfig, spec: PagedCacheSpec) -> Dict:
     """Layer-stacked physical pools: k/v [L, Hkv, NB, bs, D] (+ float32
-    [..., 1] absmax scales in int8 mode)."""
+    [..., 1] absmax scales in int8 mode).
+
+    Latent attention (MLA) keeps one row per token per layer instead,
+    the normed latent beside the rotated shared key (``cfg.mla.row``
+    values, zero-padded to :func:`latent_width`): ``latent`` [L, 1, NB,
+    bs, width] for the expert layers and ``latent_dense`` for the
+    leading dense ones, so each of the engine's two layer walks owns its
+    pool whole."""
+    if cfg.latent:
+        if spec.quantized:
+            raise NotImplementedError(
+                "an int8 latent pool is not supported (bf16 latent only)")
+        nd = cfg.moe.first_dense_layers
+
+        def latent(n):
+            return jnp.zeros((n, 1, spec.num_blocks, spec.block_size,
+                              latent_width(cfg)), cfg.dtype)
+        pools = {"latent": latent(cfg.num_layers - nd)}
+        if nd:
+            pools["latent_dense"] = latent(nd)
+        return pools
     shape = (cfg.num_layers, cfg.num_kv_heads, spec.num_blocks,
              spec.block_size, cfg.hd)
     if spec.quantized:
@@ -270,6 +290,15 @@ def init_pools(cfg: ModelConfig, spec: PagedCacheSpec) -> Dict:
         }
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
+
+
+def latent_width(cfg: ModelConfig) -> int:
+    """Lanes of a latent pool row: ``cfg.mla.row`` rounded up to whole
+    128-lane tiles. Mosaic copies a page only where its lanes are whole:
+    the decode kernel's page copy of a 576-wide row (Moonlight's 512 +
+    64) is refused ("Slice shape ... must be aligned to tiling (128)"),
+    and the TPU's memory layout pads such a row to 640 lanes anyway."""
+    return -(-cfg.mla.row // LANES) * LANES
 
 
 def quantize_rows(x):
@@ -378,3 +407,12 @@ def append_token(pools: Dict, spec: PagedCacheSpec, k_tok, v_tok, phys, off
         out["v"] = pools["v"].at[:, phys, off].set(
             v_tok.astype(pools["v"].dtype))
     return out
+
+
+def append_latent(pool, row, phys, off):
+    """Write latent rows into one layer's latent pool [1, NB, bs, width]:
+    row [N, row] (zero-padded to the pool's width) at ``(phys, off)`` [N]
+    (padding and dead lanes point at the null block, where duplicate
+    writes are harmless)."""
+    row = jnp.pad(row, ((0, 0), (0, pool.shape[-1] - row.shape[-1])))
+    return pool.at[0, phys, off].set(row.astype(pool.dtype))

@@ -683,8 +683,12 @@ class ContinuousScheduler:
                 jnp.asarray(dec_tables), jnp.asarray(dec_ctx))
             self.decode_steps_run += 1
         with span("scheduler.tokens"):
-            nxt = np.asarray(self.sampler(logits, self._next_key()),
-                             np.int32)
+            nxt = self.sampler(logits, self._next_key())
+            if self.engine.moe_stats is None:
+                nxt = np.asarray(nxt, np.int32)
+            else:                 # the expert load, in the same transfer
+                nxt, load = jax.device_get((nxt, self.engine.moe_stats))
+                self._record_expert_load(load)
         with span("scheduler.commit"):
             emitted = 0
             for slot in np.flatnonzero(ready):
@@ -813,6 +817,27 @@ class ContinuousScheduler:
                           "accepted_drafts": acc, "emitted": emitted})
             self._pending_trace.append(emit_spec)
         return emitted
+
+    def _record_expert_load(self, load: np.ndarray) -> None:
+        """A decode step's expert load per MoE layer (rows: assignments
+        on held experts, held experts hit, most rows on one)."""
+        m = self.metrics
+        m.counter("serve_moe_held_assignments",
+                  "decode (token, expert) assignments on the experts held "
+                  "here, summed over the MoE layers").inc(
+                      float(load[:, 0].sum()))
+        held = self.engine.cfg.moe.held
+        buckets = tuple(float(b) for b in range(held + 1))
+        hit = m.histogram("serve_moe_experts_hit",
+                          "held experts with rows, per MoE layer and "
+                          "decode step", buckets=buckets)
+        busiest = m.histogram("serve_moe_busiest_expert",
+                              "most rows on one held expert, per MoE layer "
+                              "and decode step",
+                              buckets=tuple(float(2 ** i) for i in range(12)))
+        for n_hit, most in load[:, 1:]:
+            hit.observe(n_hit)
+            busiest.observe(most)
 
     def _sample_metrics(self, t: float) -> None:
         """Per-step registry sample (a host dict update): pool occupancy

@@ -525,3 +525,129 @@ def test_paged_decode_matches_contiguous_attention():
                                      jnp.asarray(phys, jnp.int32), ctx,
                                      interpret=True)
     assert float(jnp.max(jnp.abs(got - dense[:, :, 0]))) < 5e-6
+
+
+# ------------------------------------------- latent (MLA) paged kernels ---
+def _latent_values(pool, dv):
+    """The latent pool as the oracle's V pool: a row's first ``dv`` lanes,
+    the rest zero (the oracle's output keeps them zero)."""
+    return jnp.where(jnp.arange(pool.shape[-1]) < dv, pool, 0.0)
+
+
+# a latent row of 40 (32 + 8) padded to 128 lanes; Moonlight's 576 in 640
+@pytest.mark.parametrize("b,hq,d,dv,bs,ctx_list", [
+    (4, 4, 128, 32, 8, [13, 1, 0, 48]),
+    (3, 16, 640, 512, 16, [40, 0, 300]),
+])
+def test_paged_decode_latent(b, hq, d, dv, bs, ctx_list):
+    """One latent pool is keys and (its first ``dv`` lanes) values: the
+    kernel matches the gather oracle on those pools, every dead page and
+    dead lane poisoned with NaN, dead lanes giving zeros."""
+    nb = 1 + sum(-(-c // bs) for c in ctx_list) + 2
+    kp, _, tbl, ctx = _paged_setup(KEY, b, 1, nb, bs, d, ctx_list)
+    q = jax.random.normal(jax.random.fold_in(KEY, 43), (b, hq, d),
+                          jnp.float32)
+    scale = 192 ** -0.5
+    want = ref.paged_decode_attention_ref(q, kp, _latent_values(kp, dv), tbl,
+                                          ctx, scale=scale)[..., :dv]
+    got = ops.paged_decode_attention(
+        q, _poison_dead(kp, tbl, ctx_list, bs), None, tbl, ctx, scale=scale,
+        latent_v=dv, interpret=True)
+    assert got.shape == (b, hq, dv)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.max(jnp.abs(got - want))) < 5e-6
+    for i, c in enumerate(ctx_list):
+        if c == 0:
+            assert float(jnp.abs(got[i]).max()) == 0.0
+
+
+@pytest.mark.parametrize("hq,d,dv,bs,chunk,ctx,off", [
+    (4, 128, 32, 8, 8, 21, 8),
+    (16, 640, 512, 16, 32, 70, 64),
+])
+def test_paged_prefill_latent(hq, d, dv, bs, chunk, ctx, off):
+    """The chunked-prefill kernel over one latent pool (keys, and values
+    in the first ``dv`` lanes) matches the gather oracle."""
+    _, _, kp, _, tbl, _ = _prefill_pool_setup(jax.random.fold_in(KEY, 47),
+                                              1, bs, d, ctx)
+    q = jax.random.normal(jax.random.fold_in(KEY, 53), (hq, chunk, d),
+                          jnp.float32)
+    got = ops.paged_prefill_attention(q, kp, None, tbl, off, ctx,
+                                      scale=192 ** -0.5, latent_v=dv,
+                                      interpret=True)
+    want = ref.paged_prefill_attention_ref(q, kp, _latent_values(kp, dv),
+                                           tbl, off, ctx,
+                                           scale=192 ** -0.5)[..., :dv]
+    clen = ctx - off
+    assert got.shape == (hq, chunk, dv)
+    assert float(jnp.max(jnp.abs(got[:, :clen] - want[:, :clen]))) < 5e-6
+
+
+def test_latent_kernels_refuse_mixed_pools():
+    q = jnp.zeros((2, 4, 128))
+    kp = jnp.zeros((1, 4, 8, 128))
+    tbl, ctx = jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32)
+    with pytest.raises(ValueError, match="latent"):
+        ops.paged_decode_attention(q, kp, kp, tbl, ctx, latent_v=64,
+                                   interpret=True)
+    with pytest.raises(ValueError, match="latent"):
+        ops.paged_decode_attention(q, kp, None, tbl, ctx, interpret=True)
+
+
+# --------------------------------------------- grouped expert SwiGLU ------
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("sizes", [[5, 0, 17, 1], [0, 0, 0, 0],
+                                   [40, 0, 0, 0]],
+                         ids=["ragged", "empty", "one-expert"])
+@pytest.mark.parametrize("f", [48, 384], ids=["whole", "3-blocks"])
+def test_moe_expert_ffn(dtype, sizes, f, monkeypatch):
+    """Rows sorted by expert and padded to whole tiles: each row gets its
+    own expert's SwiGLU, tiles past the live ones are zero, and nothing
+    depends on the padding rows; an expert wider than the VMEM allowed
+    for its weights is summed over blocks of the width."""
+    import numpy as np
+    from repro.kernels import moe
+    from repro.kernels.moe import moe_expert_ffn_ref
+    tile, d, e = 16, 32, len(sizes)
+    # room for the weights of 128 of the 384 lanes, double-buffered
+    monkeypatch.setattr(moe, "WEIGHT_VMEM_BYTES", 2 * 3 * d * 128 * 4)
+    assert moe.block_f(d, f, 4) == min(f, 128)
+    tiles = [-(-n // tile) for n in sizes]
+    n_live = sum(tiles)
+    n_tiles = n_live + 3                           # dead tiles after
+    ks = jax.random.split(jax.random.fold_in(KEY, 59), 4)
+    x = jax.random.normal(ks[0], (n_tiles * tile, d), jnp.float32)
+    wi = jax.random.normal(ks[1], (e, d, f)) * d ** -0.5
+    wg = jax.random.normal(ks[2], (e, d, f)) * d ** -0.5
+    wo = jax.random.normal(ks[3], (e, f, d)) * f ** -0.5
+    x, wi, wg, wo = (a.astype(dtype) for a in (x, wi, wg, wo))
+    te = np.concatenate([np.full(t, i) for i, t in enumerate(tiles)]
+                        + [np.full(3, e - 1)]).astype(np.int32)
+    n = jnp.array([n_live], jnp.int32)
+    # the weights as layer stacks: two layers, the second one runs
+    lay = jnp.array([1], jnp.int32)
+    wi, wg, wo = (jnp.stack([jnp.flip(w, 0), w]) for w in (wi, wg, wo))
+    got = ops.moe_expert_ffn(x, jnp.asarray(te), n, lay, wi, wg, wo,
+                             tile=tile, interpret=True)
+    want = moe_expert_ffn_ref(x, jnp.asarray(te), n, lay, wi, wg, wo,
+                              tile=tile)
+    tol = 1e-5 if dtype == jnp.float32 else 3e-2
+    assert float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                 - want.astype(jnp.float32)))) < tol
+    assert float(jnp.abs(got[n_live * tile:].astype(jnp.float32)).max()) == 0
+    if dtype == jnp.float32:                       # one row by hand
+        i = 0 if sizes[0] else None
+        if i is not None:
+            h = jax.nn.silu(x[i] @ wg[1, 0]) * (x[i] @ wi[1, 0])
+            assert float(jnp.abs(got[i] - h @ wo[1, 0]).max()) < 1e-5
+
+
+def test_moe_expert_ffn_block_width():
+    """The whole expert where its weights fit; else the widest multiple
+    of 128 lanes dividing its width; else a clear refusal."""
+    from repro.kernels.moe import block_f
+    assert block_f(2048, 1408, 2) == 1408          # Moonlight
+    assert block_f(2048, 768, 2) == 768            # Qwen3-MoE
+    assert block_f(6144, 10752, 2) == 512          # DBRX
+    with pytest.raises(ValueError, match="VMEM"):
+        block_f(2 ** 20, 10752, 2)

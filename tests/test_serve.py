@@ -483,9 +483,10 @@ def test_chunked_lifts_max_context_submit_limit(dense_setup):
 
 
 def test_moe_family_through_scheduler():
-    """MoE configs serve through the chunked continuous scheduler (smoke
-    + determinism only: capacity routing is cross-token, so chunked-vs-
-    monolithic equivalence is pinned to the dense family)."""
+    """MoE configs serve through the chunked continuous scheduler over
+    the dropless held-expert layer (smoke + determinism; monolithic
+    prefill, which runs the training forward's capacity routing, refuses
+    them)."""
     from repro.models import lm
     cfg = _smoke_cfg("qwen3_moe_30b_a3b")
     params = lm.init(KEY, cfg)
@@ -497,6 +498,8 @@ def test_moe_family_through_scheduler():
     b = serve_continuous(cfg, **kw)
     assert a["requests"] == 3 and a["total_new_tokens"] > 0
     assert a["sequences"] == b["sequences"]
+    with pytest.raises(NotImplementedError, match="chunked"):
+        serve_continuous(cfg, **dict(kw, prefill="monolithic"))
 
 
 # ----------------------------------------- pod prefix-cache sharing -------

@@ -1,5 +1,8 @@
 """The main-path Pallas kernels compile for a TPU v5e at full flad-adllm
-widths (Hq=16, Hkv=8, head_dim 64, d_model 1024, bf16).
+widths (Hq=16, Hkv=8, head_dim 64, d_model 1024, bf16), and the latent
+(MLA) and held-expert kernels at moonlight-serve-plans' (64 lanes, 16
+heads over a 512 + 64 latent row in 640 lanes, 16 held experts of
+2048 x 1408).
 
 Nothing runs: each test lowers a kernel for a described (not attached)
 v5e chip and compiles it with the TPU compiler, which refuses what
@@ -135,3 +138,57 @@ def test_lora_matmul_forward_and_dx_compile(sds):
     assert _mosaic_calls(fwd_dx, sds((m, DMODEL), BF16),
                          sds((DMODEL, n), BF16), sds((DMODEL, r), BF16),
                          sds((r, n), BF16), sds((m, n), BF16)) == 2
+
+
+# moonlight-serve-plans: 64 lanes, 112-slot tables (1,792 tokens) over a
+# 1 GiB latent pool of 13 layers (4,032 blocks of 16 rows of 640 lanes)
+LAT_SLOTS, LAT_T, LAT_NB, LAT_D, LAT_V = 64, 112, 4032, 640, 512
+
+
+def test_latent_paged_decode_compiles(sds):
+    def decode(q, pool, tbl, ctx):
+        return ops.paged_decode_attention(q, pool, None, tbl, ctx,
+                                          scale=192 ** -0.5, latent_v=LAT_V,
+                                          interpret=False)
+
+    assert _mosaic_calls(decode, sds((LAT_SLOTS, HQ, LAT_D), BF16),
+                         sds((1, LAT_NB, BS, LAT_D), BF16),
+                         sds((LAT_SLOTS, LAT_T), jnp.int32),
+                         sds((LAT_SLOTS,), jnp.int32)) == 1
+
+
+def test_latent_paged_prefill_compiles(sds):
+    def prefill(q, pool, tbl, off, ctx):
+        return ops.paged_prefill_attention(q, pool, None, tbl, off, ctx,
+                                           scale=192 ** -0.5,
+                                           latent_v=LAT_V, interpret=False)
+
+    scalar = sds((), jnp.int32)
+    assert _mosaic_calls(prefill, sds((HQ, 32, LAT_D), BF16),
+                         sds((1, LAT_NB, BS, LAT_D), BF16),
+                         sds((LAT_T,), jnp.int32), scalar, scalar) == 1
+
+
+@pytest.mark.parametrize("tokens,layers,held,top_k,d,f", [
+    (64, 12, 16, 6, 2048, 1408), (32, 12, 16, 6, 2048, 1408),
+    (64, 48, 32, 8, 2048, 768), (64, 2, 4, 4, 6144, 10752)],
+    ids=["decode", "prefill", "qwen3-moe", "dbrx"])
+def test_moe_expert_ffn_compiles(sds, tokens, layers, held, top_k, d, f):
+    """The held experts' grouped SwiGLU: at the cell's widths (16 experts
+    of 2048 x 1408 in the stacks of 12 expert layers), and one chip's
+    share of Qwen3-MoE's (32 of 128 experts of 2048 x 768) and of DBRX's
+    (4 of 16 experts of 6144 x 10752 in two layers, which fit the HBM;
+    their weights are taken in blocks
+    of the width); rows for ``tokens`` tokens x ``top_k`` choices padded
+    to 16-row tiles (the bound ``blocks.held_moe`` sizes)."""
+    def ffn(x, te, n, lay, wi, wg, wo):
+        return ops.moe_expert_ffn(x, te, n, lay, wi, wg, wo, tile=16,
+                                  interpret=False)
+
+    rows = -(-(tokens * min(top_k, held) + held * 15) // 16) * 16
+    assert _mosaic_calls(ffn, sds((rows, d), BF16),
+                         sds((rows // 16,), jnp.int32), sds((1,), jnp.int32),
+                         sds((1,), jnp.int32),
+                         sds((layers, held, d, f), BF16),
+                         sds((layers, held, d, f), BF16),
+                         sds((layers, held, f, d), BF16)) == 1
