@@ -190,6 +190,9 @@ def serve_phase(cfg, *, slots: int = 8, requests: int = 8,
     ctx[0] = plen
     dec_args = (params, pools, jnp.asarray(tokens), jnp.asarray(tables),
                 jnp.asarray(ctx))
+    # the decode donates the pools: keep the shapes to lower it again
+    dec_shapes = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), dec_args)
     decoded, _ = engine.decode(*dec_args)
 
     cfg32 = cfg.replace(param_dtype="float32")
@@ -205,7 +208,7 @@ def serve_phase(cfg, *, slots: int = 8, requests: int = 8,
               f"(tol {LOGIT_TOL})")
         check(err <= LOGIT_TOL, f"{name} logits within tolerance")
 
-    hlo = jax.jit(engine.decode).lower(*dec_args).compile().as_text()
+    hlo = engine._decode.lower(*dec_shapes).compile().as_text()
     n = hlo.count('custom_call_target="tpu_custom_call"')
     print(f"[serve] compiled decode step: {n} tpu_custom_call op(s)")
     check(n >= 1, "the decode step runs a Mosaic kernel")

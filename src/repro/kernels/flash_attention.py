@@ -35,15 +35,20 @@ positions (``kp < kv_len``); padded q rows carry zero cotangents, so they
 contribute nothing to dK/dV and their dQ rows are sliced off.
 
 The serving kernels read K/V through a block table over physical page
-pools. Chunked prefill walks the table as its innermost sequential grid
-dimension, like the KV blocks above. Decode instead gives each request
-one grid step and walks only its live pages in a ``fori_loop``, copying
-them by hand into double-buffered VMEM, so its cost follows the live
-context and not the table's width.
+pools, stored page-major as layer stacks [L, NB, bs, Hkv * D] (a token's
+row holds every KV head) and passed whole with the layer
+scalar-prefetched, so no caller slices or relayouts a pool for them.
+Both score every query head against a page's rows in one matmul from a
+block-diagonal query. Chunked prefill walks the table as its sequential
+grid dimension, like the KV blocks above, one page of all heads a step.
+Decode instead gives each request one grid step and walks only its live
+pages in a ``fori_loop``, copying them by hand into double-buffered
+VMEM, so its cost follows the live context and not the table's width.
 """
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -224,15 +229,17 @@ def _token_scales(sc, bs: int, hkv: int, d: int):
                        preferred_element_type=jnp.float32)
 
 
-def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, *rest,
+def _paged_decode_kernel(tbl_ref, ctx_ref, layer_ref, q_ref, k_hbm, *rest,
                          scale: float, bs: int, pages: int, t: int,
                          hkv: int, quantized: bool, latent_v: int):
     """One decode token per request against a paged KV pool.
 
     Grid (batch,): one step per lane, all heads at once. The pools stay
-    in HBM (``pl.ANY``) as [NB, bs, Hkv * D], a page of every KV head per
-    row block, and a ``fori_loop`` walks the lane's ``cdiv(ctx, P * bs)``
-    compute blocks. Each block's pages are copied from the
+    in HBM (``pl.ANY``) as the engine stores them, the layer stacks [L,
+    NB, bs, Hkv * D] with a page of every KV head per row block; the
+    layer is scalar-prefetched (``layer_ref``), so the caller hands the
+    stacks over whole. A ``fori_loop`` walks the lane's ``cdiv(ctx, P *
+    bs)`` compute blocks. Each block's pages are copied from the
     scalar-prefetched block table (``tbl_ref[b * t + i]``), one DMA per
     page, into a double-buffered VMEM tile: block ``j + 1``'s copies start
     before block ``j`` is computed. Only live pages are copied: a ragged
@@ -265,6 +272,7 @@ def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, *rest,
         v_hbm, o_ref, k_buf, v_buf, sem = rest
         pairs = ((k_hbm, k_buf), (v_hbm, v_buf))
     b = pl.program_id(0)
+    layer = layer_ref[0]
     ctx = ctx_ref[b]
     n_pages = pl.cdiv(ctx, bs)
     n_blocks = pl.cdiv(ctx, pages * bs)
@@ -279,8 +287,8 @@ def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, *rest,
             def _page():
                 page = tbl_ref[b * t + j * pages + p]
                 for hbm, buf in pairs:
-                    op(pltpu.make_async_copy(hbm.at[page], buf.at[slot, p],
-                                             sem.at[slot]))
+                    op(pltpu.make_async_copy(hbm.at[layer, page],
+                                             buf.at[slot, p], sem.at[slot]))
 
     @pl.when(n_blocks > 0)
     def _first():
@@ -327,71 +335,94 @@ def _paged_decode_kernel(tbl_ref, ctx_ref, q_ref, k_hbm, *rest,
     if latent_v:
         o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
         return
-    row = jax.lax.broadcasted_iota(jnp.int32, (hq, w), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hq, w), 1)
-    acc = jnp.where(row // g == lane // d, acc, 0.0)   # own head's lanes
+    o_ref[0] = (_fold_heads(acc, g, d) / jnp.maximum(l, 1e-30)).astype(
+        o_ref.dtype)
+
+
+def _fold_heads(acc, rows_per_kv: int, d: int):
+    """[rows, Hkv * D] accumulator of a block-diagonal query -> [rows, D]:
+    row ``r`` keeps the lanes of its KV head ``r // rows_per_kv``, summed
+    down to D lanes by an exact 0/1 matmul."""
+    rows, w = acc.shape
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, w), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, w), 1)
+    acc = jnp.where(row // rows_per_kv == lane // d, acc, 0.0)
     fold = (jax.lax.broadcasted_iota(jnp.int32, (w, d), 0) % d
             == jax.lax.broadcasted_iota(jnp.int32, (w, d), 1))
-    o = jax.lax.dot(acc, fold.astype(jnp.float32),
-                    precision=jax.lax.Precision.HIGHEST,
-                    preferred_element_type=jnp.float32)
-    o_ref[0] = (o / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    return jax.lax.dot(acc, fold.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST,
+                       preferred_element_type=jnp.float32)
+
+
+def _pool_geometry(q, k_pages, v_pages, k_scales, latent_v):
+    """(Hkv, D) of page-major pools [L, NB, bs, Hkv * D] read by queries
+    of D lanes, after checking the latent / K-V combination."""
+    d, w = q.shape[-1], k_pages.shape[-1]
+    if w % d:
+        raise ValueError(f"pool rows of {w} lanes do not hold whole heads "
+                         f"of {d}")
+    hkv = w // d
+    if (v_pages is None) != bool(latent_v) or (latent_v and (
+            hkv != 1 or k_scales is not None)):
+        raise ValueError("latent attention is one bf16 pool, one KV head, "
+                         "and no V pool; a V pool needs latent_v=0")
+    return hkv, d
+
+
+def _block_diagonal(q, hkv: int, axis: int):
+    """Queries [..., D] whose axis ``axis`` is the query head -> [...,
+    Hkv * D], head ``h`` in the lanes of its KV head ``h // G`` and zeros
+    elsewhere (one KV head: the queries as they are)."""
+    if hkv == 1:
+        return q
+    hq, d = q.shape[axis], q.shape[-1]
+    shape = [1] * (q.ndim - 1) + [hkv, 1]
+    shape[axis] = hq
+    own = (jnp.arange(hq)[:, None] // (hq // hkv)
+           == jnp.arange(hkv)[None, :]).reshape(shape)
+    return jnp.where(own, q[..., None, :], jnp.zeros((), q.dtype)).reshape(
+        q.shape[:-1] + (hkv * d,))
 
 
 def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
-                           scale: Optional[float] = None,
+                           layer, scale: Optional[float] = None,
                            k_scales=None, v_scales=None,
                            latent_v: int = 0, interpret: bool = False):
     """Single-token decode attention over a paged KV cache.
 
-    q: [B, Hq, D] (one query token per request); k_pages/v_pages:
-    [Hkv, NB, bs, D] physical block pools; block_tables: [B, T] int32
+    q: [B, Hq, D] (one query token per request); k_pages/v_pages: the
+    page-major layer stacks [L, NB, bs, Hkv * D] as the engine stores
+    them (a token's row holds every KV head), read at ``layer`` (an int32
+    scalar, scalar-prefetched, so the stacks are passed whole and nothing
+    slices a layer out of them); block_tables: [B, T] int32
     logical->physical maps (slots at or past a request's context are
     never read); ctx_lens: [B] int32 visible KV length per request
     (requests with ``ctx_lens == 0`` return zeros). With
-    ``k_scales``/``v_scales`` ([Hkv, NB, bs, 1] float32) the pools are
-    int8 and dequantized in-kernel. Returns [B, Hq, D].
-
-    The kernel reads the pools as [NB, bs, Hkv * D]: Mosaic slices a
-    memory reference only where its lanes stay whole, which a D of 64
-    (padded to 128 lanes) is not, while a page of all heads is.
+    ``k_scales``/``v_scales`` ([L, NB, 1, bs * Hkv] float32, token ``t``
+    of head ``h`` at lane ``t * Hkv + h``) the pools are int8 and
+    dequantized in-kernel. Returns [B, Hq, D].
 
     Latent attention (MLA) passes ``v_pages=None`` and ``latent_v``: the
-    one pool ([1, NB, bs, D], D the latent and its rotary key) holds the
+    one pool ([L, NB, bs, D], D the latent and its rotary key) holds the
     keys, and its first ``latent_v`` lanes are the values. Returns [B,
     Hq, latent_v] then.
     """
-    b, hq, d = q.shape
-    hkv, nb, bs, _ = k_pages.shape
-    g = hq // hkv
+    b, hq, _ = q.shape
+    hkv, d = _pool_geometry(q, k_pages, v_pages, k_scales, latent_v)
+    bs = k_pages.shape[2]
     t = block_tables.shape[1]
     pages = max(1, min(t, DECODE_BLOCK_TOKENS // bs))
     scale = scale if scale is not None else d ** -0.5
     quantized = k_scales is not None
-    if (v_pages is None) != bool(latent_v) or (latent_v and (
-            hkv != 1 or quantized)):
-        raise ValueError("latent attention is one bf16 pool, one KV head, "
-                         "and no V pool; a V pool needs latent_v=0")
 
-    def rows(pool):                                # -> [NB, bs, Hkv * D]
-        # merging (NB, bs) first leaves XLA one relayout, where
-        # transposing the 4-D pool first costs it two
-        return pool.reshape(hkv, nb * bs, -1).transpose(1, 0, 2).reshape(
-            nb, bs, -1)
-
-    if latent_v:
-        operands = [q, k_pages.reshape(nb, bs, d)]
-        scratch = [pltpu.VMEM((2, pages, bs, d), k_pages.dtype)]
-    else:
-        own = (jnp.arange(hq)[:, None] // g == jnp.arange(hkv)[None, :])
-        qbd = jnp.where(own[None, :, :, None], q[:, :, None, :],
-                        jnp.zeros((), q.dtype)).reshape(b, hq, hkv * d)
-        operands = [qbd, rows(k_pages), rows(v_pages)]
-        scratch = [pltpu.VMEM((2, pages, bs, hkv * d), k_pages.dtype),
-                   pltpu.VMEM((2, pages, bs, hkv * d), v_pages.dtype)]
-    if quantized:                                  # -> [NB, 1, bs * Hkv]
-        operands += [rows(s).reshape(nb, 1, bs * hkv)
-                     for s in (k_scales, v_scales)]
+    qbd = _block_diagonal(q, hkv, axis=1)
+    operands = [qbd, k_pages]
+    scratch = [pltpu.VMEM((2, pages, bs, hkv * d), k_pages.dtype)]
+    if not latent_v:
+        operands.append(v_pages)
+        scratch.append(pltpu.VMEM((2, pages, bs, hkv * d), v_pages.dtype))
+    if quantized:
+        operands += [k_scales, v_scales]
         scratch += [pltpu.VMEM((2, pages, 1, bs * hkv), jnp.float32)] * 2
 
     dv = latent_v or d
@@ -399,13 +430,13 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
                                pages=pages, t=t, hkv=hkv,
                                quantized=quantized, latent_v=latent_v)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, hq, hkv * d),
-                               lambda b_, tbl, ctx: (b_, 0, 0))]
+                               lambda b_, tbl, ctx, lay: (b_, 0, 0))]
         + [pl.BlockSpec(memory_space=pl.ANY)] * (len(operands) - 1),
         out_specs=pl.BlockSpec((1, hq, dv),
-                               lambda b_, tbl, ctx: (b_, 0, 0)),
+                               lambda b_, tbl, ctx, lay: (b_, 0, 0)),
         scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))],
     )
     return pl.pallas_call(
@@ -417,31 +448,52 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
         interpret=interpret,
         name="paged_decode_attention",
     )(block_tables.astype(jnp.int32).reshape(-1),
-      ctx_lens.astype(jnp.int32), *operands)
+      ctx_lens.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), *operands)
 
 
 # --------------------------------------------------------- paged prefill ---
+def _lane_group(hkv: int, d: int) -> int:
+    """KV heads the prefill kernel serves together from one lane slice of
+    a page: the fewest whose lanes fill whole 128-lane tiles (2 heads of
+    64, 1 of 128 or of 640), or all of them where that does not divide
+    ``hkv``. Mosaic slices a page's lanes only at whole tiles, and a
+    group's query is block-diagonal within the group alone, so the
+    kernel's work and VMEM follow Hq * C * D, not Hq * C * Hkv * D."""
+    hp = 128 // math.gcd(d, 128)
+    return hp if hkv % hp == 0 else hkv
+
+
 def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, *rest,
-                          scale: float, bs: int, chunk: int,
-                          quantized: bool, latent_v: int):
+                          scale: float, bs: int, chunk: int, hkv: int,
+                          hp: int, quantized: bool, latent_v: int):
     """One prompt chunk of a single request against a paged KV pool.
 
-    Grid (kv-head, table-slot); the innermost dimension walks the
-    request's block table sequentially while (m, l, acc) persist in VMEM
-    scratch — ``_fwd_kernel``'s KV walk through a block table.
-    The query chunk is laid out [Hkv, G*C, D] (GQA group-major), so row
-    ``r`` is chunk offset ``r % C`` at absolute position ``q_offset +
-    r % C``; the causal mask is applied from those absolute positions
-    against the block's absolute KV positions. The chunk's OWN K/V rows
-    have already been scattered into their pool blocks before this kernel
-    runs, so "prior context plus itself" is one uniform table walk —
-    there is no contiguous [Smax] staging buffer anywhere. Blocks at or
-    past ``ctx = q_offset + chunk_len`` are dead (their table entries
-    point at the reserved null block) and skip compute entirely; rows of
-    the chunk past ``chunk_len`` (last-chunk padding) are garbage by
-    contract and masked down to a nonempty-but-meaningless context so
-    they stay finite. With ``latent_v`` (MLA) there is no V pool: the
-    values are the first ``latent_v`` lanes of the key rows.
+    Grid (table-slot,): the one dimension walks the request's block table
+    sequentially while (m, l, acc) persist in VMEM scratch —
+    ``_fwd_kernel``'s KV walk through a block table. Each step reads one
+    page of the layer stacks [L, NB, bs, Hkv * D] (the layer comes with
+    the scalar-prefetched ``meta``), a row of every KV head per token,
+    and serves every query head from it, one lane group of ``hp`` KV
+    heads (:func:`_lane_group`) at a time. The query chunk arrives as
+    [Hkv / hp, hp * G * C, hp * D]: in group ``j``, row ``r`` is query
+    head ``j * hp * G + r // C`` at chunk offset ``r % C`` (absolute
+    position ``q_offset + r % C``), block-diagonal within the group (in
+    the lanes of its KV head), so one matmul scores the group's heads
+    against the group's lanes of the page. With ``hp > 1`` the
+    accumulator's diagonal blocks, folded as in decode, are the outputs.
+    The causal mask is applied from absolute positions against the
+    page's absolute KV positions. The chunk's OWN K/V rows have already
+    been written into their pool blocks before this kernel runs, so
+    "prior context plus itself" is one uniform table walk — there is no
+    contiguous [Smax] staging buffer anywhere. Blocks at or past ``ctx =
+    q_offset + chunk_len`` are dead (their table entries point at the
+    reserved null block) and skip compute entirely; rows of the chunk
+    past ``chunk_len`` (last-chunk padding) are garbage by contract and
+    masked down to a nonempty-but-meaningless context so they stay
+    finite. With ``latent_v`` (MLA) there is no V pool: the values are
+    the first ``latent_v`` lanes of the key rows, and one KV head leaves
+    nothing to fold.
     """
     if latent_v:
         o_ref, m_ref, l_ref, acc_ref = rest
@@ -449,7 +501,7 @@ def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, *rest,
         v_ref, ks_ref, vs_ref, o_ref, m_ref, l_ref, acc_ref = rest
     else:
         v_ref, o_ref, m_ref, l_ref, acc_ref = rest
-    i = pl.program_id(1)
+    i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
@@ -459,122 +511,128 @@ def _paged_prefill_kernel(tbl_ref, meta_ref, q_ref, k_ref, *rest,
 
     q_offset = meta_ref[0]
     ctx = meta_ref[1]
+    groups, rows, gw = q_ref.shape
+    d = gw // hp
 
     @pl.when(i * bs < ctx)
     def _compute():
-        q = q_ref[0].astype(jnp.float32)           # [gc, d]
-        k = k_ref[0, 0].astype(jnp.float32)        # [bs, d]
+        k = k_ref[0, 0].astype(jnp.float32)        # [bs, hkv * d]
         if latent_v:
             v = k[:, :latent_v]
         else:
-            v = v_ref[0, 0].astype(jnp.float32)    # [bs, d]
-        if quantized:
-            k = k * ks_ref[0, 0]                   # per-row absmax scales
-            v = v * vs_ref[0, 0]
-        gc = q.shape[0]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        r = jax.lax.broadcasted_iota(jnp.int32, (gc, bs), 0)
+            v = v_ref[0, 0].astype(jnp.float32)    # [bs, hkv * d]
+        if quantized:                              # per-row absmax scales
+            k = k * _token_scales(ks_ref[0], bs, hkv, d)
+            v = v * _token_scales(vs_ref[0], bs, hkv, d)
+        r = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0)
         qp = q_offset + r % chunk                  # absolute q position
-        kp = i * bs + jax.lax.broadcasted_iota(jnp.int32, (gc, bs), 1)
-        s = jnp.where((kp <= qp) & (kp < ctx), s, NEG_INF)
+        kp = i * bs + jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+        live = (kp <= qp) & (kp < ctx)
+        for j in range(groups):
+            lanes = slice(j * gw, (j + 1) * gw)
+            q = q_ref[j].astype(jnp.float32)       # [rows, gw]
+            s = jax.lax.dot_general(q, k[:, lanes], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(live, s * scale, NEG_INF)
+            m_prev = m_ref[j]                      # [rows, 1]
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            corr = jnp.exp(m_prev - m_new)
+            l_ref[j] = l_ref[j] * corr + p.sum(axis=1, keepdims=True)
+            acc_ref[j] = acc_ref[j] * corr + jax.lax.dot(
+                p, v if latent_v else v[:, lanes],
+                preferred_element_type=jnp.float32)
+            m_ref[j] = m_new
 
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * corr[:, None] + jax.lax.dot(
-            p, v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(i == pl.num_programs(1) - 1)
+    @pl.when(i == pl.num_programs(0) - 1)
     def _done():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        for j in range(groups):
+            acc = acc_ref[j]
+            if hp > 1:
+                acc = _fold_heads(acc, rows // hp, d)
+            o_ref[j] = (acc / jnp.maximum(l_ref[j], 1e-30)).astype(
+                o_ref.dtype)
 
 
 def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
-                            ctx_len, *, scale: Optional[float] = None,
+                            ctx_len, *, layer,
+                            scale: Optional[float] = None,
                             k_scales=None, v_scales=None, latent_v: int = 0,
                             interpret: bool = False):
     """Chunked-prefill attention for one request over a paged KV cache.
 
     q: [Hq, C, D] (a fixed-size query chunk whose row ``c`` sits at
-    absolute position ``q_offset + c``); k_pages/v_pages: [Hkv, NB, bs,
-    D] physical block pools ALREADY holding the chunk's own K/V rows;
-    block_table: [T] int32 logical->physical map (dead slots point at the
-    reserved null block 0); q_offset/ctx_len: traced int32 scalars —
-    ``ctx_len = q_offset + chunk_len`` is the visible KV length, so the
-    same jitted call serves every chunk of every prompt length. With
-    ``k_scales``/``v_scales`` ([Hkv, NB, bs, 1] float32) the pools are
-    int8 and dequantized in-kernel. Rows past ``chunk_len`` are padding
-    and return garbage (finite) values. Returns [Hq, C, D].
+    absolute position ``q_offset + c``); k_pages/v_pages: the page-major
+    layer stacks [L, NB, bs, Hkv * D] ALREADY holding the chunk's own K/V
+    rows, read at ``layer`` (int32 scalar, scalar-prefetched; the stacks
+    are passed whole); block_table: [T] int32 logical->physical map (dead
+    slots point at the reserved null block 0); q_offset/ctx_len: traced
+    int32 scalars — ``ctx_len = q_offset + chunk_len`` is the visible KV
+    length, so the same jitted call serves every chunk of every prompt
+    length. With ``k_scales``/``v_scales`` ([L, NB, 1, bs * Hkv] float32)
+    the pools are int8 and dequantized in-kernel. Rows past
+    ``chunk_len`` are padding and return garbage (finite) values.
+    Returns [Hq, C, D].
 
     Latent attention (MLA) passes ``v_pages=None`` and ``latent_v``: one
-    pool [1, NB, bs, D] of key rows whose first ``latent_v`` lanes are the
+    pool [L, NB, bs, D] of key rows whose first ``latent_v`` lanes are the
     values; each page is read once. Returns [Hq, C, latent_v] then.
     """
-    hq, c, d = q.shape
-    hkv, _, bs, _ = k_pages.shape
-    g = hq // hkv
+    hq, c, _ = q.shape
+    hkv, d = _pool_geometry(q, k_pages, v_pages, k_scales, latent_v)
+    bs = k_pages.shape[2]
     t = block_table.shape[0]
     scale = scale if scale is not None else d ** -0.5
     quantized = k_scales is not None
-    if (v_pages is None) != bool(latent_v) or (latent_v and (
-            hkv != 1 or quantized)):
-        raise ValueError("latent attention is one bf16 pool, one KV head, "
-                         "and no V pool; a V pool needs latent_v=0")
     dv = latent_v or d
-    # group-major rows: [Hq, C, D] -> [Hkv, G, C, D] -> [Hkv, G*C, D]
-    qg = q.reshape(hkv, g, c, d).reshape(hkv, g * c, d)
+    hp = _lane_group(hkv, d)
+    groups, rows, gw = hkv // hp, hq // hkv * hp * c, hp * d
+    # [Hq, C, D] -> [groups, hp * G, C, hp * D] -> [groups, rows, gw]
+    qg = _block_diagonal(q.reshape(groups, hq // groups, c, d), hp,
+                         axis=1).reshape(groups, rows, gw)
     meta = jnp.stack([jnp.asarray(q_offset, jnp.int32),
-                      jnp.asarray(ctx_len, jnp.int32)])
+                      jnp.asarray(ctx_len, jnp.int32),
+                      jnp.asarray(layer, jnp.int32)])
 
     kernel = functools.partial(_paged_prefill_kernel, scale=scale, bs=bs,
-                               chunk=c, quantized=quantized,
+                               chunk=c, hkv=hkv, hp=hp, quantized=quantized,
                                latent_v=latent_v)
-    page = pl.BlockSpec((1, 1, bs, d),
-                        lambda h, i, tbl, meta_: (h, tbl[i], 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, g * c, d), lambda h, i, tbl, meta_: (h, 0, 0)),
-        page,
-    ]
+    page = pl.BlockSpec((1, 1, bs, hkv * d),
+                        lambda i, tbl, meta_: (meta_[2], tbl[i], 0, 0))
+    in_specs = [pl.BlockSpec((groups, rows, gw),
+                             lambda i, tbl, meta_: (0, 0, 0)), page]
     operands = [qg, k_pages]
     if not latent_v:
         in_specs.append(page)
         operands.append(v_pages)
     if quantized:
-        in_specs += [
-            pl.BlockSpec((1, 1, bs, 1),
-                         lambda h, i, tbl, meta_: (h, tbl[i], 0, 0)),
-            pl.BlockSpec((1, 1, bs, 1),
-                         lambda h, i, tbl, meta_: (h, tbl[i], 0, 0)),
-        ]
+        in_specs += [pl.BlockSpec(
+            (1, 1, 1, bs * hkv),
+            lambda i, tbl, meta_: (meta_[2], tbl[i], 0, 0))] * 2
         operands += [k_scales, v_scales]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(hkv, t),
+        grid=(t,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, g * c, dv),
-                               lambda h, i, tbl, meta_: (h, 0, 0)),
+        out_specs=pl.BlockSpec((groups, rows, dv),
+                               lambda i, tbl, meta_: (0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g * c,), jnp.float32),
-            pltpu.VMEM((g * c,), jnp.float32),
-            pltpu.VMEM((g * c, dv), jnp.float32),
+            pltpu.VMEM((groups, rows, 1), jnp.float32),
+            pltpu.VMEM((groups, rows, 1), jnp.float32),
+            pltpu.VMEM((groups, rows, latent_v or gw), jnp.float32),
         ],
     )
     o = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hkv, g * c, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((groups, rows, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="paged_prefill_attention",
     )(block_table.astype(jnp.int32), meta, *operands)
-    return o.reshape(hkv, g, c, dv).reshape(hq, c, dv)
+    return o.reshape(hq, c, dv)
 
 
 # ------------------------------------------------------------ backward ----

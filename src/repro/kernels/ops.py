@@ -105,16 +105,18 @@ def flash_attention_ad(q, k, v, scale=None, causal=True, window=None,
 @functools.partial(jax.jit, static_argnames=("scale", "latent_v",
                                              "interpret"))
 def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
-                           scale=None, k_scales=None, v_scales=None,
+                           layer, scale=None, k_scales=None, v_scales=None,
                            latent_v=0, interpret=None):
-    """q: [B, Hq, D] decode queries; k_pages/v_pages: [Hkv, NB, bs, D]
-    block pools; block_tables: [B, T] logical->physical maps; ctx_lens:
-    [B] visible KV lengths. Pass ``k_scales``/``v_scales`` for int8
-    pools (dequantized in-kernel). Returns [B, Hq, D]. Latent attention
-    passes ``v_pages=None`` and ``latent_v``: values are the first
-    ``latent_v`` lanes of the one pool's rows (returns [B, Hq, latent_v])."""
+    """q: [B, Hq, D] decode queries; k_pages/v_pages: page-major layer
+    stacks [L, NB, bs, Hkv * D], read at ``layer`` (int32 scalar);
+    block_tables: [B, T] logical->physical maps; ctx_lens: [B] visible
+    KV lengths. Pass ``k_scales``/``v_scales`` ([L, NB, 1, bs * Hkv])
+    for int8 pools (dequantized in-kernel). Returns [B, Hq, D]. Latent
+    attention passes ``v_pages=None`` and ``latent_v``: values are the
+    first ``latent_v`` lanes of the one pool's rows (returns [B, Hq,
+    latent_v])."""
     return _fa.paged_decode_attention(q, k_pages, v_pages, block_tables,
-                                      ctx_lens, scale=scale,
+                                      ctx_lens, layer=layer, scale=scale,
                                       k_scales=k_scales, v_scales=v_scales,
                                       latent_v=latent_v,
                                       interpret=_auto_interpret(interpret))
@@ -127,19 +129,20 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, ctx_lens, *,
 @functools.partial(jax.jit, static_argnames=("scale", "latent_v",
                                              "interpret"))
 def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
-                            ctx_len, *, scale=None, k_scales=None,
+                            ctx_len, *, layer, scale=None, k_scales=None,
                             v_scales=None, latent_v=0, interpret=None):
     """q: [Hq, C, D] query chunk (row c at position q_offset + c);
-    k_pages/v_pages: [Hkv, NB, bs, D] block pools already holding the
-    chunk's own K/V rows; block_table: [T] logical->physical map;
-    q_offset/ctx_len: int32 scalars (ctx_len = q_offset + chunk_len).
-    Pass ``k_scales``/``v_scales`` for int8 pools (dequantized
-    in-kernel). Returns [Hq, C, D]; rows past chunk_len are garbage.
-    Latent attention passes ``v_pages=None`` and ``latent_v`` as in
+    k_pages/v_pages: page-major layer stacks [L, NB, bs, Hkv * D]
+    already holding the chunk's own K/V rows, read at ``layer``;
+    block_table: [T] logical->physical map; q_offset/ctx_len: int32
+    scalars (ctx_len = q_offset + chunk_len). Pass
+    ``k_scales``/``v_scales`` for int8 pools (dequantized in-kernel).
+    Returns [Hq, C, D]; rows past chunk_len are garbage. Latent
+    attention passes ``v_pages=None`` and ``latent_v`` as in
     :func:`paged_decode_attention`."""
     return _fa.paged_prefill_attention(q, k_pages, v_pages, block_table,
-                                       q_offset, ctx_len, scale=scale,
-                                       k_scales=k_scales,
+                                       q_offset, ctx_len, layer=layer,
+                                       scale=scale, k_scales=k_scales,
                                        v_scales=v_scales, latent_v=latent_v,
                                        interpret=_auto_interpret(interpret))
 
@@ -151,19 +154,21 @@ def paged_prefill_attention(q, k_pages, v_pages, block_table, q_offset,
 # constant) and unrolls into independent kernel calls inside one jit.
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_verify_attention(q, k_pages, v_pages, block_tables, ctx_lens,
-                           chunk_lens, *, scale=None, k_scales=None,
+                           chunk_lens, *, layer, scale=None, k_scales=None,
                            v_scales=None, interpret=None):
     """q: [B, Hq, C, D] per-lane draft-window queries (row c of lane b at
-    position ctx_lens[b] + c); k_pages/v_pages: [Hkv, NB, bs, D] pools
-    already holding the window's own K/V rows; block_tables: [B, T];
-    ctx_lens/chunk_lens: [B] int32 (lane b's window covers positions
-    [ctx_lens[b], ctx_lens[b] + chunk_lens[b])). Returns [B, Hq, C, D];
-    rows at or past a lane's chunk_len are garbage."""
+    position ctx_lens[b] + c); k_pages/v_pages: page-major layer stacks
+    [L, NB, bs, Hkv * D] already holding the window's own K/V rows, read
+    at ``layer``; block_tables: [B, T]; ctx_lens/chunk_lens: [B] int32
+    (lane b's window covers positions [ctx_lens[b], ctx_lens[b] +
+    chunk_lens[b])). Returns [B, Hq, C, D]; rows at or past a lane's
+    chunk_len are garbage."""
     outs = [
         _fa.paged_prefill_attention(
             q[b], k_pages, v_pages, block_tables[b], ctx_lens[b],
-            ctx_lens[b] + chunk_lens[b], scale=scale, k_scales=k_scales,
-            v_scales=v_scales, interpret=_auto_interpret(interpret))
+            ctx_lens[b] + chunk_lens[b], layer=layer, scale=scale,
+            k_scales=k_scales, v_scales=v_scales,
+            interpret=_auto_interpret(interpret))
         for b in range(q.shape[0])
     ]
     return jnp.stack(outs)

@@ -41,34 +41,49 @@ def flash_attention_ref(q, k, v, *, scale: Optional[float] = None,
     return o
 
 
+def _logical_kv(k_pages, v_pages, table, layer, d, k_scales, v_scales):
+    """A request's logical K/V view through its block table: page-major
+    stacks [L, NB, bs, Hkv * D] at ``layer`` gathered by table [..., T]
+    -> float32 k, v [..., Hkv, T * bs, D], dequantized when the scales
+    ([L, NB, 1, bs * Hkv], token ``t`` of head ``h`` at lane ``t * Hkv +
+    h``) are given."""
+    _, _, bs, w = k_pages.shape
+    hkv = w // d
+    lead = table.shape[:-1]
+    n = table.shape[-1] * bs
+
+    def view(pages, scales):
+        x = pages[layer][table].astype(jnp.float32)   # [..., T, bs, w]
+        x = x.reshape(lead + (n, hkv, d))
+        if scales is not None:
+            sc = scales[layer][table].reshape(lead + (n, hkv, 1))
+            x = x * sc
+        return jnp.moveaxis(x, -2, -3)               # [..., Hkv, n, D]
+    return view(k_pages, k_scales), view(v_pages, v_scales)
+
+
 def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens,
-                               *, scale: Optional[float] = None,
+                               *, layer, scale: Optional[float] = None,
                                k_scales=None, v_scales=None):
     """Oracle for the paged single-token decode kernel.
 
-    q: [B, Hq, D]; k_pages/v_pages: [Hkv, NB, bs, D]; block_tables:
-    [B, T] int32; ctx_lens: [B] int32. Gathers each request's logical KV
-    view through its block table, dequantizes when scales are given,
+    q: [B, Hq, D]; k_pages/v_pages: page-major layer stacks [L, NB, bs,
+    Hkv * D], read at ``layer``; block_tables: [B, T] int32; ctx_lens:
+    [B] int32. Gathers each request's logical KV view through its block
+    table, dequantizes when scales ([L, NB, 1, bs * Hkv]) are given,
     masks positions >= ctx_len, and runs dense softmax attention.
     Requests with ``ctx_lens == 0`` return zeros (matching the kernel,
     which attends to nothing for them)."""
     b, hq, d = q.shape
-    hkv, _, bs, _ = k_pages.shape
+    k, v = _logical_kv(k_pages, v_pages, block_tables, layer, d, k_scales,
+                       v_scales)                      # [B, Hkv, T*bs, D]
+    hkv, n = k.shape[1], k.shape[2]
     g = hq // hkv
-    t = block_tables.shape[1]
     scale = scale if scale is not None else d ** -0.5
-
-    k = k_pages[:, block_tables].astype(jnp.float32)   # [Hkv, B, T, bs, D]
-    v = v_pages[:, block_tables].astype(jnp.float32)
-    if k_scales is not None:
-        k = k * k_scales[:, block_tables]
-        v = v * v_scales[:, block_tables]
-    k = k.transpose(1, 0, 2, 3, 4).reshape(b, hkv, t * bs, d)
-    v = v.transpose(1, 0, 2, 3, 4).reshape(b, hkv, t * bs, d)
 
     qg = q.reshape(b, hkv, g, d).astype(jnp.float32)
     s = jnp.einsum("bhgd,bhkd->bhgk", qg, k) * scale
-    mask = jnp.arange(t * bs)[None, :] < ctx_lens[:, None]   # [B, T*bs]
+    mask = jnp.arange(n)[None, :] < ctx_lens[:, None]   # [B, T*bs]
     s = jnp.where(mask[:, None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhgk,bhkd->bhgd", p, v)
@@ -77,37 +92,32 @@ def paged_decode_attention_ref(q, k_pages, v_pages, block_tables, ctx_lens,
 
 
 def paged_prefill_attention_ref(q, k_pages, v_pages, block_table, q_offset,
-                                ctx_len, *, scale: Optional[float] = None,
+                                ctx_len, *, layer,
+                                scale: Optional[float] = None,
                                 k_scales=None, v_scales=None):
     """Oracle for the chunked paged-prefill kernel.
 
     q: [Hq, C, D] (row ``c`` at absolute position ``q_offset + c``);
-    k_pages/v_pages: [Hkv, NB, bs, D] pools already holding the chunk's
-    own K/V; block_table: [T] int32. Gathers the request's logical KV
-    view through its table, dequantizes when scales are given, masks
-    causally from absolute positions (``kp <= q_offset + c`` and ``kp <
-    ctx_len``), and runs dense softmax attention. Rows past ``chunk_len
-    = ctx_len - q_offset`` are padding and return garbage values the
-    caller discards — the comparison against the kernel slices them off.
+    k_pages/v_pages: page-major layer stacks [L, NB, bs, Hkv * D] already
+    holding the chunk's own K/V, read at ``layer``; block_table: [T]
+    int32. Gathers the request's logical KV view through its table,
+    dequantizes when scales are given, masks causally from absolute
+    positions (``kp <= q_offset + c`` and ``kp < ctx_len``), and runs
+    dense softmax attention. Rows past ``chunk_len = ctx_len - q_offset``
+    are padding and return garbage values the caller discards — the
+    comparison against the kernel slices them off.
     """
     hq, c, d = q.shape
-    hkv, _, bs, _ = k_pages.shape
+    k, v = _logical_kv(k_pages, v_pages, block_table, layer, d, k_scales,
+                       v_scales)                      # [Hkv, T*bs, D]
+    hkv, n = k.shape[0], k.shape[1]
     g = hq // hkv
-    t = block_table.shape[0]
     scale = scale if scale is not None else d ** -0.5
-
-    k = k_pages[:, block_table].astype(jnp.float32)    # [Hkv, T, bs, D]
-    v = v_pages[:, block_table].astype(jnp.float32)
-    if k_scales is not None:
-        k = k * k_scales[:, block_table]
-        v = v * v_scales[:, block_table]
-    k = k.reshape(hkv, t * bs, d)
-    v = v.reshape(hkv, t * bs, d)
 
     qg = q.reshape(hkv, g, c, d).astype(jnp.float32)
     s = jnp.einsum("hgcd,hkd->hgck", qg, k) * scale
     qp = q_offset + jnp.arange(c)
-    kp = jnp.arange(t * bs)
+    kp = jnp.arange(n)
     mask = (kp[None, :] <= qp[:, None]) & (kp[None, :] < ctx_len)
     s = jnp.where(mask[None, None], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)

@@ -9,14 +9,22 @@ per-request contiguous cache:
     trace), causal masking keeps the padded tail out of every real
     position's attention — and returns the last true token's logits plus
     the layer-stacked K/V to scatter into pool blocks;
-  * **decode** re-implements the block walk as a ``lax.scan`` whose xs
-    carry each layer's pool slices: embed -> rms/qkv/rope (positions =
-    per-request context lengths) -> append the token's K/V into its
-    physical block -> the paged Pallas decode kernel
-    (:func:`repro.kernels.ops.paged_decode_attention`) -> wo/ffn. All
-    ``slots`` batch lanes run the projections every step; dead lanes
-    point at the null block and attend to an empty context, so the
-    kernel copies none of their pages and writes zeros for them.
+  * **decode** re-implements the block walk as a ``lax.scan`` over the
+    layers whose carry holds the whole layer-stacked pools: embed ->
+    rms/qkv/rope (positions = per-request context lengths) -> write the
+    token's K/V row into its physical block at ``(layer, block,
+    offset)`` -> the paged Pallas decode kernel
+    (:func:`repro.kernels.ops.paged_decode_attention`, handed the whole
+    stacks and the layer index) -> wo/ffn. All ``slots`` batch lanes run
+    the projections every step; dead lanes point at the null block and
+    attend to an empty context, so the kernel copies none of their pages
+    and writes zeros for them.
+
+Every program that takes the pools donates them (``donate_argnums``):
+the scan's carry aliases the caller's buffers, XLA writes each layer's
+rows in place, and nothing slices, relayouts or writes back a layer of
+a pool, so no launch copies or allocates one. A caller must rebind the
+pools a call returns; the ones it passed are deleted.
 
 The numerics match the contiguous path op for op (same rope-after-norm
 order, float32 softmax statistics), which is what the paged-vs-contiguous
@@ -29,7 +37,7 @@ head's query is taken into the latent (``attention/latent_absorb``), the
 paged kernels attend over the rows with the latent's lanes as values,
 and the heads' value up-projections follow. Leading dense layers
 (``moe.first_dense_layers``) run as a walk of their own before the walk
-over the expert layers.
+over the expert layers; both carry the one latent stack of every layer.
 
 MoE layers on this path are the dropless held-expert layer
 (:func:`repro.models.blocks.held_moe`: ``mlp/router``, ``mlp/experts``,
@@ -57,20 +65,21 @@ from repro.serve import kvcache as KC
 _PAGED_FAMILIES = ("dense", "moe")
 
 
-def _layer_xs(blocks: dict, pools):
-    """What a scan over the layers walks: (blocks, pools), and for a model
-    with experts also each layer's index, with the held experts' weights
-    taken out of ``blocks`` and returned beside as the whole stacks
-    ``[L, E, ...]``. The grouped matmul picks its layer's experts from the
-    stacks, so the scan slices and copies none of them."""
+def _layer_xs(blocks: dict):
+    """What a scan over the layers walks: (blocks, each layer's index).
+    For a model with experts the held experts' weights are taken out of
+    ``blocks`` and returned beside as the whole stacks ``[L, E, ...]``.
+    The paged kernels pick their layer of the pools (which ride the
+    scan's carry) and the grouped matmul its layer's experts from the
+    stacks by that index, so the scan slices and copies none of them."""
+    idx = jnp.arange(jax.tree.leaves(blocks)[0].shape[0], dtype=jnp.int32)
     if "moe" not in blocks:
-        return (blocks, pools), None
+        return (blocks, idx), None
     moe = blocks["moe"]
     stacks = {n: moe[n] for n in B.EXPERT_WEIGHTS}
     blocks = dict(blocks, moe={n: w for n, w in moe.items()
                                if n not in stacks})
-    n = jax.tree.leaves(pools)[0].shape[0]
-    return (blocks, pools, jnp.arange(n, dtype=jnp.int32)), stacks
+    return (blocks, idx), stacks
 
 
 class PagedEngine:
@@ -98,11 +107,15 @@ class PagedEngine:
         self.max_context = int(max_context)
         self.slots = int(slots)
         self._prefill = jax.jit(self._prefill_impl)
-        self._prefill_chunk = jax.jit(self._prefill_chunk_impl)
-        self._decode = jax.jit(self._decode_impl)
-        self._verify = jax.jit(self._verify_impl)
-        self._write = jax.jit(functools.partial(KC.write_prefill, spec=spec))
-        self._copy_block = jax.jit(self._copy_block_impl)
+        # the pools are donated: each program writes them in place
+        self._prefill_chunk = jax.jit(self._prefill_chunk_impl,
+                                      donate_argnums=1)
+        self._decode = jax.jit(self._decode_impl, donate_argnums=1)
+        self._verify = jax.jit(self._verify_impl, donate_argnums=1)
+        self._write = jax.jit(functools.partial(KC.write_prefill, spec=spec),
+                              donate_argnums=0)
+        self._copy_block = jax.jit(self._copy_block_impl, donate_argnums=0)
+        self._restore = jax.jit(KC.scatter_rows, donate_argnums=0)
         #: per-layer expert load of the last ``decode`` / ``prefill_chunk``
         #: (int32 [L_moe, 3] on the device); None for a model without
         #: experts
@@ -174,21 +187,22 @@ class PagedEngine:
         off = pos % spec.block_size
         valid = (rows < chunk_len)[None]                   # [1, C]
         if cfg.latent:
-            def attend(q, pool):                           # [1, C, H, row]
+            def attend(q, pool, i):                        # [1, C, H, row]
                 o = kops.paged_prefill_attention(
                     q[0].transpose(1, 0, 2), pool, None, table, q_offset,
-                    q_offset + chunk_len, scale=cfg.mla.qk_head_dim ** -0.5,
+                    q_offset + chunk_len, layer=i,
+                    scale=cfg.mla.qk_head_dim ** -0.5,
                     latent_v=cfg.mla.kv_lora_rank)         # [H, C, r]
                 return o.transpose(1, 0, 2)[None]
             x, new_pools, stats = self._latent_walk(
                 params, pools, x, positions, phys, off, valid, attend)
             return self._head(params, x[:, chunk_len - 1]), new_pools, stats
 
-        xs, stacks = _layer_xs(params["blocks"], pools)
+        xs, stacks = _layer_xs(params["blocks"])
 
         def body(carry, layer):
-            h_in = carry
-            lp, layer_pools = layer[:2]
+            h_in, pools = carry
+            lp, i = layer
             ap = lp["attn"]
             with jax.named_scope("attention"):
                 h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
@@ -207,27 +221,29 @@ class PagedEngine:
                 k = B.rope(k, positions, cfg.rope_theta)
 
                 with jax.named_scope("kv_write"):
-                    new_pools = KC.append_token(layer_pools, spec, k[0],
-                                                v[0], phys, off)
+                    pools = KC.append_token(pools, spec, i,
+                                            k[0].transpose(1, 0, 2),
+                                            v[0].transpose(1, 0, 2), phys,
+                                            off)
                 o = kops.paged_prefill_attention(
-                    q[0], new_pools["k"], new_pools["v"], table,
-                    q_offset, q_offset + chunk_len, scale=scale,
-                    k_scales=new_pools.get("k_scale"),
-                    v_scales=new_pools.get("v_scale"))     # [Hq, C, D]
+                    q[0], pools["k"], pools["v"], table, q_offset,
+                    q_offset + chunk_len, layer=i, scale=scale,
+                    k_scales=pools.get("k_scale"),
+                    v_scales=pools.get("v_scale"))         # [Hq, C, D]
                 h_in = h_in + (o.transpose(1, 0, 2).reshape(1, c, nq * hd)
                                @ ap["wo"]).astype(h_in.dtype)
             with jax.named_scope("mlp"):
                 hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
                 if stacks is not None:
                     f, st = B.held_moe(dict(lp["moe"], **stacks), hh, cfg,
-                                       layer[2], valid)
-                    return h_in + f, (new_pools, st)
+                                       i, valid)
+                    return (h_in + f, pools), st
                 f = B.mlp(lp["ffn"], hh)
-            return h_in + f, new_pools
+            return (h_in + f, pools), None
 
-        x, ys = jax.lax.scan(body, x, xs)
+        (x, pools), stats = jax.lax.scan(body, (x, pools), xs)
         logits = self._head(params, x[:, chunk_len - 1])
-        return (logits,) + (ys if cfg.moe.num_experts else (ys,))
+        return (logits, pools) + ((stats,) if stacks is not None else ())
 
     def prefill_chunk(self, params, pools, tokens, table, q_offset,
                       chunk_len) -> Tuple:
@@ -252,56 +268,60 @@ class PagedEngine:
     def _latent_walk(self, params, pools, x, positions, phys, off, valid,
                      attend):
         """The layers of a latent-attention model over tokens x [B, S, d]
-        at ``positions`` [B, S]: each writes its rows at ``(phys, off)``
-        [B * S] and attends through ``attend(q, pool)`` (q [B, S, H, row]
-        -> latent outputs [B, S, H, r]). The leading dense layers walk
-        first, then the expert layers. Returns (x, pools, expert load
-        [L_moe, 3])."""
+        at ``positions`` [B, S]: each writes its rows into its layer of
+        the pool stack at ``(phys, off)`` [B * S] and attends through
+        ``attend(q, pool, layer)`` (q [B, S, H, row] -> latent outputs
+        [B, S, H, r]). The leading dense layers walk first, then the
+        expert layers, both carrying the one pool stack of every layer
+        whole (the expert walk's pool layer is its expert-stack index
+        plus the dense layers'). Returns (x, pools, expert load [L_moe,
+        3])."""
         cfg = self.cfg
+        nd = cfg.moe.first_dense_layers
 
-        def layer(h_in, lp, pool):
+        def layer(h_in, lp, pool, i):
             with jax.named_scope("attention"):
                 h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
                 q, row = B.mla_absorbed(lp["attn"], h, positions, cfg)
                 with jax.named_scope("kv_write"):
                     pool = KC.append_latent(
-                        pool, row.reshape(-1, row.shape[-1]), phys, off)
+                        pool, i, row.reshape(-1, row.shape[-1]), phys, off)
                 o = attend(jnp.pad(q, ((0, 0),) * 3 + (
-                    (0, pool.shape[-1] - q.shape[-1]),)), pool)
+                    (0, pool.shape[-1] - q.shape[-1]),)), pool, i)
                 h_in = h_in + B.mla_output(lp["attn"], o, cfg).astype(
                     h_in.dtype)
             with jax.named_scope("mlp"):
                 return h_in, B.rms_norm(lp["ln2"], h_in, cfg.norm_eps), pool
 
-        def dense(h_in, xs):
-            lp, pool = xs
-            h_in, hh, pool = layer(h_in, lp, pool)
+        def dense(carry, xs):
+            (h_in, pool), (lp, i) = carry, xs
+            h_in, hh, pool = layer(h_in, lp, pool, i)
             with jax.named_scope("mlp"):
                 f = B.mlp(lp["ffn"], hh)
-            return h_in + f, pool
+            return (h_in + f, pool), None
 
-        xs, stacks = _layer_xs(params["blocks"], pools["latent"])
+        xs, stacks = _layer_xs(params["blocks"])
 
-        def expert(h_in, xs):
-            lp, pool, i = xs
-            h_in, hh, pool = layer(h_in, lp, pool)
+        def expert(carry, xs):
+            (h_in, pool), (lp, i) = carry, xs
+            h_in, hh, pool = layer(h_in, lp, pool, nd + i)
             with jax.named_scope("mlp"):
                 f, st = B.held_moe(dict(lp["moe"], **stacks), hh, cfg, i,
                                    valid)
-            return h_in + f, (pool, st)
+            return (h_in + f, pool), st
 
-        new_pools = {}
-        if "latent_dense" in pools:
-            x, new_pools["latent_dense"] = jax.lax.scan(
-                dense, x, (params["dense"], pools["latent_dense"]))
-        x, (new_pools["latent"], stats) = jax.lax.scan(expert, x, xs)
-        return x, new_pools, stats
+        pool = pools["latent"]
+        if nd:
+            (x, pool), _ = jax.lax.scan(dense, (x, pool),
+                                        _layer_xs(params["dense"])[0])
+        (x, pool), stats = jax.lax.scan(expert, (x, pool), xs)
+        return x, {"latent": pool}, stats
 
     def _copy_block_impl(self, pools, src, dst):
         """Copy-on-write helper: clone physical block ``src`` into ``dst``
-        across every pool tensor (block axis 2 of [L, Hkv, NB, bs, D])."""
-        return {k: p.at[:, :, dst].set(p[:, :, src])
-                for k, p in pools.items()}
+        across every pool tensor (block axis 1 of every page-major
+        stack)."""
+        return {k: p.at[:, dst].set(p[:, src]) for k, p in pools.items()}
 
     def copy_block(self, pools, src, dst) -> Dict:
         return self._copy_block(pools, jnp.int32(src), jnp.int32(dst))
@@ -334,9 +354,9 @@ class PagedEngine:
         attend = jnp.where(tables[:, 0] != 0, ctx_lens + 1, 0)
         valid = (attend > 0)[:, None]                      # [slots, 1]
         if cfg.latent:
-            def latent_attend(q, pool):                    # [slots,1,H,row]
+            def latent_attend(q, pool, i):                 # [slots,1,H,row]
                 return kops.paged_decode_attention(
-                    q[:, 0], pool, None, tables, attend,
+                    q[:, 0], pool, None, tables, attend, layer=i,
                     scale=cfg.mla.qk_head_dim ** -0.5,
                     latent_v=cfg.mla.kv_lora_rank)[:, None]
             x, new_pools, stats = self._latent_walk(
@@ -344,11 +364,11 @@ class PagedEngine:
                 latent_attend)
             return self._head(params, x[:, 0]), new_pools, stats
 
-        xs, stacks = _layer_xs(params["blocks"], pools)
+        xs, stacks = _layer_xs(params["blocks"])
 
         def body(carry, layer):
-            h_in = carry
-            lp, layer_pools = layer[:2]
+            h_in, pools = carry
+            lp, i = layer
             ap = lp["attn"]
             with jax.named_scope("attention"):
                 h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
@@ -367,27 +387,24 @@ class PagedEngine:
                 k = B.rope(k, positions, cfg.rope_theta)
 
                 with jax.named_scope("kv_write"):
-                    k_tok = k[:, :, 0].transpose(1, 0, 2)  # [Hkv,slots,D]
-                    v_tok = v[:, :, 0].transpose(1, 0, 2)
-                    new_pools = KC.append_token(layer_pools, spec, k_tok,
-                                                v_tok, phys, off)
+                    pools = KC.append_token(pools, spec, i, k[:, :, 0],
+                                            v[:, :, 0], phys, off)
                 o = kops.paged_decode_attention(
-                    q[:, :, 0], new_pools["k"], new_pools["v"], tables,
-                    attend, scale=scale,
-                    k_scales=new_pools.get("k_scale"),
-                    v_scales=new_pools.get("v_scale"))     # [slots,Hq,D]
+                    q[:, :, 0], pools["k"], pools["v"], tables, attend,
+                    layer=i, scale=scale, k_scales=pools.get("k_scale"),
+                    v_scales=pools.get("v_scale"))         # [slots,Hq,D]
                 h_in = h_in + (o.reshape(slots, 1, nq * hd)
                                @ ap["wo"]).astype(h_in.dtype)
             with jax.named_scope("mlp"):
                 hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
                 if stacks is not None:
                     f, st = B.held_moe(dict(lp["moe"], **stacks), hh, cfg,
-                                       layer[2], valid)
-                    return h_in + f, (new_pools, st)
+                                       i, valid)
+                    return (h_in + f, pools), st
                 f = B.mlp(lp["ffn"], hh)
-            return h_in + f, new_pools
+            return (h_in + f, pools), None
 
-        x, ys = jax.lax.scan(body, x, xs)
+        (x, pools), stats = jax.lax.scan(body, (x, pools), xs)
         with jax.named_scope("head"):
             x = B.rms_norm(params["ln_f"], x, cfg.norm_eps)
             if cfg.tie_embeddings:
@@ -395,7 +412,7 @@ class PagedEngine:
             else:
                 logits = B.linear(params["head"], x).astype(
                     jnp.float32)[:, 0]
-        return (logits,) + (ys if cfg.moe.num_experts else (ys,))
+        return (logits, pools) + ((stats,) if stacks is not None else ())
 
     def decode(self, params, pools, tokens, tables, ctx_lens) -> Tuple:
         return self._keep_stats(
@@ -441,11 +458,11 @@ class PagedEngine:
         phys = jnp.where(valid, phys, 0).reshape(-1)       # [slots*C]
         off = jnp.where(valid, safe_pos % spec.block_size, 0).reshape(-1)
 
-        xs, stacks = _layer_xs(params["blocks"], pools)
+        xs, stacks = _layer_xs(params["blocks"])
 
         def body(carry, layer):
-            h_in = carry
-            lp, layer_pools = layer[:2]
+            h_in, pools = carry
+            lp, i = layer
             ap = lp["attn"]
             h = B.rms_norm(lp["ln1"], h_in, cfg.norm_eps)
             q = h @ ap["wq"]
@@ -462,27 +479,26 @@ class PagedEngine:
             q = B.rope(q, positions, cfg.rope_theta)
             k = B.rope(k, positions, cfg.rope_theta)
 
-            k_rows = k.transpose(1, 0, 2, 3).reshape(nkv, slots * c, hd)
-            v_rows = v.transpose(1, 0, 2, 3).reshape(nkv, slots * c, hd)
-            new_pools = KC.append_token(layer_pools, spec, k_rows, v_rows,
-                                        phys, off)
+            k_rows = k.transpose(0, 2, 1, 3).reshape(slots * c, nkv, hd)
+            v_rows = v.transpose(0, 2, 1, 3).reshape(slots * c, nkv, hd)
+            pools = KC.append_token(pools, spec, i, k_rows, v_rows, phys,
+                                    off)
             o = kops.paged_verify_attention(
-                q, new_pools["k"], new_pools["v"], tables, ctx_lens,
-                chunk_lens, scale=scale,
-                k_scales=new_pools.get("k_scale"),
-                v_scales=new_pools.get("v_scale"))     # [slots, Hq, C, D]
+                q, pools["k"], pools["v"], tables, ctx_lens, chunk_lens,
+                layer=i, scale=scale, k_scales=pools.get("k_scale"),
+                v_scales=pools.get("v_scale"))         # [slots, Hq, C, D]
             h_in = h_in + (o.transpose(0, 2, 1, 3).reshape(slots, c,
                                                            nq * hd)
                            @ ap["wo"]).astype(h_in.dtype)
             hh = B.rms_norm(lp["ln2"], h_in, cfg.norm_eps)
             if stacks is not None:
-                f, _ = B.held_moe(dict(lp["moe"], **stacks), hh, cfg,
-                                  layer[2], valid)
+                f, _ = B.held_moe(dict(lp["moe"], **stacks), hh, cfg, i,
+                                  valid)
             else:
                 f = B.mlp(lp["ffn"], hh)
-            return h_in + f, new_pools
+            return (h_in + f, pools), None
 
-        x, new_pools = jax.lax.scan(body, x, xs)
+        (x, new_pools), _ = jax.lax.scan(body, (x, pools), xs)
         x = B.rms_norm(params["ln_f"], x, cfg.norm_eps)
         if cfg.tie_embeddings:
             logits = B.unembed(params["embed"], x)
@@ -494,6 +510,11 @@ class PagedEngine:
                chunk_lens) -> Tuple:
         return self._verify(params, pools, tokens, tables, ctx_lens,
                             chunk_lens)
+
+    def restore_rows(self, pools, rows, phys, off) -> Dict:
+        """:func:`repro.serve.kvcache.scatter_rows` into the donated
+        pools, in place."""
+        return self._restore(pools, rows, phys, off)
 
     # ---- sampling -----------------------------------------------------
     def make_sampler(self, sampling: str = "greedy",

@@ -2,19 +2,26 @@
 
 The serving tier's memory model (vLLM-style paging, sized for the edge
 AD-LLM of paper Fig. 2): physical KV storage is a fixed pool of
-``num_blocks`` blocks of ``block_size`` tokens per (layer, kv-head), and
-each in-flight request holds a *logical* view — a row of physical block
-ids — so admission/eviction never copies or compacts KV state. Physical
-block 0 is reserved as the null block: dead table slots point at it, its
+``num_blocks`` blocks of ``block_size`` tokens per layer, and each
+in-flight request holds a *logical* view — a row of physical block ids —
+so admission/eviction never copies or compacts KV state. Physical block
+0 is reserved as the null block: dead table slots point at it, its
 contents are garbage by design, and the paged kernel masks it out via
 ``ctx_lens``.
+
+The pools are page-major, ``[L, NB, bs, Hkv * D]``: a token's row holds
+every KV head, so a page is one contiguous tile that the paged kernels
+copy as stored. The engine carries the whole stacks through its layer
+scan, writes each layer's new rows in place at ``(layer, block,
+offset)`` and donates them to each launch, so no launch copies a pool.
 
 Two cache modes share the layout:
 
   * ``fp32``/model-dtype pools — K/V stored as written;
   * int8 pools — every (token, kv-head) row is quantized through the
     :mod:`repro.kernels.quantize` Pallas pair with a per-row absmax
-    scale, stored alongside as [..., 1] float32. Rows are zero-padded to
+    scale, stored alongside as float32, one row of ``bs * Hkv`` scales
+    a page (``[L, NB, 1, bs * Hkv]``). Rows are zero-padded to
     the kernel's 128-lane layout (padding cannot change a row's absmax)
     and the random-bits input is pinned to 2**31 — ``floor(x + 0.5)`` —
     so cache quantization is deterministic round-to-nearest rather than
@@ -257,36 +264,35 @@ class PrefixCache:
 
 # ---------------------------------------------------------------- pools ----
 def init_pools(cfg: ModelConfig, spec: PagedCacheSpec) -> Dict:
-    """Layer-stacked physical pools: k/v [L, Hkv, NB, bs, D] (+ float32
-    [..., 1] absmax scales in int8 mode).
+    """Layer-stacked physical pools, page-major: k/v [L, NB, bs, Hkv * D],
+    each token one row of every KV head (head ``h`` in lanes ``h * D`` to
+    ``(h + 1) * D``). That is the page the paged kernels read, so they
+    take the stacks as stored and no launch relayouts them. int8 pools
+    keep their float32 absmax scales beside, one row a page: k_scale /
+    v_scale [L, NB, 1, bs * Hkv], token ``t`` of head ``h`` at lane ``t *
+    Hkv + h``.
 
     Latent attention (MLA) keeps one row per token per layer instead,
     the normed latent beside the rotated shared key (``cfg.mla.row``
-    values, zero-padded to :func:`latent_width`): ``latent`` [L, 1, NB,
-    bs, width] for the expert layers and ``latent_dense`` for the
-    leading dense ones, so each of the engine's two layer walks owns its
-    pool whole."""
+    values, zero-padded to :func:`latent_width`): ``latent`` [L, NB, bs,
+    width], the leading dense layers first, which both of the engine's
+    layer walks carry whole."""
     if cfg.latent:
         if spec.quantized:
             raise NotImplementedError(
                 "an int8 latent pool is not supported (bf16 latent only)")
-        nd = cfg.moe.first_dense_layers
-
-        def latent(n):
-            return jnp.zeros((n, 1, spec.num_blocks, spec.block_size,
-                              latent_width(cfg)), cfg.dtype)
-        pools = {"latent": latent(cfg.num_layers - nd)}
-        if nd:
-            pools["latent_dense"] = latent(nd)
-        return pools
-    shape = (cfg.num_layers, cfg.num_kv_heads, spec.num_blocks,
-             spec.block_size, cfg.hd)
+        return {"latent": jnp.zeros((cfg.num_layers, spec.num_blocks,
+                                     spec.block_size, latent_width(cfg)),
+                                    cfg.dtype)}
+    lead = (cfg.num_layers, spec.num_blocks)
+    shape = lead + (spec.block_size, cfg.num_kv_heads * cfg.hd)
     if spec.quantized:
+        scales = lead + (1, spec.block_size * cfg.num_kv_heads)
         return {
             "k": jnp.zeros(shape, jnp.int8),
             "v": jnp.zeros(shape, jnp.int8),
-            "k_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
-            "v_scale": jnp.zeros(shape[:-1] + (1,), jnp.float32),
+            "k_scale": jnp.zeros(scales, jnp.float32),
+            "v_scale": jnp.zeros(scales, jnp.float32),
         }
     return {"k": jnp.zeros(shape, cfg.dtype),
             "v": jnp.zeros(shape, cfg.dtype)}
@@ -324,15 +330,28 @@ def dequantize_rows(q, scale, dtype=jnp.float32):
     return (q.astype(jnp.float32) * scale).astype(dtype)
 
 
+def _rows_at(pools: Dict, key: str, layer, phys, off) -> tuple:
+    """Index of the stored rows of tokens ``(phys, off)`` [N] in
+    ``pools[key]`` at ``layer`` (a slice for every layer): a K/V or latent
+    row, or the Hkv scales of a token, lanes ``off * Hkv + h`` of its
+    page's scale row ([N, Hkv])."""
+    if key.endswith("_scale"):
+        hkv = pools[key].shape[-1] // pools["k"].shape[2]
+        lanes = off[:, None] * hkv + jnp.arange(hkv, dtype=jnp.int32)
+        return (layer, phys[:, None], 0, lanes)
+    return (layer, phys, off)
+
+
 def write_prefill(pools: Dict, spec: PagedCacheSpec, k_layers, v_layers,
                   table_row) -> Dict:
     """Scatter one request's contiguous prefill K/V into its pool blocks.
 
     k_layers/v_layers: [L, Hkv, S, D] (S is the padded prefill buffer —
     rows past the true context length are garbage and stay masked by
-    ``ctx_lens``); table_row: [T] int32, trailing entries null. Blocks
-    beyond the request's allocation scatter into the null block, which is
-    garbage by contract."""
+    ``ctx_lens``), laid out page-major here as [L, S / bs, bs, Hkv * D];
+    table_row: [T] int32, trailing entries null. Blocks beyond the
+    request's allocation scatter into the null block, which is garbage
+    by contract."""
     l, hkv, s, d = k_layers.shape
     bs = spec.block_size
     pad = (-s) % bs
@@ -341,20 +360,23 @@ def write_prefill(pools: Dict, spec: PagedCacheSpec, k_layers, v_layers,
         k_layers = jnp.pad(k_layers, widths)
         v_layers = jnp.pad(v_layers, widths)
     nb = (s + pad) // bs
-    kb = k_layers.reshape(l, hkv, nb, bs, d)
-    vb = v_layers.reshape(l, hkv, nb, bs, d)
     row = table_row[:nb]
+
+    def pages(x):                      # [L, Hkv, nb*bs, e] -> [L, nb, bs, Hkv*e]
+        e = x.shape[-1]
+        return x.reshape(l, hkv, nb, bs, e).transpose(0, 2, 3, 1, 4).reshape(
+            l, nb, bs, hkv * e)
+
     out = dict(pools)
-    if spec.quantized:
-        kq, ks = quantize_rows(kb)
-        vq, vs = quantize_rows(vb)
-        out["k"] = pools["k"].at[:, :, row].set(kq)
-        out["v"] = pools["v"].at[:, :, row].set(vq)
-        out["k_scale"] = pools["k_scale"].at[:, :, row].set(ks)
-        out["v_scale"] = pools["v_scale"].at[:, :, row].set(vs)
-    else:
-        out["k"] = pools["k"].at[:, :, row].set(kb.astype(pools["k"].dtype))
-        out["v"] = pools["v"].at[:, :, row].set(vb.astype(pools["v"].dtype))
+    for name, x in (("k", k_layers), ("v", v_layers)):
+        if spec.quantized:
+            q, sc = quantize_rows(x)
+            out[name] = pools[name].at[:, row].set(pages(q))
+            out[name + "_scale"] = pools[name + "_scale"].at[:, row].set(
+                pages(sc).reshape(l, nb, 1, bs * hkv))
+        else:
+            out[name] = pools[name].at[:, row].set(
+                pages(x).astype(pools[name].dtype))
     return out
 
 
@@ -362,14 +384,15 @@ def gather_rows(pools: Dict, phys, off) -> Dict:
     """Snapshot pool rows at ``(phys, off)`` token positions (jit-safe).
 
     ``phys``/``off``: [N] int32 physical block ids and in-block offsets.
-    Returns ``{key: [L, Hkv, N, ...]}`` — the exact stored rows (int8
-    codes AND their scales in quantized mode), so a later
-    :func:`scatter_rows` restores them bitwise. This is the speculative
-    decoder's rollback snapshot: taken over a lane's draft window before
-    the batched verify appends draft K/V, then written back over the
-    rejected tail so the pools are indistinguishable from never having
-    drafted."""
-    return {key: p[:, :, phys, off] for key, p in pools.items()}
+    Returns ``{key: [L, N, ...]}`` — the exact stored rows (int8 codes
+    [L, N, Hkv * D] AND their scales [L, N, Hkv] in quantized mode), so a
+    later :func:`scatter_rows` restores them bitwise. This is the
+    speculative decoder's rollback snapshot: taken over a lane's draft
+    window before the batched verify appends draft K/V, then written
+    back over the rejected tail so the pools are indistinguishable from
+    never having drafted."""
+    return {key: p[_rows_at(pools, key, slice(None), phys, off)]
+            for key, p in pools.items()}
 
 
 def scatter_rows(pools: Dict, rows: Dict, phys, off) -> Dict:
@@ -378,41 +401,41 @@ def scatter_rows(pools: Dict, rows: Dict, phys, off) -> Dict:
     Callers mask a *partial* restore by redirecting kept positions to the
     null block (``phys = where(rejected, phys, 0)``); duplicate scatters
     into block 0 are harmless by the null-block contract."""
+    return {key: p.at[_rows_at(pools, key, slice(None), phys, off)].set(
+        rows[key].astype(p.dtype)) for key, p in pools.items()}
+
+
+def append_token(pools: Dict, spec: PagedCacheSpec, layer, k_tok, v_tok,
+                 phys, off) -> Dict:
+    """Write new tokens' K/V into ``layer`` of the layer-stacked pools.
+
+    k_tok/v_tok: [N, Hkv, D], each token's rows, written as one ``Hkv *
+    D`` pool row (int8 pools: quantized per (token, head), the Hkv
+    scales into the page's scale row); pools: the whole stacks of
+    :func:`init_pools`, which a layer scan carries so that XLA writes
+    them in place; layer: int32 scalar; phys/off: [N] physical block id
+    and in-block offset. Inactive rows point at (null, 0) — duplicate
+    scatters there are harmless."""
+    n, hkv, d = k_tok.shape
     out = dict(pools)
-    for key, p in pools.items():
-        out[key] = p.at[:, :, phys, off].set(rows[key].astype(p.dtype))
+    for name, x in (("k", k_tok), ("v", v_tok)):
+        if spec.quantized:
+            q, sc = quantize_rows(x)
+            out[name] = pools[name].at[layer, phys, off].set(
+                q.reshape(n, hkv * d))
+            key = name + "_scale"
+            out[key] = pools[key].at[_rows_at(pools, key, layer, phys,
+                                              off)].set(sc[..., 0])
+        else:
+            out[name] = pools[name].at[layer, phys, off].set(
+                x.reshape(n, hkv * d).astype(pools[name].dtype))
     return out
 
 
-def append_token(pools: Dict, spec: PagedCacheSpec, k_tok, v_tok, phys, off
-                 ) -> Dict:
-    """Append one decode token's K/V per request into per-layer pools.
-
-    k_tok/v_tok: [Hkv, B, D] (a single layer's new rows, batch in the
-    middle so the scatter value matches ``pools[:, phys, off]``); pools
-    here are the [Hkv, NB, bs, D] slices of one layer; phys/off: [B]
-    physical block id and in-block offset. Inactive slots point at
-    (null, 0) — duplicate scatters there are harmless."""
-    out = dict(pools)
-    if spec.quantized:
-        kq, ks = quantize_rows(k_tok)
-        vq, vs = quantize_rows(v_tok)
-        out["k"] = pools["k"].at[:, phys, off].set(kq)
-        out["v"] = pools["v"].at[:, phys, off].set(vq)
-        out["k_scale"] = pools["k_scale"].at[:, phys, off].set(ks)
-        out["v_scale"] = pools["v_scale"].at[:, phys, off].set(vs)
-    else:
-        out["k"] = pools["k"].at[:, phys, off].set(
-            k_tok.astype(pools["k"].dtype))
-        out["v"] = pools["v"].at[:, phys, off].set(
-            v_tok.astype(pools["v"].dtype))
-    return out
-
-
-def append_latent(pool, row, phys, off):
-    """Write latent rows into one layer's latent pool [1, NB, bs, width]:
-    row [N, row] (zero-padded to the pool's width) at ``(phys, off)`` [N]
-    (padding and dead lanes point at the null block, where duplicate
-    writes are harmless)."""
+def append_latent(pool, layer, row, phys, off):
+    """Write latent rows into ``layer`` of a layer-stacked latent pool
+    [L, NB, bs, width]: row [N, row] (zero-padded to the pool's width) at
+    ``(phys, off)`` [N] (padding and dead lanes point at the null block,
+    where duplicate writes are harmless)."""
     row = jnp.pad(row, ((0, 0), (0, pool.shape[-1] - row.shape[-1])))
-    return pool.at[0, phys, off].set(row.astype(pool.dtype))
+    return pool.at[layer, phys, off].set(row.astype(pool.dtype))

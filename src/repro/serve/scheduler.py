@@ -62,7 +62,8 @@ acceptance emits the matched prefix plus the target's own next token,
 so the output streams are bit-identical to non-speculative greedy
 decode; the rejected tail's K/V rows are rolled back bitwise
 (:func:`repro.serve.kvcache.gather_rows` snapshot before the verify
-append, :func:`repro.serve.kvcache.scatter_rows` restore after) and the
+append, :func:`repro.serve.kvcache.scatter_rows` restore after, into the
+donated pools in place) and the
 per-lane context rewinds to the accepted length. Lanes near completion
 shrink their window to the tokens they may still emit, which keeps
 every append inside the blocks reserved at admission.
@@ -768,7 +769,8 @@ class ContinuousScheduler:
         if restore.any():
             r_phys = jnp.asarray(np.where(restore, phys, 0).reshape(-1))
             r_off = jnp.asarray(np.where(restore, off, 0).reshape(-1))
-            self.pools = KC.scatter_rows(self.pools, saved, r_phys, r_off)
+            self.pools = self.engine.restore_rows(self.pools, saved,
+                                                  r_phys, r_off)
 
         hist = self.metrics.histogram(
             "serve_spec_accepted_len",

@@ -1,4 +1,6 @@
 """Pallas kernels vs pure-jnp oracles, interpret mode, shape/dtype sweeps."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -262,13 +264,19 @@ def test_quantize_int8_stochastic_rounding_unbiased():
     assert mean_err < 0.25 * step, (mean_err, step)
 
 
+# The kernels read page-major layer stacks [L, NB, bs, Hkv * D] at a
+# layer index; the tests' pools hold two layers and read the second.
+LAYERS, LAYER = 2, 1
+
+
 def _paged_setup(k, b, hkv, nb, bs, d, ctx_list, t=None):
-    """Random pools + a valid block table for the given context lengths
-    (``t`` table slots; one more than the longest context needs)."""
+    """Random pools [LAYERS, NB, bs, Hkv * D] + a valid block table for the
+    given context lengths (``t`` table slots; one more than the longest
+    context needs)."""
     import numpy as np
     ks = jax.random.split(k, 3)
-    kp = jax.random.normal(ks[0], (hkv, nb, bs, d), jnp.float32)
-    vp = jax.random.normal(ks[1], (hkv, nb, bs, d), jnp.float32)
+    kp = jax.random.normal(ks[0], (LAYERS, nb, bs, hkv * d), jnp.float32)
+    vp = jax.random.normal(ks[1], (LAYERS, nb, bs, hkv * d), jnp.float32)
     t = t or max(-(-c // bs) for c in ctx_list) + 1
     tbl = np.zeros((b, t), np.int32)
     free = list(range(1, nb))
@@ -299,12 +307,21 @@ def test_paged_decode_attention(b, hq, hkv, d, bs, ctx_list, t):
     kp, vp, tbl, ctx = _paged_setup(KEY, b, hkv, nb, bs, d, ctx_list, t)
     q = jax.random.normal(jax.random.fold_in(KEY, 7), (b, hq, d),
                           jnp.float32)
-    got = ops.paged_decode_attention(q, kp, vp, tbl, ctx, interpret=True)
-    want = ref.paged_decode_attention_ref(q, kp, vp, tbl, ctx)
+    got = ops.paged_decode_attention(q, kp, vp, tbl, ctx, layer=LAYER,
+                                     interpret=True)
+    want = ref.paged_decode_attention_ref(q, kp, vp, tbl, ctx, layer=LAYER)
     assert float(jnp.max(jnp.abs(got - want))) < 5e-6
     for i, c in enumerate(ctx_list):
         if c == 0:
             assert float(jnp.abs(got[i]).max()) == 0.0
+
+
+def _int8_scales(key, pool, hkv):
+    """Random float32 absmax scales for an int8 pool: one row of bs * Hkv
+    a page, [L, NB, 1, bs * Hkv]."""
+    l, nb, bs, _ = pool.shape
+    return jax.random.uniform(key, (l, nb, 1, bs * hkv), jnp.float32, 1e-3,
+                              2e-2)
 
 
 @pytest.mark.parametrize("bs,ctx_list", [
@@ -321,28 +338,32 @@ def test_paged_decode_attention_int8(bs, ctx_list):
                             jnp.int32).astype(jnp.int8)
     vq = jax.random.randint(ks[1], vp.shape, -127, 128,
                             jnp.int32).astype(jnp.int8)
-    ksc = jax.random.uniform(ks[2], kp.shape[:-1] + (1,), jnp.float32,
-                             1e-3, 2e-2)
-    vsc = jax.random.uniform(ks[3], vp.shape[:-1] + (1,), jnp.float32,
-                             1e-3, 2e-2)
+    ksc = _int8_scales(ks[2], kp, hkv)
+    vsc = _int8_scales(ks[3], vp, hkv)
     q = jax.random.normal(ks[4], (b, hq, d), jnp.float32)
-    got = ops.paged_decode_attention(q, kq, vq, tbl, ctx, k_scales=ksc,
-                                     v_scales=vsc, interpret=True)
-    want = ref.paged_decode_attention_ref(q, kq, vq, tbl, ctx,
+    got = ops.paged_decode_attention(q, kq, vq, tbl, ctx, layer=LAYER,
+                                     k_scales=ksc, v_scales=vsc,
+                                     interpret=True)
+    want = ref.paged_decode_attention_ref(q, kq, vq, tbl, ctx, layer=LAYER,
                                           k_scales=ksc, v_scales=vsc)
     assert float(jnp.max(jnp.abs(got - want))) < 5e-6
 
 
-def _poison_dead(pool, tbl, ctx_list, bs):
+def _poison_dead(pool, tbl, ctx_list, bs, hkv=1):
     """NaN in every pool row outside the lanes' live ranges: every block
     no lane's context reaches (the null block 0 included) and the rows
-    past the context in each lane's partial last page."""
+    past the context in each lane's partial last page. A scale pool [L,
+    NB, 1, bs * Hkv] is poisoned at the lanes of those tokens."""
     import numpy as np
-    live = np.zeros(pool.shape[1:3], bool)            # [NB, bs]
+    live = np.zeros((pool.shape[1], bs), bool)        # [NB, bs]
     for row, c in zip(np.asarray(tbl), ctx_list):
         for j in range(-(-c // bs)):
             live[row[j], :min(bs, c - j * bs)] = True
-    return jnp.where(jnp.asarray(live)[None, :, :, None], pool, jnp.nan)
+    if pool.shape[2] == 1 and pool.shape[3] == bs * hkv:
+        live = np.repeat(live, hkv, axis=1)[:, None, :]  # lane t*Hkv + h
+    else:
+        live = live[:, :, None]
+    return jnp.where(jnp.asarray(live)[None], pool, jnp.nan)
 
 
 @pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
@@ -361,46 +382,107 @@ def test_paged_decode_never_reads_dead_pages(int8):
         ks = jax.random.split(jax.random.fold_in(KEY, 17), 2)
         kp = jnp.clip(jnp.round(kp * 40), -127, 127).astype(jnp.int8)
         vp = jnp.clip(jnp.round(vp * 40), -127, 127).astype(jnp.int8)
-        scales = [jax.random.uniform(k, kp.shape[:-1] + (1,), jnp.float32,
-                                     1e-3, 2e-2) for k in ks]
+        scales = [_int8_scales(k, kp, hkv) for k in ks]
         want = ref.paged_decode_attention_ref(
-            q, kp, vp, tbl, ctx, k_scales=scales[0], v_scales=scales[1])
-        ksc, vsc = (_poison_dead(s_, tbl, ctx_list, bs) for s_ in scales)
-        got = ops.paged_decode_attention(q, kp, vp, tbl, ctx, k_scales=ksc,
-                                         v_scales=vsc, interpret=True)
+            q, kp, vp, tbl, ctx, layer=LAYER, k_scales=scales[0],
+            v_scales=scales[1])
+        ksc, vsc = (_poison_dead(s_, tbl, ctx_list, bs, hkv)
+                    for s_ in scales)
+        got = ops.paged_decode_attention(q, kp, vp, tbl, ctx, layer=LAYER,
+                                         k_scales=ksc, v_scales=vsc,
+                                         interpret=True)
     else:
-        want = ref.paged_decode_attention_ref(q, kp, vp, tbl, ctx)
+        want = ref.paged_decode_attention_ref(q, kp, vp, tbl, ctx,
+                                              layer=LAYER)
         got = ops.paged_decode_attention(
             q, _poison_dead(kp, tbl, ctx_list, bs),
-            _poison_dead(vp, tbl, ctx_list, bs), tbl, ctx, interpret=True)
+            _poison_dead(vp, tbl, ctx_list, bs), tbl, ctx, layer=LAYER,
+            interpret=True)
     assert bool(jnp.isfinite(got).all())
     assert float(jnp.max(jnp.abs(got - want))) < 5e-6
     assert float(jnp.abs(got[1]).max()) == 0.0
 
 
+def _pages(x, bs):
+    """A contiguous stream [Hkv, S, D] as page-major pages [S / bs, bs,
+    Hkv * D] (zero-padded to whole pages)."""
+    hkv, s, d = x.shape
+    t = -(-s // bs)
+    x = jnp.pad(x, ((0, 0), (0, t * bs - s), (0, 0)))
+    return x.transpose(1, 0, 2).reshape(t, bs, hkv * d)
+
+
+def _quantize_pool(pool, hkv):
+    """int8 codes and [L, NB, 1, bs * Hkv] scales of a page-major pool,
+    quantized per (token, head) row as the engine writes them."""
+    from repro.serve.kvcache import quantize_rows
+    l, nb, bs, w = pool.shape
+    q, sc = quantize_rows(pool.reshape(l, nb, bs, hkv, w // hkv))
+    return q.reshape(pool.shape), sc.reshape(l, nb, 1, bs * hkv)
+
+
+@pytest.mark.parametrize("kernel", ["decode", "prefill"])
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+def test_paged_kernels_read_only_their_layer(kernel, int8):
+    """Handed the whole layer stacks, a kernel reads the layer it is told
+    and no other: NaN in every other layer leaves its output finite and
+    equal to the oracle's on clean stacks."""
+    b, hq, hkv, d, bs, ctx_list = 3, 4, 2, 64, 16, [40, 0, 17]
+    nb = 1 + sum(-(-c // bs) for c in ctx_list) + 1
+    kp, vp, tbl, ctx = _paged_setup(KEY, b, hkv, nb, bs, d, ctx_list)
+    kw = {}
+    if int8:
+        ks = jax.random.split(jax.random.fold_in(KEY, 61), 2)
+        kp = jnp.clip(jnp.round(kp * 40), -127, 127).astype(jnp.int8)
+        vp = jnp.clip(jnp.round(vp * 40), -127, 127).astype(jnp.int8)
+        kw = dict(k_scales=_int8_scales(ks[0], kp, hkv),
+                  v_scales=_int8_scales(ks[1], vp, hkv))
+    other = jnp.arange(LAYERS)[:, None, None, None] != LAYER
+    if kernel == "decode":
+        q = jax.random.normal(jax.random.fold_in(KEY, 67), (b, hq, d))
+        want = ref.paged_decode_attention_ref(q, kp, vp, tbl, ctx,
+                                              layer=LAYER, **kw)
+        run = functools.partial(ops.paged_decode_attention, q,
+                                block_tables=tbl, ctx_lens=ctx)
+    else:
+        q = jax.random.normal(jax.random.fold_in(KEY, 71), (hq, 8, d))
+        want = ref.paged_prefill_attention_ref(q, kp, vp, tbl[0], 32, 40,
+                                               layer=LAYER, **kw)[:, :8]
+        run = functools.partial(ops.paged_prefill_attention, q,
+                                block_table=tbl[0], q_offset=32, ctx_len=40)
+    if not int8:        # NaN in the other layer's K and V
+        poisoned = {n: jnp.where(other, jnp.nan, x) for n, x in
+                    dict(kp=kp, vp=vp).items()}
+    else:               # int8 codes cannot hold NaN: poison the scales
+        poisoned = dict(kp=kp, vp=vp, **{n: jnp.where(other, jnp.nan, x)
+                                         for n, x in kw.items()})
+    got = run(k_pages=poisoned.pop("kp"), v_pages=poisoned.pop("vp"),
+              layer=LAYER, interpret=True, **poisoned)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.max(jnp.abs(got - want))) < 5e-6
+
+
 def _prefill_pool_setup(key, hkv, bs, d, s, spare=2, int8=False):
-    """A contiguous K/V stream scattered into shuffled physical blocks,
-    plus the block table that maps it back (trailing entries null)."""
+    """A contiguous K/V stream scattered into shuffled physical blocks of
+    layer ``LAYER`` of the stacks (the other layer random), plus the
+    block table that maps it back (trailing entries null)."""
     import numpy as np
     t = -(-s // bs)
     nb = 1 + t + spare
-    ks = jax.random.split(key, 4)
+    ks = jax.random.split(key, 5)
     k = jax.random.normal(ks[0], (hkv, s, d), jnp.float32)
     v = jax.random.normal(ks[1], (hkv, s, d), jnp.float32)
-    pad = t * bs - s
-    kb = jnp.pad(k, ((0, 0), (0, pad), (0, 0))).reshape(hkv, t, bs, d)
-    vb = jnp.pad(v, ((0, 0), (0, pad), (0, 0))).reshape(hkv, t, bs, d)
     rng = np.random.default_rng(int(jax.random.randint(ks[2], (), 0, 1 << 30)))
     phys = rng.permutation(np.arange(1, nb))[:t]
-    kp = jnp.zeros((hkv, nb, bs, d), jnp.float32).at[:, phys].set(kb)
-    vp = jnp.zeros((hkv, nb, bs, d), jnp.float32).at[:, phys].set(vb)
+    other = jax.random.normal(ks[3], (LAYERS, nb, bs, hkv * d), jnp.float32)
+    kp = other.at[LAYER].set(0.0).at[LAYER, phys].set(_pages(k, bs))
+    vp = (-other).at[LAYER].set(0.0).at[LAYER, phys].set(_pages(v, bs))
     tbl = np.zeros(t + 1, np.int32)
     tbl[:t] = phys
     scales = None
     if int8:
-        from repro.serve.kvcache import quantize_rows
-        kp, ksc = quantize_rows(kp)
-        vp, vsc = quantize_rows(vp)
+        kp, ksc = _quantize_pool(kp, hkv)
+        vp, vsc = _quantize_pool(vp, hkv)
         scales = (ksc, vsc)
     return k, v, kp, vp, jnp.asarray(tbl), scales
 
@@ -414,6 +496,10 @@ def _prefill_pool_setup(key, hkv, bs, d, s, spare=2, int8=False):
         (8, 8, 64, 16, 16, 16, 0),    # MHA, one exact-fit chunk
         (2, 1, 128, 4, 4, 9, 4),      # MQA, tiny blocks, odd tail
         (4, 2, 32, 8, 16, 37, 16),    # chunk spanning multiple blocks
+        # lane groups: 2 heads of 64 per 128-lane slice, 2 groups of 2
+        # query heads each; 1 head of 128 per slice, 2 groups of 4
+        (8, 4, 64, 8, 8, 21, 8),
+        (8, 2, 128, 8, 8, 21, 8),
     ])
 def test_paged_prefill_attention(hq, hkv, d, bs, chunk, ctx, off):
     """Chunked paged prefill kernel vs the dense gather oracle: a C-row
@@ -422,27 +508,31 @@ def test_paged_prefill_attention(hq, hkv, d, bs, chunk, ctx, off):
                                                hkv, bs, d, ctx)
     q = jax.random.normal(jax.random.fold_in(KEY, 17), (hq, chunk, d),
                           jnp.float32)
-    got = ops.paged_prefill_attention(q, kp, vp, tbl, off, ctx,
+    got = ops.paged_prefill_attention(q, kp, vp, tbl, off, ctx, layer=LAYER,
                                       interpret=True)
-    want = ref.paged_prefill_attention_ref(q, kp, vp, tbl, off, ctx)
+    want = ref.paged_prefill_attention_ref(q, kp, vp, tbl, off, ctx,
+                                           layer=LAYER)
     clen = ctx - off            # rows past the live chunk are garbage
     assert got.shape == (hq, chunk, d)
     err = float(jnp.max(jnp.abs(got[:, :clen] - want[:, :clen])))
     assert err < 5e-6
 
 
-def test_paged_prefill_attention_int8():
+# one group of the whole row; two lane groups of 2 heads of 64
+@pytest.mark.parametrize("hq,hkv,d", [(4, 2, 32), (8, 4, 64)])
+def test_paged_prefill_attention_int8(hq, hkv, d):
     """int8 pools dequantize in-kernel through per-row scales."""
-    hq, hkv, d, bs, chunk, ctx, off = 4, 2, 32, 8, 8, 19, 8
+    bs, chunk, ctx, off = 8, 8, 19, 8
     _, _, kp, vp, tbl, (ksc, vsc) = _prefill_pool_setup(
         jax.random.fold_in(KEY, 19), hkv, bs, d, ctx, int8=True)
     q = jax.random.normal(jax.random.fold_in(KEY, 23), (hq, chunk, d),
                           jnp.float32)
-    got = ops.paged_prefill_attention(q, kp, vp, tbl, off, ctx,
+    got = ops.paged_prefill_attention(q, kp, vp, tbl, off, ctx, layer=LAYER,
                                       k_scales=ksc, v_scales=vsc,
                                       interpret=True)
     want = ref.paged_prefill_attention_ref(q, kp, vp, tbl, off, ctx,
-                                           k_scales=ksc, v_scales=vsc)
+                                           layer=LAYER, k_scales=ksc,
+                                           v_scales=vsc)
     clen = ctx - off
     assert float(jnp.max(jnp.abs(got[:, :clen] - want[:, :clen]))) < 5e-6
 
@@ -465,8 +555,9 @@ def test_paged_prefill_dead_blocks_skipped():
     tbl_nan = np.asarray(tbl).copy()
     tbl_nan[live:] = poison
     got = ops.paged_prefill_attention(q, kp, vp, jnp.asarray(tbl_nan),
-                                      off, ctx, interpret=True)
-    want = ref.paged_prefill_attention_ref(q, kp, vp, tbl, off, ctx)
+                                      off, ctx, layer=LAYER, interpret=True)
+    want = ref.paged_prefill_attention_ref(q, kp, vp, tbl, off, ctx,
+                                           layer=LAYER)
     clen = ctx - off
     assert bool(jnp.isfinite(got[:, :clen]).all())
     assert float(jnp.max(jnp.abs(got[:, :clen] - want[:, :clen]))) < 5e-6
@@ -486,7 +577,7 @@ def test_paged_prefill_chunks_match_flash():
         qc = jnp.zeros((hq, chunk, d)).at[:, :clen].set(
             q[:, off:off + clen])
         o = ops.paged_prefill_attention(qc, kp, vp, tbl, off, off + clen,
-                                        interpret=True)
+                                        layer=LAYER, interpret=True)
         outs.append(o[:, :clen])
     got = jnp.concatenate(outs, axis=1)
     want = ref.flash_attention_ref(q[None], k[None], v[None],
@@ -508,22 +599,17 @@ def test_paged_decode_matches_contiguous_attention():
 
     t = -(-s // bs)
     nb = 1 + b * t
-    pad = t * bs - s
-    kb = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    vb = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
     rng = np.random.default_rng(3)
     phys = rng.permutation(np.arange(1, nb)).reshape(b, t)
-    kp = jnp.zeros((hkv, nb, bs, d), jnp.float32)
-    vp = jnp.zeros((hkv, nb, bs, d), jnp.float32)
+    kp = jnp.zeros((1, nb, bs, hkv * d), jnp.float32)
+    vp = jnp.zeros((1, nb, bs, hkv * d), jnp.float32)
     for i in range(b):
-        kp = kp.at[:, phys[i]].set(
-            kb[i].reshape(hkv, t, bs, d))
-        vp = vp.at[:, phys[i]].set(
-            vb[i].reshape(hkv, t, bs, d))
+        kp = kp.at[0, phys[i]].set(_pages(k[i], bs))
+        vp = vp.at[0, phys[i]].set(_pages(v[i], bs))
     ctx = jnp.full((b,), s, jnp.int32)
     got = ops.paged_decode_attention(q[:, :, 0], kp, vp,
                                      jnp.asarray(phys, jnp.int32), ctx,
-                                     interpret=True)
+                                     layer=0, interpret=True)
     assert float(jnp.max(jnp.abs(got - dense[:, :, 0]))) < 5e-6
 
 
@@ -549,10 +635,11 @@ def test_paged_decode_latent(b, hq, d, dv, bs, ctx_list):
                           jnp.float32)
     scale = 192 ** -0.5
     want = ref.paged_decode_attention_ref(q, kp, _latent_values(kp, dv), tbl,
-                                          ctx, scale=scale)[..., :dv]
+                                          ctx, layer=LAYER,
+                                          scale=scale)[..., :dv]
     got = ops.paged_decode_attention(
-        q, _poison_dead(kp, tbl, ctx_list, bs), None, tbl, ctx, scale=scale,
-        latent_v=dv, interpret=True)
+        q, _poison_dead(kp, tbl, ctx_list, bs), None, tbl, ctx, layer=LAYER,
+        scale=scale, latent_v=dv, interpret=True)
     assert got.shape == (b, hq, dv)
     assert bool(jnp.isfinite(got).all())
     assert float(jnp.max(jnp.abs(got - want))) < 5e-6
@@ -573,10 +660,10 @@ def test_paged_prefill_latent(hq, d, dv, bs, chunk, ctx, off):
     q = jax.random.normal(jax.random.fold_in(KEY, 53), (hq, chunk, d),
                           jnp.float32)
     got = ops.paged_prefill_attention(q, kp, None, tbl, off, ctx,
-                                      scale=192 ** -0.5, latent_v=dv,
-                                      interpret=True)
+                                      layer=LAYER, scale=192 ** -0.5,
+                                      latent_v=dv, interpret=True)
     want = ref.paged_prefill_attention_ref(q, kp, _latent_values(kp, dv),
-                                           tbl, off, ctx,
+                                           tbl, off, ctx, layer=LAYER,
                                            scale=192 ** -0.5)[..., :dv]
     clen = ctx - off
     assert got.shape == (hq, chunk, dv)
@@ -588,10 +675,11 @@ def test_latent_kernels_refuse_mixed_pools():
     kp = jnp.zeros((1, 4, 8, 128))
     tbl, ctx = jnp.zeros((2, 2), jnp.int32), jnp.zeros((2,), jnp.int32)
     with pytest.raises(ValueError, match="latent"):
-        ops.paged_decode_attention(q, kp, kp, tbl, ctx, latent_v=64,
-                                   interpret=True)
+        ops.paged_decode_attention(q, kp, kp, tbl, ctx, layer=0,
+                                   latent_v=64, interpret=True)
     with pytest.raises(ValueError, match="latent"):
-        ops.paged_decode_attention(q, kp, None, tbl, ctx, interpret=True)
+        ops.paged_decode_attention(q, kp, None, tbl, ctx, layer=0,
+                                   interpret=True)
 
 
 # --------------------------------------------- grouped expert SwiGLU ------

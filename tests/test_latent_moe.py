@@ -281,11 +281,13 @@ def test_latent_pool_geometry_and_refusals(model):
     _, cfg, _ = model
     spec = PagedCacheSpec(num_blocks=6, block_size=4, max_blocks_per_req=2)
     pools = KC.init_pools(cfg, spec)
-    assert pools["latent"].shape == (2, 1, 6, 4, 128)   # 40 lanes padded
-    assert pools["latent_dense"].shape == (1, 1, 6, 4, 128)
+    # one stack of every layer, the dense one first; 40 lanes padded
+    assert set(pools) == {"latent"}
+    assert pools["latent"].shape == (3, 6, 4, 128)
     eng = PagedEngine(cfg, spec, max_context=8, slots=2)
-    moved = eng.copy_block(pools, 0, 3)
-    assert set(moved) == set(pools)
+    keys = set(pools)
+    moved = eng.copy_block(pools, 0, 3)     # donates ``pools``
+    assert set(moved) == keys
     with pytest.raises(NotImplementedError, match="int8 latent"):
         KC.init_pools(cfg, PagedCacheSpec(6, 4, 2, quantized=True))
     with pytest.raises(NotImplementedError, match="chunked"):

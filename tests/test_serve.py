@@ -78,6 +78,104 @@ def test_quantize_rows_deterministic_roundtrip():
 
 
 # ------------------------------------ incremental decode == full forward ---
+def _pool_cfg():
+    from repro.config import ModelConfig
+    return ModelConfig(name="t", family="dense", num_layers=3, d_model=16,
+                       num_heads=4, num_kv_heads=2, d_ff=32, vocab_size=32,
+                       param_dtype="float32")
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_append_token_writes_one_row_of_one_layer(quantized):
+    """A token's K/V lands as one page-major row ``[Hkv * D]`` (head h in
+    lanes h*D..) at (layer, block, offset), its int8 scales at lanes
+    ``off * Hkv + h`` of the page's scale row; nothing else changes."""
+    cfg = _pool_cfg()
+    hkv, d = cfg.num_kv_heads, cfg.hd
+    spec = PagedCacheSpec(num_blocks=6, block_size=4, max_blocks_per_req=2,
+                          quantized=quantized)
+    pools = KC.init_pools(cfg, spec)
+    assert pools["k"].shape == (3, 6, 4, hkv * d)
+    if quantized:
+        assert pools["k_scale"].shape == (3, 6, 1, 4 * hkv)
+    before = {k: np.asarray(p).copy() for k, p in pools.items()}
+    k_tok = jax.random.normal(KEY, (2, hkv, d), jnp.float32)
+    v_tok = -k_tok
+    phys, off = jnp.asarray([3, 5], jnp.int32), jnp.asarray([1, 3],
+                                                           jnp.int32)
+    out = KC.append_token(pools, spec, 2, k_tok, v_tok, phys, off)
+    for name, x in (("k", k_tok), ("v", v_tok)):
+        want = before[name].copy()
+        if quantized:
+            q, sc = KC.quantize_rows(x)
+            want[2, [3, 5], [1, 3]] = np.asarray(q).reshape(2, hkv * d)
+            ws = before[name + "_scale"].copy()
+            for n, (b, o) in enumerate(((3, 1), (5, 3))):
+                ws[2, b, 0, o * hkv:(o + 1) * hkv] = np.asarray(sc)[n, :, 0]
+            assert np.array_equal(np.asarray(out[name + "_scale"]), ws)
+        else:
+            want[2, [3, 5], [1, 3]] = np.asarray(x).reshape(2, hkv * d)
+        assert np.array_equal(np.asarray(out[name]), want)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["f32", "int8"])
+def test_write_prefill_equals_token_appends(quantized):
+    """Scattering a contiguous prefill [L, Hkv, S, D] into its blocks
+    stores exactly what appending its tokens one layer at a time does."""
+    cfg = _pool_cfg()
+    hkv, d, s = cfg.num_kv_heads, cfg.hd, 7
+    spec = PagedCacheSpec(num_blocks=6, block_size=4, max_blocks_per_req=2,
+                          quantized=quantized)
+    k = jax.random.normal(KEY, (3, hkv, s, d), jnp.float32)
+    v = jax.random.normal(jax.random.fold_in(KEY, 1), (3, hkv, s, d))
+    table = jnp.asarray([4, 2], jnp.int32)
+    got = KC.write_prefill(KC.init_pools(cfg, spec), spec, k, v, table)
+    want = KC.init_pools(cfg, spec)
+    pos = jnp.arange(s)
+    phys, off = table[pos // 4], pos % 4
+    for layer in range(3):
+        want = KC.append_token(want, spec, layer,
+                               k[layer].transpose(1, 0, 2),
+                               v[layer].transpose(1, 0, 2), phys, off)
+    for key in want:      # position 7 of block 2 is padding, zero in both
+        assert np.array_equal(np.asarray(got[key]), np.asarray(want[key]))
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk", "verify",
+                                     "copy_block", "write_prefill",
+                                     "restore_rows"])
+def test_engine_programs_donate_the_pools(dense_setup, program):
+    """Each program that takes the pools consumes them: the arrays passed
+    in are deleted and the returned pools are live and of the same
+    geometry, so a launch allocates no second pool."""
+    cfg, params = dense_setup
+    spec = PagedCacheSpec.for_requests(2, 16, block_size=4)
+    eng = PagedEngine(cfg, spec, max_context=16, slots=2)
+    pools = eng.init_pools()
+    z = jnp.zeros(2, jnp.int32)
+    tables = jnp.zeros((2, spec.max_blocks_per_req), jnp.int32)
+    if program == "decode":
+        out = eng.decode(params, pools, z, tables, z)[1]
+    elif program == "prefill_chunk":
+        out = eng.prefill_chunk(params, pools, jnp.zeros(4, jnp.int32),
+                                tables[0], 0, 1)[1]
+    elif program == "verify":
+        out = eng.verify(params, pools, jnp.zeros((2, 2), jnp.int32),
+                         tables, z, z)[1]
+    elif program == "copy_block":
+        out = eng.copy_block(pools, 0, 1)
+    elif program == "restore_rows":      # the speculative rollback
+        saved = KC.gather_rows(pools, z, z)
+        out = eng.restore_rows(pools, saved, z, z)
+    else:
+        _, k, v = eng.prefill(params, *eng.pad_prompt([1, 2, 3]))
+        out = eng.write_prefill(pools, k, v, tables[0])
+    assert all(p.is_deleted() for p in pools.values())
+    assert not any(p.is_deleted() for p in out.values())
+    assert {k: p.shape for k, p in out.items()} == {
+        k: p.shape for k, p in pools.items()}
+
+
 @pytest.mark.parametrize("arch", ["flad_adllm", "xlstm_350m", "hymba_1_5b"])
 def test_incremental_decode_matches_forward(arch):
     """prefill + N single-token serve steps must reproduce the logits of
@@ -538,7 +636,7 @@ def test_prefix_sharing_streams_and_block_immutability(dense_setup):
     first = sched.run_to_completion([reqs[0]])
     assert sched.prefix.registered_blocks == 2    # template = 2 full blocks
     reg = sorted(set(sched.prefix._map.values()))
-    snap = np.asarray(sched.pools["k"])[:, :, reg].copy()
+    snap = np.asarray(sched.pools["k"])[:, reg].copy()
 
     rest = sched.run_to_completion(reqs[1:])
     got = {r.rid: list(r.tokens) for r in first + rest}
@@ -546,7 +644,7 @@ def test_prefix_sharing_streams_and_block_immutability(dense_setup):
     assert sched.prefix.hits >= 2                 # rid 1 shares, rid 2 CoWs
     assert sched.prefix.shared_blocks > 0
     # registered template blocks were mapped, never rewritten
-    assert np.array_equal(snap, np.asarray(sched.pools["k"])[:, :, reg])
+    assert np.array_equal(snap, np.asarray(sched.pools["k"])[:, reg])
     # after drain only the registry holds blocks
     assert sched.allocator.in_use == sched.prefix.registered_blocks
 
@@ -696,9 +794,15 @@ def _rollback_cycle(salt, quantized):
         want = before[k].copy()
         if accepted:
             ap, ao = np.asarray(phys)[:accepted], np.asarray(off)[:accepted]
-            want[:, :, ap, ao] = np.asarray(garbage[k])[:, :, :accepted]
+            g = np.asarray(garbage[k])[:, :accepted]       # [L, a, ...]
+            if k.endswith("_scale"):        # page rows [L, NB, 1, bs*Hkv]
+                hkv = g.shape[-1]
+                lanes = ao[:, None] * hkv + np.arange(hkv)
+                want[:, ap[:, None], 0, lanes] = g
+            else:                           # token rows [L, NB, bs, Hkv*D]
+                want[:, ap, ao] = g
         # block 0 is garbage by contract; everything else must be exact
-        assert np.array_equal(got[:, :, 1:], want[:, :, 1:]), k
+        assert np.array_equal(got[:, 1:], want[:, 1:]), k
 
 
 @pytest.mark.parametrize("quantized", [False, True])

@@ -9,8 +9,15 @@ v5e chip and compiles it with the TPU compiler, which refuses what
 interpret mode accepts (block shapes off the (8, 128) tiling, casts
 Mosaic lacks). The topology is described inside a module fixture, never
 at import: only one process at a time may load the TPU library.
+
+The serving engine's decode and prefill-chunk programs are compiled
+whole, for a K/V and a latent pool, to show that they write the donated
+pools in place: every pool is aliased to an output and nothing but the
+in-place scatter of new rows yields a pool-sized result.
 """
+import dataclasses
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +30,7 @@ HQ, HKV, D, DMODEL = 16, 8, 64, 1024
 BF16 = jnp.bfloat16
 SLOTS, BS, T = 8, 16, 35              # 8 lanes, 16-token blocks, 560 tokens
 NB = 1 + SLOTS * T + 1                 # null block + lanes + headroom
+LAYERS = 16
 
 
 @pytest.fixture(scope="module")
@@ -85,32 +93,62 @@ def test_flash_backward_compiles(sds):
      (False, 32, 144, 2048), (True, 32, 144, 2048)],
     ids=["bf16", "int8", "bf16-fleet", "int8-fleet"])
 def test_paged_decode_compiles(sds, int8, slots, t, nb):
-    pool = sds((HKV, nb, BS, D), jnp.int8 if int8 else BF16)
-    scales = sds((HKV, nb, BS, 1), jnp.float32) if int8 else None
+    # the engine's page-major layer stacks, read at a traced layer
+    pool = sds((LAYERS, nb, BS, HKV * D), jnp.int8 if int8 else BF16)
+    scales = sds((LAYERS, nb, 1, BS * HKV), jnp.float32) if int8 else None
 
-    def decode(q, kp, vp, tbl, ctx, ks, vs):
-        return ops.paged_decode_attention(q, kp, vp, tbl, ctx, k_scales=ks,
-                                          v_scales=vs, interpret=False)
+    def decode(q, kp, vp, tbl, ctx, layer, ks, vs):
+        return ops.paged_decode_attention(q, kp, vp, tbl, ctx, layer=layer,
+                                          k_scales=ks, v_scales=vs,
+                                          interpret=False)
 
     assert _mosaic_calls(decode, sds((slots, HQ, D), BF16), pool, pool,
                          sds((slots, t), jnp.int32),
-                         sds((slots,), jnp.int32), scales, scales) == 1
+                         sds((slots,), jnp.int32), sds((), jnp.int32),
+                         scales, scales) == 1
 
 
-@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-def test_paged_prefill_compiles(sds, int8):
-    pool = sds((HKV, NB, BS, D), jnp.int8 if int8 else BF16)
-    scales = sds((HKV, NB, BS, 1), jnp.float32) if int8 else None
+# the fleet cell's chunk of 32 tokens besides; and the widest GQA
+# configurations served through the paged engine, qwen3-32b (64 query
+# heads over 8 KV heads of 128) and dbrx (48 over 8 of 128), whose
+# block-diagonal query over all eight KV heads would not fit VMEM
+@pytest.mark.parametrize(
+    "int8,chunk,hq,hkv,d",
+    [(False, 16, HQ, HKV, D), (True, 16, HQ, HKV, D),
+     (False, 32, HQ, HKV, D), (True, 32, HQ, HKV, D),
+     (False, 32, 64, 8, 128), (True, 32, 64, 8, 128),
+     (False, 32, 48, 8, 128)],
+    ids=["bf16", "int8", "bf16-fleet", "int8-fleet", "bf16-qwen3-32b",
+         "int8-qwen3-32b", "bf16-dbrx"])
+def test_paged_prefill_compiles(sds, int8, chunk, hq, hkv, d):
+    pool = sds((LAYERS, NB, BS, hkv * d), jnp.int8 if int8 else BF16)
+    scales = sds((LAYERS, NB, 1, BS * hkv), jnp.float32) if int8 else None
 
-    def prefill(q, kp, vp, tbl, off, ctx, ks, vs):
+    def prefill(q, kp, vp, tbl, off, ctx, layer, ks, vs):
         return ops.paged_prefill_attention(q, kp, vp, tbl, off, ctx,
-                                           k_scales=ks, v_scales=vs,
-                                           interpret=False)
+                                           layer=layer, k_scales=ks,
+                                           v_scales=vs, interpret=False)
 
     scalar = sds((), jnp.int32)
-    assert _mosaic_calls(prefill, sds((HQ, 16, D), BF16), pool, pool,
-                         sds((T,), jnp.int32), scalar, scalar, scales,
-                         scales) == 1
+    assert _mosaic_calls(prefill, sds((hq, chunk, d), BF16), pool, pool,
+                         sds((T,), jnp.int32), scalar, scalar, scalar,
+                         scales, scales) == 1
+
+
+@pytest.mark.parametrize("hq,hkv,d", [(64, 8, 128), (48, 8, 128)],
+                         ids=["qwen3-32b", "dbrx"])
+def test_paged_decode_compiles_at_wide_heads(sds, hq, hkv, d):
+    """The decode kernel's block-diagonal query [Hq, Hkv * D] at the
+    widest GQA configurations the engine serves."""
+    pool = sds((LAYERS, NB, BS, hkv * d), BF16)
+
+    def decode(q, kp, vp, tbl, ctx, layer):
+        return ops.paged_decode_attention(q, kp, vp, tbl, ctx, layer=layer,
+                                          interpret=False)
+
+    assert _mosaic_calls(decode, sds((SLOTS, hq, d), BF16), pool, pool,
+                         sds((SLOTS, T), jnp.int32),
+                         sds((SLOTS,), jnp.int32), sds((), jnp.int32)) == 1
 
 
 # 300 rows used to pick a 150-row tile, which is not a multiple of 8
@@ -143,30 +181,33 @@ def test_lora_matmul_forward_and_dx_compile(sds):
 # moonlight-serve-plans: 64 lanes, 112-slot tables (1,792 tokens) over a
 # 1 GiB latent pool of 13 layers (4,032 blocks of 16 rows of 640 lanes)
 LAT_SLOTS, LAT_T, LAT_NB, LAT_D, LAT_V = 64, 112, 4032, 640, 512
+LAT_LAYERS = 12
 
 
 def test_latent_paged_decode_compiles(sds):
-    def decode(q, pool, tbl, ctx):
+    def decode(q, pool, tbl, ctx, layer):
         return ops.paged_decode_attention(q, pool, None, tbl, ctx,
-                                          scale=192 ** -0.5, latent_v=LAT_V,
-                                          interpret=False)
+                                          layer=layer, scale=192 ** -0.5,
+                                          latent_v=LAT_V, interpret=False)
 
     assert _mosaic_calls(decode, sds((LAT_SLOTS, HQ, LAT_D), BF16),
-                         sds((1, LAT_NB, BS, LAT_D), BF16),
+                         sds((LAT_LAYERS, LAT_NB, BS, LAT_D), BF16),
                          sds((LAT_SLOTS, LAT_T), jnp.int32),
-                         sds((LAT_SLOTS,), jnp.int32)) == 1
+                         sds((LAT_SLOTS,), jnp.int32),
+                         sds((), jnp.int32)) == 1
 
 
 def test_latent_paged_prefill_compiles(sds):
-    def prefill(q, pool, tbl, off, ctx):
+    def prefill(q, pool, tbl, off, ctx, layer):
         return ops.paged_prefill_attention(q, pool, None, tbl, off, ctx,
-                                           scale=192 ** -0.5,
+                                           layer=layer, scale=192 ** -0.5,
                                            latent_v=LAT_V, interpret=False)
 
     scalar = sds((), jnp.int32)
     assert _mosaic_calls(prefill, sds((HQ, 32, LAT_D), BF16),
-                         sds((1, LAT_NB, BS, LAT_D), BF16),
-                         sds((LAT_T,), jnp.int32), scalar, scalar) == 1
+                         sds((LAT_LAYERS, LAT_NB, BS, LAT_D), BF16),
+                         sds((LAT_T,), jnp.int32), scalar, scalar,
+                         scalar) == 1
 
 
 @pytest.mark.parametrize("tokens,layers,held,top_k,d,f", [
@@ -192,3 +233,107 @@ def test_moe_expert_ffn_compiles(sds, tokens, layers, held, top_k, d, f):
                          sds((layers, held, d, f), BF16),
                          sds((layers, held, d, f), BF16),
                          sds((layers, held, f, d), BF16)) == 1
+
+
+# ------------------------------------------------ engine programs, whole ---
+def _engine_cfg(kind):
+    """Small widths and few layers; the pool rows keep the cells' lanes
+    (flad-adllm's 8 KV heads of 64, Moonlight's 576-value latent in 640)."""
+    from repro.config import ModelConfig
+    from repro.configs.moonlight_16b_a3b import CONFIG
+    if kind == "kv":
+        return ModelConfig(name="kv", family="dense", num_layers=2,
+                           d_model=256, num_heads=HQ, num_kv_heads=HKV,
+                           head_dim=D, d_ff=512, vocab_size=512,
+                           param_dtype="bfloat16")
+    return dataclasses.replace(
+        CONFIG, num_layers=3, d_model=256, num_heads=4, d_ff=512,
+        vocab_size=512, param_dtype="bfloat16",
+        moe=dataclasses.replace(CONFIG.moe, num_experts=8, d_expert=128,
+                                experts_held=4))
+
+
+_DTYPE_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "s8": 1, "u32": 4, "pred": 1,
+                "u8": 1, "f16": 2}
+_HLO_DTYPE = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
+_INSTR = re.compile(r"^\s*(?:ROOT )?%(\S+) = (.+?) ([a-z][\w-]*)\(")
+_ARRAY = re.compile(r"\b(" + "|".join(_DTYPE_BYTES) + r")\[([\d,]*)\]")
+# ops that pass a buffer on without moving its bytes
+_FORWARDING = {"parameter", "get-tuple-element", "tuple", "while", "bitcast"}
+
+
+def _array_bytes(result_type):
+    for dt, dims in _ARRAY.findall(result_type):
+        n = _DTYPE_BYTES[dt]
+        for x in filter(None, dims.split(",")):
+            n *= int(x)
+        yield n
+
+
+# 4,096 blocks: one layer of a pool is 64 MiB (K/V) or 80 MiB (latent),
+# which the compiler cannot stage in VMEM, so a pool-sized result is a
+# copy in HBM or the in-place write
+@pytest.mark.parametrize("program", ["decode", "prefill_chunk"])
+@pytest.mark.parametrize("kind", ["kv", "latent"])
+def test_engine_writes_donated_pools_in_place(sds, monkeypatch, kind,
+                                              program):
+    """Every pool is in the compiled program's input-output alias table,
+    and no operation but the in-place scatter of the new rows
+    (``attention/kv_write``) yields a result as large as one layer of a
+    pool: nothing slices, relayouts, copies or writes back a pool."""
+    from repro.models import lm
+    from repro.serve import PagedCacheSpec, PagedEngine
+
+    # the engine's kernels pick interpret mode from the default backend
+    # (the CPU here); compile them with Mosaic for the described chip
+    monkeypatch.setattr(ops, "_auto_interpret", bool)
+    cfg = _engine_cfg(kind)
+    spec = PagedCacheSpec(num_blocks=4096, block_size=BS,
+                          max_blocks_per_req=T)
+    eng = PagedEngine(cfg, spec, max_context=T * BS, slots=SLOTS)
+    as_sds = functools.partial(jax.tree.map, lambda x: sds(x.shape, x.dtype))
+    params = as_sds(jax.eval_shape(lambda: lm.init(jax.random.PRNGKey(0),
+                                                   cfg)))
+    pools = as_sds(jax.eval_shape(eng.init_pools))
+    def i32(*shape):
+        return sds(shape, jnp.int32)
+
+    if program == "decode":
+        fn, args = eng._decode, (i32(SLOTS), i32(SLOTS, T), i32(SLOTS))
+    else:
+        fn, args = eng._prefill_chunk, (i32(32), i32(T), i32(), i32())
+    hlo = fn.lower(params, pools, *args).compile().as_text()
+    assert hlo.count('custom_call_target="tpu_custom_call"') >= 1
+
+    # the entry parameters of a pool's type (no weight has one), each
+    # aliased to an output: ``{out}: (param, {}, may-alias)``
+    leaves = jax.tree.leaves(pools)
+    types = {_HLO_DTYPE[x.dtype.name] + str(list(x.shape)).replace(" ", "")
+             for x in leaves}
+    entry = hlo[hlo.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    pool_params = [int(n) for ty, n in re.findall(
+        r"= (\w+\[[\d,]+\])\{[^}]*\} parameter\((\d+)\)", entry)
+        if ty in types]
+    assert len(pool_params) == len(leaves)
+    header = hlo[:hlo.index("\n")]
+    aliased = {int(n) for n in re.findall(
+        r"\(\s*(\d+),\s*\{\}", header[header.index("input_output_alias"):])}
+    assert set(pool_params) <= aliased
+
+    layer_bytes = min(x.size // x.shape[0] * x.dtype.itemsize
+                      for x in leaves)
+    movers, writes = [], 0
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if not m or max(_array_bytes(m.group(2)), default=0) < layer_bytes:
+            continue
+        name, op = m.group(1), m.group(3)
+        if op == "scatter" or (op == "fusion"
+                               and 'kv_write/scatter"' in line
+                               and "aliasing_operands" in line):
+            writes += 1
+        elif op not in _FORWARDING:
+            movers.append(f"{op} {name}")
+    assert writes >= 1
+    assert not movers, movers
